@@ -8,13 +8,11 @@ Three measurements (ROADMAP Open item 1 acceptance):
   tenant-parallel :class:`repro.launch.pod.PodBank`).  The headline
   ``scaling_ratio_4x`` is wall(4K tenants, D=4) / wall(K tenants, D=1)
   — the acceptance bar is <= 2x ON A HOST THAT CAN RUN THE DEVICES IN
-  PARALLEL (cpu cores >= devices, e.g. the nightly CI runner).  Forced
-  host devices are threads of one process: when the container grants
-  fewer cores than devices they SERIALIZE, so 4x the tenants is 4x the
-  compute on one core and the strict ratio degenerates to >= 4x by
-  construction — the report carries ``host_cpu_cores`` /
-  ``serialized_host`` so a reader (and the regression guard baseline)
-  can tell which regime produced the number.
+  PARALLEL.  Forced CPU host devices are threads of one process: when
+  the container grants fewer cores than devices they SERIALIZE, so the
+  strict ratio degenerates to >= 4x by construction — the report carries
+  ``host_cpu_cores`` / ``serialized_host`` so a reader (and the
+  regression guard baseline) can tell which regime produced the number.
 * **equal-work sharding tax** — wall(4K tenants, D=4) / wall(the SAME
   4K-tenant roster stacked on one device).  Total compute is identical
   on both sides, so this isolates what the mesh costs (input scatter,
@@ -26,33 +24,30 @@ Three measurements (ROADMAP Open item 1 acceptance):
   usually LOSES wall-clock — the number documents that cost; on a real
   mesh it is what makes the over-VMEM machine runnable at all).
 
-Each device count needs its own ``XLA_FLAGS=--xla_force_host_platform_
-device_count=D`` BEFORE jax import, so the harness forks one child
-python per D and aggregates their JSON; on a host that cannot fork
-(or when jax is already initialised with enough devices) the in-child
-measurement code also runs standalone:
+Everything runs in ONE process, on meshes built from the first D of
+``jax.devices()`` — a process that holds a chip keeps it, so no child
+process could measure on it.  D values beyond the devices present are
+not measured and the report says so; on a one-chip host only D=1 runs
+and the D=4 ratios are absent.  Rehearse the 4-device path on a CPU by
+starting the process with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``:
 
-    python -m benchmarks.pod_bench            # parent: forks children
-    python -m benchmarks.pod_bench --child 4  # one measurement (4 dev)
+    python -m benchmarks.pod_bench
 """
 from __future__ import annotations
 
-import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
-from .common import FAST
+from .common import FAST, row
 
 DEVICE_COUNTS = (1, 2, 4)
 OUT = "BENCH_pod.json"
 
 
-def _child_main(devices: int) -> dict:
-    """Measure on THIS process's devices (jax initialised with
-    ``devices`` fake host devices by the parent's env)."""
+def _measure(devices: int) -> dict:
+    """Measure on a mesh of this process's first ``devices`` devices."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -161,76 +156,67 @@ def _child_main(devices: int) -> dict:
 
 
 def run() -> dict:
-    """Fork one child per device count (XLA_FLAGS must precede jax
-    import), aggregate into BENCH_pod.json, print the CSV rows."""
-    from .common import row
+    """Measure every D in :data:`DEVICE_COUNTS` this process has devices
+    for, write BENCH_pod.json, print the CSV rows."""
+    import jax
 
-    by_devices = {}
-    for d in DEVICE_COUNTS:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={d} "
-                            + env.get("XLA_FLAGS", "")).strip()
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.pod_bench", "--child",
-             str(d)],
-            capture_output=True, text=True, env=env, timeout=1200)
-        if proc.returncode != 0:
-            print(proc.stdout)
-            print(proc.stderr, file=sys.stderr)
-            raise RuntimeError(f"pod_bench child (D={d}) failed")
-        # last stdout line is the child's JSON payload
-        by_devices[str(d)] = json.loads(
-            proc.stdout.strip().splitlines()[-1])
-
-    d1, d4 = by_devices["1"], by_devices["4"]
-    cores = d4["host_cpu_cores"]
+    present = len(jax.devices())
+    counts = [d for d in DEVICE_COUNTS if d <= present]
+    by_devices = {str(d): _measure(d) for d in counts}
+    dev = jax.devices()[0]
+    cores = len(os.sched_getaffinity(0))
     report = {
         "by_devices": by_devices,
-        # acceptance: 4 devices serve 4K tenants in <= 2x the wall of
-        # K tenants on one device.  The bar applies where the host can
-        # execute the devices in parallel (cores >= devices); with
-        # fewer cores the forced host devices serialize and the strict
-        # ratio degenerates to >= devices-x by construction (4x the
-        # compute on one core) — see the module docstring.
-        "scaling_ratio_4x": d4["flush_wall_s"] / max(d1["flush_wall_s"],
-                                                     1e-12),
-        # equal total compute on both sides: the pure mesh tax (input
-        # scatter + per-device dispatch), meaningful on any host
-        "equal_work_ratio_4x": (d4["flush_wall_s"]
-                                / max(d1["flush_wall_4k_s"], 1e-12)),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "devices_present": present,
         "host_cpu_cores": cores,
-        "serialized_host": cores < d4["devices"],
-        "clause_sharded": d4.get("clause_sharded"),
     }
-    with open(OUT, "w") as f:
-        json.dump(report, f, indent=2)
-    for d in DEVICE_COUNTS:
+    for d in counts:
         e = by_devices[str(d)]
         row(f"pod_flush_d{d}_k{e['tenants']}", e["flush_wall_s"] * 1e6,
             f"{e['tenants_per_s']:.1f} tenants/s")
-    regime = (f"SERIALIZED host: {cores} core(s) for {d4['devices']} "
-              "devices" if report["serialized_host"] else "parallel host")
-    row("pod_scaling_4x", report["scaling_ratio_4x"] * 100,
-        f"{report['scaling_ratio_4x']:.2f}x wall for 4x tenants ({regime})")
-    row("pod_equal_work_4x", report["equal_work_ratio_4x"] * 100,
-        f"{report['equal_work_ratio_4x']:.2f}x mesh tax at equal work")
-    cs = report["clause_sharded"]
-    if cs:
+    if "4" not in by_devices:
+        report["not_measured"] = (
+            f"D={[d for d in DEVICE_COUNTS if d > present]}: this process "
+            f"has {present} device(s)")
+        row("pod_scaling_4x", float("nan"),
+            f"not measured: {report['not_measured']}")
+    else:
+        d1, d4 = by_devices["1"], by_devices["4"]
+        report.update({
+            # acceptance: 4 devices serve 4K tenants in <= 2x the wall of
+            # K tenants on one device (where the devices run in parallel;
+            # serialized forced CPU devices degenerate to >= 4x)
+            "scaling_ratio_4x": d4["flush_wall_s"]
+            / max(d1["flush_wall_s"], 1e-12),
+            # equal total compute on both sides: the pure mesh tax (input
+            # scatter + per-device dispatch), meaningful on any host
+            "equal_work_ratio_4x": d4["flush_wall_s"]
+            / max(d1["flush_wall_4k_s"], 1e-12),
+            "serialized_host": (dev.platform == "cpu"
+                                and cores < d4["devices"]),
+            "clause_sharded": d4.get("clause_sharded"),
+        })
+        regime = (f"SERIALIZED host: {cores} core(s) for 4 devices"
+                  if report["serialized_host"] else "parallel devices")
+        row("pod_scaling_4x", report["scaling_ratio_4x"] * 100,
+            f"{report['scaling_ratio_4x']:.2f}x wall for 4x tenants "
+            f"({regime})")
+        row("pod_equal_work_4x", report["equal_work_ratio_4x"] * 100,
+            f"{report['equal_work_ratio_4x']:.2f}x mesh tax at equal work")
+        cs = report["clause_sharded"]
         row(f"pod_clause_sharded_R{cs['R']}", cs["step_us_sharded"],
             f"{cs['sharded_vs_single']:.2f}x vs single-device")
+    with open(OUT, "w") as f:
+        json.dump(report, f, indent=2)
     return report
 
 
-def main(argv=None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--child", type=int, default=None,
-                    help="internal: measure on N forced devices and "
-                         "print JSON")
-    args = ap.parse_args(argv)
-    if args.child is not None:
-        print(json.dumps(_child_main(args.child)))
-    else:
-        run()
+def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    run()
 
 
 if __name__ == "__main__":

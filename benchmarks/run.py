@@ -30,6 +30,8 @@ def main() -> None:
     if args.smoke:
         # must land before benchmark modules import benchmarks.common
         os.environ["FAST"] = "1"
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from . import (autotune_bench, fig3_opcounts, fig7_clause_skip,
                    fig11_kernels, fig14_weight_bits, fig15_lfsr,

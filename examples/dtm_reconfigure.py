@@ -15,6 +15,9 @@ import numpy as np
 from repro import api
 from repro.api import TM, TMSpec
 from repro.data import KWS6_LIKE, MNIST_LIKE, make_bool_dataset
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 rng = np.random.default_rng(0)
 B = 32

@@ -9,6 +9,9 @@ PYTHONPATH=src python examples/quickstart.py
 """
 from repro.api import TM, TMSpec
 from repro.data import MNIST_LIKE, make_bool_dataset
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # 784 Boolean features, 10 classes — MNIST geometry (synthetic surrogate).
 x, y = make_bool_dataset(MNIST_LIKE, 1024)

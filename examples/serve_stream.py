@@ -20,6 +20,9 @@ from repro import api
 from repro.api import TMSpec
 from repro.launch.scheduler import BATCH, GOLD, STANDARD, SchedulerConfig
 from repro.launch.serve_tm import demo_batch
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 B = 8
 TENANTS = {

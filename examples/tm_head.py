@@ -14,6 +14,9 @@ PYTHONPATH=src python examples/tm_head.py
 import numpy as np
 
 from repro.api import TM, TMSpec
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # synthetic 3-way "sensor" task behind a frozen random-projection
 # backbone: class-dependent means, fixed mixing matrix, pooled features

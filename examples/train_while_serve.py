@@ -23,6 +23,9 @@ from repro.api import TMSpec
 from repro.launch.scheduler import GOLD, STANDARD, SchedulerConfig
 from repro.launch.serve_tm import demo_batch
 from repro.runtime.fault import FaultInjector, FaultPlan
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 B = 8
 STEPS = 6

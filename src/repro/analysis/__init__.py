@@ -9,9 +9,9 @@ synthesis (arXiv 2403.10538) for our jax_pallas stack:
   packed layout, writeable cached arrays, interpret-default drift,
   silent exception fallbacks, unlocked stats reads.
 * :mod:`repro.analysis.kernel_check` — static Pallas kernel contract
-  checker: grid x index-map coverage and per-tile VMEM footprints for
-  every tile plan the autotuner can emit, against the
-  ``launch.mesh.HardwareModel`` budget.
+  checker: grid x index-map coverage, Mosaic's (8, 128) block rule and
+  per-tile VMEM footprints for every tile plan the autotuner can emit,
+  against the scoped VMEM limit each launch gets.
 * :mod:`repro.analysis.trace_audit` — runtime trace contract: the
   five-TMSpec-kind scenario matrix under ``jax.checking_leaks`` +
   ``jax.transfer_guard("disallow")``, jit cache sizes and dispatch

@@ -9,18 +9,22 @@ declarative plans and verifies, WITHOUT running anything:
 * **coverage**: the output index maps tile every output block exactly
   (remainder rows exist only as caller-side padding, which the ops
   wrappers add and strip — the checker verifies padded dims divide);
+* **tiling**: Mosaic's block rule — the last two block dims are
+  multiples of (8, 128) or span the whole array dim;
 * **VMEM**: the per-grid-step footprint — every HBM-streamed block
-  double-buffered, plus VMEM scratch — fits
-  ``launch.mesh.HardwareModel.vmem_bytes`` for EVERY tile plan the
-  autotuner can emit (``EVAL_TILES``/``TRAIN_TILES``/``TA_TILES`` ×
-  the plan-key grid of shapes and batch buckets).  No plan the tuner
-  can persist may be unlaunchable (the eFPGA runtime-tunable TM work,
-  arXiv 2502.07823, does the same budget validation pre-load).
+  double-buffered, plus VMEM scratch — fits the budget the launch gets:
+  Mosaic's scoped default, or the explicit ``vmem_limit_bytes`` the
+  kernel requests for a larger plan (``kernels.tpu_params``) — for EVERY
+  tile plan the autotuner can emit (``EVAL_TILES``/``TRAIN_TILES``/
+  ``TA_TILES`` × the plan-key grid of shapes and batch buckets).  No plan
+  the tuner can persist may be unlaunchable (the eFPGA runtime-tunable
+  TM work, arXiv 2502.07823, does the same budget validation pre-load).
 
 Index maps are the REAL lambdas from the kernel modules' contracts,
 restated here; they are affine coordinate projections, so the checker
 probes them with unit grid vectors and verifies linearity instead of
-enumerating the full grid product.
+enumerating the full grid product.  The AOT compile in
+``tests/test_tpu_compile.py`` is the ground truth these rules mirror.
 """
 
 from __future__ import annotations
@@ -30,8 +34,9 @@ import math
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from repro.kernels.autotune import EVAL_TILES, TA_TILES, TRAIN_TILES
+from repro.kernels.class_sum import N_LIMBS
 from repro.kernels.ops import _skip_caps
-from repro.launch.mesh import V5E
+from repro.kernels.tpu_params import SCOPED_VMEM_BYTES, vmem_limit
 
 __all__ = ["KernelPlan", "Violation", "build_plans", "check_plan",
            "check_all", "main"]
@@ -55,7 +60,7 @@ class BlockUse:
 @dataclasses.dataclass(frozen=True)
 class KernelPlan:
     kernel: str
-    desc: str                           # e.g. "eval/b256/L1024xR512 wt=32"
+    desc: str                           # e.g. "eval/b256/L1024xR512 wt=128"
     grid: Tuple[int, ...]
     uses: Tuple[BlockUse, ...]
     scratch_bytes: int = 0
@@ -65,7 +70,7 @@ class KernelPlan:
 class Violation:
     kernel: str
     desc: str
-    kind: str                           # oob | coverage | divide | vmem
+    kind: str                           # oob|coverage|divide|tiling|vmem
     detail: str
 
     def render(self) -> str:
@@ -80,8 +85,12 @@ def _pad_to(n: int, t: int) -> int:
     return -(-n // t) * t
 
 
-def _packed_words(L: int, wt: int) -> int:
-    return _pad_to(-(-L // _WORD), wt)
+def _packed_tile(L: int, wt: int) -> Tuple[int, int]:
+    """(padded words, word tile) as ``ops.lane_tile`` + padding give
+    them: a tile at least as wide as the row becomes the whole row."""
+    W = -(-L // _WORD)
+    wt = W if W <= wt else wt
+    return _pad_to(W, wt), wt
 
 
 # --------------------------------------------------------------------------- #
@@ -103,7 +112,7 @@ def plan_clause_eval(B, L, C, bt=8, yt=128, xt=256) -> KernelPlan:
 def plan_packed_clause(B, L, C, bt=8, yt=128, wt=128,
                        kernel="packed_clause_eval") -> KernelPlan:
     B, C = _pad_to(B, bt), _pad_to(C, yt)
-    W = _packed_words(L, wt)
+    W, wt = _packed_tile(L, wt)
     grid = (B // bt, C // yt, W // wt)
     return KernelPlan(
         kernel, f"B{B} W{W} C{C} bt{bt} yt{yt} wt{wt}", grid,
@@ -120,7 +129,8 @@ def plan_class_sum(B, C, H, bt=8, mt=128) -> KernelPlan:
     return KernelPlan(
         "class_sum", f"B{B} C{C} H{H} bt{bt} mt{mt}", grid,
         (BlockUse("clauses", (B, C), (bt, mt), lambda b, k: (b, k), 1),
-         BlockUse("weights", (H, C), (H, mt), lambda b, k: (0, k), 4),
+         BlockUse("weight_limbs", (N_LIMBS, H, C), (N_LIMBS, H, mt),
+                  lambda b, k: (0, 0, k), 1),
          BlockUse("sums", (B, H), (bt, H), lambda b, k: (b, 0), 4,
                   is_output=True)),
         scratch_bytes=bt * H * 4)
@@ -133,7 +143,8 @@ def plan_tm_infer(B, L, C, H, bt=8, yt=128, xt=256) -> KernelPlan:
         "tm_infer", f"B{B} L{L} C{C} H{H} bt{bt} yt{yt} xt{xt}", grid,
         (BlockUse("neg_lit", (B, L), (bt, xt), lambda b, c, k: (b, k), 1),
          BlockUse("include", (C, L), (yt, xt), lambda b, c, k: (c, k), 1),
-         BlockUse("weights", (H, C), (H, yt), lambda b, c, k: (0, c), 4),
+         BlockUse("weight_limbs", (N_LIMBS, H, C), (N_LIMBS, H, yt),
+                  lambda b, c, k: (0, 0, c), 1),
          BlockUse("sums", (B, H), (bt, H), lambda b, c, k: (b, 0), 4,
                   is_output=True)),
         scratch_bytes=(bt * yt + yt + bt * H) * 4)
@@ -147,7 +158,8 @@ def plan_fused_step(B, L, R, H, bt=8, yt=128, xt=256) -> KernelPlan:
         "fused_step", f"B{B} L{L} R{R} H{H} bt{bt} yt{yt} xt{xt}", grid,
         (BlockUse("neg_lit", (B, L), (bt, xt), lambda b, c, k: (b, k), 1),
          BlockUse("include", (R, L), (yt, xt), lambda b, c, k: (c, k), 1),
-         BlockUse("weights", (H, R), (H, yt), lambda b, c, k: (0, c), 4),
+         BlockUse("weight_limbs", (N_LIMBS, H, R), (N_LIMBS, H, yt),
+                  lambda b, c, k: (0, 0, c), 1),
          BlockUse("lab_oh", (B, H), (bt, H), bh, 4),
          BlockUse("neg_oh", (B, H), (bt, H), bh, 4),
          BlockUse("w_lab", (B, R), (bt, R), bh, 4),
@@ -173,10 +185,8 @@ def plan_ta_update(B, L, C, yt=128, xt=256) -> KernelPlan:
     return KernelPlan(
         "ta_update", f"B{B} L{L} C{C} yt{yt} xt{xt}", grid,
         (BlockUse("ta", (C, L), (yt, xt), lambda c, l: (c, l), 4),
-         BlockUse("literals", (B, L), (B, xt), lambda c, l: (0, l), 1),
-         BlockUse("clause", (B, C), (B, yt), lambda c, l: (0, c), 4),
-         BlockUse("type1", (B, C), (B, yt), lambda c, l: (0, c), 4),
-         BlockUse("type2", (B, C), (B, yt), lambda c, l: (0, c), 4),
+         BlockUse("literals", (B, L), (B, xt), lambda c, l: (0, l), 4),
+         BlockUse("fb_code", (B, C), (B, yt), lambda c, l: (0, c), 4),
          BlockUse("l_mask", (1, L), (1, xt), lambda c, l: (0, l), 4),
          BlockUse("params", (1, 5), (1, 5), lambda c, l: (0, 0), 4,
                   smem=True),
@@ -194,12 +204,8 @@ def plan_ta_update_sparse(B, L, C, k, yt=128, xt=256) -> KernelPlan:
         "ta_update_sparse", f"B{B} L{L} C{C} k{k} yt{yt} xt{xt}", grid,
         (BlockUse("ta", (C, L), (yt, xt), lambda c, l: (g, l), 4,
                   gather_axes=(0,)),
-         BlockUse("literals", (B, L), (B, xt), lambda c, l: (0, l), 1),
-         BlockUse("clause", (B, C), (B, yt), lambda c, l: (0, g), 4,
-                  gather_axes=(1,)),
-         BlockUse("type1", (B, C), (B, yt), lambda c, l: (0, g), 4,
-                  gather_axes=(1,)),
-         BlockUse("type2", (B, C), (B, yt), lambda c, l: (0, g), 4,
+         BlockUse("literals", (B, L), (B, xt), lambda c, l: (0, l), 4),
+         BlockUse("fb_code", (B, C), (B, yt), lambda c, l: (0, g), 4,
                   gather_axes=(1,)),
          BlockUse("l_mask", (1, L), (1, xt), lambda c, l: (0, l), 4),
          BlockUse("ta_out", (k * yt, L), (yt, xt), lambda c, l: (c, l), 4,
@@ -241,7 +247,10 @@ def _affine(index_map, grid) -> Optional[List[Tuple[int, ...]]]:
 
 
 def check_plan(plan: KernelPlan,
-               vmem_bytes: float = V5E.vmem_bytes) -> List[Violation]:
+               vmem_bytes: Optional[float] = None) -> List[Violation]:
+    """Every violation of one plan.  ``vmem_bytes`` overrides the VMEM
+    budget (default: what the launch gets — the scoped default, or the
+    kernel's explicit request for a larger footprint)."""
     out: List[Violation] = []
 
     def bad(kind, detail):
@@ -253,6 +262,14 @@ def check_plan(plan: KernelPlan,
         for a, (d, b) in enumerate(zip(u.dims, u.block)):
             if d % b:
                 bad("divide", f"{u.name} axis {a}: dim {d} % block {b}")
+        # --- tiling: Mosaic's (8, 128)-or-whole-dim block rule ----------
+        if not u.smem and len(u.block) >= 2:
+            for a, q in ((-2, 8), (-1, 128)):
+                d, b = u.dims[a], u.block[a]
+                if b % q and b != d:
+                    bad("tiling", f"{u.name} axis {len(u.dims) + a}: block "
+                                  f"{b} is neither a multiple of {q} nor "
+                                  f"the whole dim {d}")
         lin = _affine(u.index_map, plan.grid)
         if lin is None:
             bad("oob", f"{u.name}: non-affine index map")
@@ -290,9 +307,15 @@ def check_plan(plan: KernelPlan,
         # --- VMEM: double-buffer everything HBM-streamed --------------
         blk = math.prod(u.block) * u.elem_bytes
         vmem += blk if u.smem else 2 * blk
+    if vmem_bytes is None:
+        try:
+            vmem_bytes = vmem_limit(vmem) or SCOPED_VMEM_BYTES
+        except ValueError as e:
+            bad("vmem", str(e))
+            return out
     if vmem > vmem_bytes:
         bad("vmem", f"per-step footprint {vmem / 1e6:.1f} MB exceeds "
-                    f"HardwareModel.vmem_bytes {vmem_bytes / 1e6:.0f} MB")
+                    f"the {vmem_bytes / 1e6:.1f} MB VMEM budget")
     return out
 
 
@@ -343,7 +366,7 @@ def build_plans() -> List[KernelPlan]:
     return plans
 
 
-def check_all(vmem_bytes: float = V5E.vmem_bytes
+def check_all(vmem_bytes: Optional[float] = None
               ) -> Tuple[int, List[Violation]]:
     plans = build_plans()
     violations: List[Violation] = []
@@ -356,7 +379,9 @@ def main(argv: Sequence[str]) -> int:
     import argparse
     ap = argparse.ArgumentParser(
         prog="dtmlint kernels", description=__doc__.splitlines()[0])
-    ap.add_argument("--vmem-bytes", type=float, default=V5E.vmem_bytes)
+    ap.add_argument("--vmem-bytes", type=float, default=None,
+                    help="override the VMEM budget (default: the launch's "
+                         "scoped or explicitly requested limit)")
     ns = ap.parse_args(list(argv))
     n, violations = check_all(ns.vmem_bytes)
     for v in violations:

@@ -145,6 +145,18 @@ class TMSpec:
         return cls(kind="head", classes=classes, clauses=clauses, T=T, s=s,
                    thresholds=booleanizer.thresholds, **kw)
 
+    @classmethod
+    def from_config(cls, cfg: TMConfig) -> "TMSpec":
+        """The flat spec of a :class:`TMConfig` (e.g. the paper's models
+        in ``configs/tm_paper.py``), every hyper-parameter carried over."""
+        kind = "vanilla" if cfg.tm_type == VANILLA else "coalesced"
+        return cls(kind=kind, features=cfg.features, clauses=cfg.clauses,
+                   classes=cfg.classes, T=cfg.T, s=cfg.s,
+                   ta_bits=cfg.ta_bits, weight_bits=cfg.weight_bits,
+                   rand_bits=cfg.rand_bits, prng_backend=cfg.prng_backend,
+                   lfsr_bits=cfg.lfsr_bits, seed_refresh=cfg.seed_refresh,
+                   boost_true_positive=cfg.boost_true_positive)
+
     def __post_init__(self):
         assert self.kind in KINDS, self.kind
         if self.prng_backend not in PRNG_BACKENDS:
@@ -313,14 +325,15 @@ def plan_for(mesh, *specs: TMSpec, vmem_budget: Optional[float] = None,
     trading one ``[B, H]`` class-sum psum per step for fitting at all.
     """
     # lazy imports: api is the front-end layer; launch/ pulls it back in
-    from repro.launch.mesh import V5E, mesh_chips
+    from repro.launch.mesh import hardware_model, mesh_chips
     from repro.launch import tm_perf
 
     tile = tile_for(*specs, **tile_kw)
     L, R, H = tile.padded_dims()
     ta_bits = max(s.ta_bits for s in specs)
     pbytes = tm_perf.program_bytes(L, R, H, ta_bits=ta_bits)
-    budget = int(vmem_budget if vmem_budget is not None else V5E.vmem_bytes)
+    budget = int(vmem_budget if vmem_budget is not None
+                 else hardware_model(mesh.devices.flat[0]).vmem_bytes)
     n = mesh_chips(mesh)
     axes = mesh.axis_names
     if n <= 1:

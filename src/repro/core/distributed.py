@@ -20,30 +20,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# shard_map import fallback, resolved ONCE for the whole codebase:
-# jax >= 0.7 exports it top-level (and renamed check_rep -> check_vma);
-# the 0.4.x line only has jax.experimental.shard_map.  Import the
-# resolved ``shard_map`` wrapper (or ``_shard_map``/``SM_KW``) from here
-# — do not re-duplicate this try/except at call sites.
-try:  # jax >= 0.7 top-level, else experimental
-    from jax import shard_map as _shard_map
-    SM_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover — jax < 0.7 (the pinned toolchain)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    SM_KW = {"check_rep": False}
-_SM_KW = SM_KW      # historical alias (pre-hoist call sites)
-
 from . import feedback
 from .prng import LFSRState, PRNG, _seed_lanes
 from .types import COALESCED, TMConfig, TMState, VANILLA
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking off — the
-    TM collectives are explicit integer psums/gathers, and the 0.4.x
-    checker rejects the psum-into-replicated-output pattern they use."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **SM_KW)
+    """``jax.shard_map`` with varying-manual-axes checking off — the TM
+    collectives are explicit integer psums/gathers into replicated
+    outputs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def compact_rows_psum(d: jax.Array, axes, frac: float) -> jax.Array:
@@ -149,9 +136,8 @@ def dp_train_step(cfg: TMConfig, state: TMState, literals: jax.Array,
 
     w_arg = (state.weights if state.weights is not None
              else jnp.zeros((1,), jnp.int32))
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(P(), P(), P(axis), P(axis)),
-                    out_specs=(P(), P(), P(), P()), **_SM_KW)
+    fn = shard_map(shard_fn, mesh, in_specs=(P(), P(), P(axis), P(axis)),
+                   out_specs=(P(), P(), P(), P()))
     d_ta, d_w, d_sel, corr = fn(state.ta, w_arg, literals, labels)
     if cfg.tm_type == VANILLA:
         d_w = None
@@ -277,12 +263,11 @@ def pod_train_step(cfg: TMConfig, state: TMState, literals: jax.Array,
         return d_ta, d_w, d_sel, correct
 
     dp_spec = dp if len(dp) > 1 else dp[0]
-    fn = _shard_map(
-        shard_fn, mesh=mesh,
+    fn = shard_map(
+        shard_fn, mesh,
         in_specs=(P("model", None), P(None, "model"), P(dp_spec, None),
                   P(dp_spec)),
-        out_specs=(P("model", None), P(None, "model"), P("model"), P()),
-        **_SM_KW)
+        out_specs=(P("model", None), P(None, "model"), P("model"), P()))
     d_ta, d_w, d_sel, corr = fn(state.ta, state.weights, literals, labels)
     new_ta = feedback.apply_ta_delta(cfg, state.ta, d_ta)
     new_w = feedback.apply_w_delta(cfg, state.weights, d_w)

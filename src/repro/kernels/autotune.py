@@ -18,7 +18,9 @@ the heuristics as the universal fallback:
   ``off``) reuses it.
 
 Plan cache location: ``$REPRO_AUTOTUNE_CACHE`` if set, else
-``~/.cache/repro/autotune_<device_kind>.json`` — one file per device kind,
+``<checkout>/.autotune/autotune_<device_kind>.json`` (git-ignored; nothing
+the dispatcher reads comes from outside the checkout) — one file per
+device kind,
 keyed ``stage/b<batch-bucket>/L..xR..xH..`` (batch buckets are
 next-power-of-2, so nearby batch sizes share a plan).  Regenerate on new
 hardware by deleting the file and running any workload (or
@@ -42,12 +44,11 @@ MODES = ("off", "seed", "measure")
 STAGES = ("eval", "train", "ta")
 
 # Tile-geometry candidates swept by measure mode, per stage.  Ops pad
-# every operand to tile multiples, so all geometries are legal for any
-# shape; the sweep is deliberately small — a handful of points around the
-# VPU-native (8, 128) register tile.
-EVAL_TILES = ({"bt": 8, "yt": 128, "wt": 8},
-              {"bt": 8, "yt": 128, "wt": 32},
-              {"bt": 8, "yt": 128, "wt": 128})
+# every operand to tile multiples, and every tile is (8, 128)-aligned —
+# Mosaic's block rule; a packed-word tile wider than the row becomes the
+# whole row (ops.lane_tile) — so all geometries are legal for any shape.
+EVAL_TILES = ({"bt": 8, "yt": 128, "wt": 128},
+              {"bt": 8, "yt": 128, "wt": 256})
 TRAIN_TILES = ({"bt": 8, "yt": 128, "xt": 256},)
 TA_TILES = ({"yt": 128, "xt": 256},)
 
@@ -70,22 +71,22 @@ def resolve_autotune() -> str:
     return env
 
 
+# default plan-file directory: fixed, inside the checkout
+PLAN_DIR = pathlib.Path(__file__).resolve().parents[3] / ".autotune"
+
+
 def device_kind() -> str:
-    """Plan-cache namespace: the JAX device kind (e.g. ``TPU_v5e``),
+    """Plan-cache namespace: the JAX device kind (e.g. ``TPU_v5_lite``),
     ``cpu`` under interpret mode."""
-    try:
-        import jax
-        return jax.devices()[0].device_kind.replace(" ", "_")
-    except Exception:
-        return "unknown"
+    import jax
+    return jax.devices()[0].device_kind.replace(" ", "_")
 
 
 def cache_path() -> pathlib.Path:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE", "").strip()
     if env:
         return pathlib.Path(env)
-    return (pathlib.Path.home() / ".cache" / "repro"
-            / f"autotune_{device_kind()}.json")
+    return PLAN_DIR / f"autotune_{device_kind()}.json"
 
 
 def clear_cache() -> None:
@@ -115,7 +116,7 @@ def _disk_plans() -> dict:
     if _DISK is None:
         try:
             _DISK = json.loads(cache_path().read_text())
-        except (OSError, ValueError):
+        except FileNotFoundError:
             _DISK = {}
     return _DISK
 
@@ -123,12 +124,9 @@ def _disk_plans() -> dict:
 def _persist(key: str, plan: dict) -> None:
     plans = dict(_disk_plans())
     plans[key] = plan
-    try:
-        path = cache_path()
-        path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps(plans, indent=1, sort_keys=True))
-    except OSError:
-        pass        # read-only home: keep the plan in memory only
+    path = cache_path()
+    path.parent.mkdir(parents=True, exist_ok=True)
+    path.write_text(json.dumps(plans, indent=1, sort_keys=True))
     global _DISK
     _DISK = plans
 
@@ -274,10 +272,9 @@ def _measure_plan(stage: str, batch, shape) -> dict | None:
         raise ValueError(f"unknown autotune stage {stage!r}; use {STAGES}")
 
     for path, tiles, thunk in cands:
-        try:
-            s = timed(thunk)
-        except Exception:
-            continue               # a candidate that can't run never wins
+        # a candidate the device refuses is an error, not a loss: it
+        # would otherwise silently "never win" and hide a broken kernel
+        s = timed(thunk)
         if best is None or s < best["us"] / 1e6:
             best = {"path": path, "tiles": dict(tiles), "us": s * 1e6,
                     "source": "measure"}

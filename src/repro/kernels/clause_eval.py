@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+from .tpu_params import block_bytes, compiler_params
 
 
 def _kernel(neg_lit_ref, inc_ref, out_ref, acc_ref, cnt_ref, *,
@@ -34,13 +34,14 @@ def _kernel(neg_lit_ref, inc_ref, out_ref, acc_ref, cnt_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    neg = neg_lit_ref[...].astype(jnp.int32)          # [bt, xt]
-    inc = inc_ref[...].astype(jnp.int32)              # [yt, xt]
-    # violations: contract the literal (x) axis on the MXU
+    inc = inc_ref[...]                                # [yt, xt] int8
+    # violations: contract the literal (x) axis on the MXU — int8
+    # operands, int32 accumulation
     acc_ref[...] += jax.lax.dot_general(
-        neg, inc, dimension_numbers=(((1,), (1,)), ((), ())),
+        neg_lit_ref[...], inc, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32)             # [bt, yt]
-    cnt_ref[...] += inc.sum(axis=1, keepdims=True).T  # [1, yt]
+    if eval_mode:
+        cnt_ref[...] += inc.astype(jnp.int32).sum(axis=1, keepdims=True).T
 
     @pl.when(k == n_k - 1)
     def _finish():
@@ -69,6 +70,8 @@ def clause_eval(literals: jax.Array, include: jax.Array,
         (B, C, L), (bt, yt, xt))
     neg = (1 - literals).astype(jnp.int8)
     grid = (B // bt, C // yt, L // xt)
+    need = (block_bytes(((bt, xt), 1), ((yt, xt), 1), ((bt, yt), 4))
+            + (bt * yt + yt) * 4)
     return pl.pallas_call(
         functools.partial(_kernel, n_k=grid[2], eval_mode=eval_mode),
         grid=grid,
@@ -82,7 +85,7 @@ def clause_eval(literals: jax.Array, include: jax.Array,
             pltpu.VMEM((bt, yt), jnp.int32),
             pltpu.VMEM((1, yt), jnp.int32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"), need),
         interpret=interpret,
     )(neg, include.astype(jnp.int8))

@@ -48,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+from .class_sum import N_LIMBS, limb_dot, weight_limbs
+from .tpu_params import block_bytes, compiler_params
 # Fig 6d: remainder class sums pinned to the datapath minimum — single
 # definition shared with the oracles and the engine.
 from .ref import NEG_INF_SUM
@@ -69,10 +70,10 @@ def _kernel(neg_lit_ref, inc_ref, w_tile_ref, lab_oh_ref, neg_oh_ref,
     def _init_viol():
         viol_ref[...] = jnp.zeros_like(viol_ref)
 
-    neg = neg_lit_ref[...].astype(jnp.int32)              # [bt, xt]
-    inc = inc_ref[...].astype(jnp.int32)                  # [yt, xt]
+    # int8 operands, int32 accumulation on the MXU
     viol_ref[...] += jax.lax.dot_general(
-        neg, inc, dimension_numbers=(((1,), (1,)), ((), ())),
+        neg_lit_ref[...], inc_ref[...],
+        dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32)                 # [bt, yt]
 
     @pl.when(k == n_k - 1)
@@ -82,10 +83,8 @@ def _kernel(neg_lit_ref, inc_ref, w_tile_ref, lab_oh_ref, neg_oh_ref,
         fired = (viol_ref[...] == 0).astype(jnp.int32)
         clause = fired * clm_tile_ref[...]                # [bt, yt]
         clause_ref[...] = clause                          # single HBM write
-        w = w_tile_ref[...].astype(jnp.int32)             # [H, yt]
-        acc_ref[...] += jax.lax.dot_general(
-            clause, w, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)             # [bt, H]
+        acc_ref[...] += limb_dot(clause.astype(jnp.int8),
+                                 w_tile_ref)              # [bt, H]
 
         @pl.when(c == n_c - 1)
         def _select():
@@ -107,7 +106,7 @@ def _kernel(neg_lit_ref, inc_ref, w_tile_ref, lab_oh_ref, neg_oh_ref,
                 lhs = rnd_ref[...].astype(jnp.int32) * (2 * T)
                 sel = lhs < (p_num << rand_bits)
                 # Vanilla eligibility: only the class's own block (w != 0).
-                elig = jnp.where(w_frozen > 0, w_r != 0, True)
+                elig = jnp.logical_or(w_r != 0, w_frozen <= 0)
                 out_ref[...] = (sel & clm & elig).astype(jnp.int32)
 
 
@@ -147,6 +146,13 @@ def fused_step(literals: jax.Array, include: jax.Array, weights: jax.Array,
     params = jnp.stack([jnp.asarray(T, jnp.int32),
                         jnp.asarray(w_frozen, jnp.int32)]).reshape(1, 2)
     grid = (B // bt, R // yt, L // xt)
+    need = (block_bytes(((bt, xt), 1), ((yt, xt), 1), ((N_LIMBS, H, yt), 1),
+                        ((bt, H), 4), ((bt, H), 4), ((bt, R), 4),
+                        ((bt, R), 4), ((bt, R), 4), ((bt, R), 4),
+                        ((1, yt), 4), ((1, R), 4), ((1, H), 4),
+                        ((bt, yt), 4), ((bt, H), 4), ((bt, R), 4),
+                        ((bt, R), 4))
+            + (bt * yt + bt * H) * 4)
     return pl.pallas_call(
         functools.partial(_kernel, n_c=grid[1], n_k=grid[2],
                           rand_bits=rand_bits),
@@ -154,7 +160,8 @@ def fused_step(literals: jax.Array, include: jax.Array, weights: jax.Array,
         in_specs=[
             pl.BlockSpec((bt, xt), lambda b, c, k: (b, k)),    # neg literals
             pl.BlockSpec((yt, xt), lambda b, c, k: (c, k)),    # include
-            pl.BlockSpec((H, yt), lambda b, c, k: (0, c)),     # weight tile
+            pl.BlockSpec((N_LIMBS, H, yt),
+                         lambda b, c, k: (0, 0, c)),           # weight limbs
             pl.BlockSpec((bt, H), lambda b, c, k: (b, 0)),     # label one-hot
             pl.BlockSpec((bt, H), lambda b, c, k: (b, 0)),     # negated "
             pl.BlockSpec((bt, R), lambda b, c, k: (b, 0)),     # w row (lab)
@@ -183,10 +190,10 @@ def fused_step(literals: jax.Array, include: jax.Array, weights: jax.Array,
             pltpu.VMEM((bt, yt), jnp.int32),                   # violations
             pltpu.VMEM((bt, H), jnp.int32),                    # sum acc
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), need),
         interpret=interpret,
-    )(neg_lit, include.astype(jnp.int8), weights.astype(jnp.int32),
+    )(neg_lit, include.astype(jnp.int8), weight_limbs(weights),
       lab_oh.astype(jnp.int32), neg_oh.astype(jnp.int32),
       w_lab.astype(jnp.int32), w_neg.astype(jnp.int32),
       rand_lab.astype(jnp.uint32), rand_neg.astype(jnp.uint32),

@@ -227,6 +227,19 @@ def _pad1(x: jax.Array, m: int, value=0) -> jax.Array:
     return x if p == 0 else jnp.pad(x, (0, p), constant_values=value)
 
 
+def lane_tile(n: int, t: int) -> int:
+    """Block width along a lane (last) axis of extent ``n`` for a
+    requested tile ``t``.  Mosaic takes a block that is a multiple of 128
+    lanes or the whole axis, so a tile at least as wide as the axis
+    becomes the whole axis; a narrower one must be 128-aligned."""
+    if n <= t:
+        return n
+    if t % 128:
+        raise ValueError(f"lane tile {t} is neither a multiple of 128 nor "
+                         f"covers the whole {n}-wide axis")
+    return t
+
+
 @functools.partial(jax.jit, static_argnames=("eval_mode", "backend",
                                              "bt", "yt", "xt"))
 def clause_eval_op(literals, include, eval_mode=False, backend="pallas",
@@ -288,6 +301,7 @@ def packed_clause_eval_op(packed_literals, packed_include, eval_mode=False,
         packed_include = ref.tail_mask_words(packed_include, n_bits)
     B, W = packed_literals.shape
     C = packed_include.shape[0]
+    wt = lane_tile(W, wt)
     lit = _pad2(packed_literals, bt, wt)
     inc = _pad2(packed_include, yt, wt)
     out = packed_clause_eval(lit, inc, eval_mode=eval_mode, bt=bt, yt=yt,
@@ -299,13 +313,13 @@ def packed_clause_eval_op(packed_literals, packed_include, eval_mode=False,
                                              "n_bits", "bt", "yt", "wt"))
 def packed_clause_mxu_op(packed_literals, packed_include, eval_mode=False,
                          backend="pallas", n_bits=None, bt=8, yt=128,
-                         wt=8):
+                         wt=128):
     """Packed [B,W]×[C,W] -> [B,C] on the MXU popcount leg
     (:data:`PATH_PACKED_MXU`): uint32 words expand to int8 bitplanes
     in-register and clause violations become int8 dot products — same
     contract and bit-identical output as :func:`packed_clause_eval_op`,
-    matmul-rate compute for throughput batches.  ``wt`` defaults to 8
-    words (a 256-wide contraction per grid step)."""
+    matmul-rate compute for throughput batches.  ``wt`` words are
+    contracted per grid step (the whole row when W <= wt)."""
     if backend == "ref":
         return ref.packed_clause_mxu_ref(packed_literals, packed_include,
                                          eval_mode, n_bits=n_bits)
@@ -313,6 +327,7 @@ def packed_clause_mxu_op(packed_literals, packed_include, eval_mode=False,
         packed_include = ref.tail_mask_words(packed_include, n_bits)
     B, W = packed_literals.shape
     C = packed_include.shape[0]
+    wt = lane_tile(W, wt)
     lit = _pad2(packed_literals, bt, wt)
     inc = _pad2(packed_include, yt, wt)
     out = packed_clause_eval_mxu(lit, inc, eval_mode=eval_mode, bt=bt,
@@ -610,7 +625,7 @@ def packed_step_op(packed_literals, packed_include, weights, labels,
     if mxu:
         cl = packed_clause_mxu_op(packed_literals, packed_include,
                                   eval_mode=False, n_bits=n_bits, bt=bt,
-                                  yt=yt, wt=min(wt, 8))
+                                  yt=yt, wt=wt)
     else:
         cl = packed_clause_eval_op(packed_literals, packed_include,
                                    eval_mode=False, n_bits=n_bits, bt=bt,

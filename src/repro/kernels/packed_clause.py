@@ -36,7 +36,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+from .tpu_params import block_bytes, compiler_params
+
+
+def _row_any(words):
+    """[n, w] uint32 -> [n, 1] int32: 1 iff any word of the row is
+    nonzero (an OR-reduction expressed as the max-reduce Mosaic lowers)."""
+    return jnp.max((words != 0).astype(jnp.int32), axis=1, keepdims=True)
 
 
 def _kernel(lit_ref, inc_ref, out_ref, viol_ref, ne_ref, *,
@@ -54,19 +60,17 @@ def _kernel(lit_ref, inc_ref, out_ref, viol_ref, ne_ref, *,
     # a tile of zero include words can neither violate nor fire-gate, so
     # the whole per-batch violation loop is skipped (exclude-dominated
     # clauses are the common converged case; Fig 4-6 frugality)
-    col_or = jnp.bitwise_or.reduce(inc, axis=1, keepdims=True)  # [yt, 1]
+    nonempty = _row_any(inc).T                         # [1, yt]
 
-    @pl.when(jnp.any(col_or != 0))
+    @pl.when(jnp.max(nonempty) > 0)
     def _accumulate():
-        ne_ref[...] |= col_or.T
-        lit = lit_ref[...]                             # [bt, wt] uint32
-
-        def body(b, viol):
-            v = jnp.bitwise_and(inc, jnp.bitwise_not(lit[b])[None, :])
-            row = jnp.bitwise_or.reduce(v, axis=1)     # [yt]
-            return viol.at[b, :].set(viol[b, :] | row)
-
-        viol_ref[...] = jax.lax.fori_loop(0, batch_tile, body, viol_ref[...])
+        ne_ref[...] = jnp.maximum(ne_ref[...], nonempty)
+        # static batch loop: row b of the literal tile against the whole
+        # include tile, the per-clause OR landing in row b of viol
+        for b in range(batch_tile):
+            v = jnp.bitwise_and(inc, jnp.bitwise_not(lit_ref[b:b + 1, :]))
+            viol_ref[b:b + 1, :] = jnp.maximum(viol_ref[b:b + 1, :],
+                                               _row_any(v).T)
 
     @pl.when(k == n_k - 1)
     def _finish():
@@ -104,6 +108,8 @@ def packed_clause_eval(packed_literals: jax.Array, packed_include: jax.Array,
     assert W == W2 and B % bt == 0 and C % yt == 0 and W % wt == 0, (
         (B, C, W), (bt, yt, wt))
     grid = (B // bt, C // yt, W // wt)
+    need = (block_bytes(((bt, wt), 4), ((yt, wt), 4), ((bt, yt), 4))
+            + (bt * yt + yt) * 4)
     return pl.pallas_call(
         functools.partial(_kernel, batch_tile=bt, n_k=grid[2],
                           eval_mode=eval_mode),
@@ -115,25 +121,24 @@ def packed_clause_eval(packed_literals: jax.Array, packed_include: jax.Array,
         out_specs=pl.BlockSpec((bt, yt), lambda b, c, k: (b, c)),
         out_shape=jax.ShapeDtypeStruct((B, C), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((bt, yt), jnp.uint32),
-            pltpu.VMEM((1, yt), jnp.uint32),
+            pltpu.VMEM((bt, yt), jnp.int32),
+            pltpu.VMEM((1, yt), jnp.int32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"), need),
         interpret=interpret,
     )(packed_literals.astype(jnp.uint32), packed_include.astype(jnp.uint32))
 
 
-def _unpack_i8(words, wt: int):
-    """[n, wt] uint32 -> [n, wt*32] int8 bitplanes, bit j of word w landing
-    at column w*32+j (== ref.unpack_bitplanes_i8; stays in VMEM)."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-    bits = (words[:, :, None] >> shifts) & jnp.uint32(1)
-    return bits.astype(jnp.int8).reshape(words.shape[0], wt * 32)
+def _bitplane(words, j: int):
+    """Bit ``j`` of every word, as an int8 {0,1} plane of the same shape
+    (stays in VMEM; the bit arithmetic runs at 32 bits — the v5e VPU has
+    no int8 ALU — and only the finished plane narrows to int8)."""
+    return ((words >> jnp.uint32(j)) & jnp.uint32(1)).astype(jnp.int8)
 
 
 def _mxu_kernel(lit_ref, inc_ref, out_ref, viol_ref, ne_ref, *,
-                wt: int, n_k: int, eval_mode: bool):
+                n_k: int, eval_mode: bool):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -142,16 +147,21 @@ def _mxu_kernel(lit_ref, inc_ref, out_ref, viol_ref, ne_ref, *,
         ne_ref[...] = jnp.zeros_like(ne_ref)
 
     inc = inc_ref[...]                                 # [yt, wt] uint32
-    ne_ref[...] |= jnp.bitwise_or.reduce(inc, axis=1, keepdims=True).T
-    # violations as an int8 matmul: (1 - lit_bits) [bt, wt*32] ·
-    # inc_bits^T [wt*32, yt] — zero-padded words contribute nothing on
-    # either side, so the padded geometry is harmless.
-    lit_b = _unpack_i8(lit_ref[...], wt)               # [bt, wt*32] int8
-    inc_b = _unpack_i8(inc, wt)                        # [yt, wt*32] int8
-    viol_ref[...] += jax.lax.dot_general(
-        (1 - lit_b), inc_b,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    lit = lit_ref[...]                                 # [bt, wt] uint32
+    if eval_mode:
+        ne_ref[...] = jnp.maximum(ne_ref[...], _row_any(inc).T)
+    # violations as int8 matmuls, one per bit position j:
+    # Σ_j (~lit)_j [bt, wt] · inc_jᵀ [wt, yt], with (~lit)_j = 1 - lit_j.
+    # The count sums over every (word, bit) pair, so walking the
+    # bitplanes plane-major gives the same total as the word-major
+    # expansion; zero-padded words contribute nothing on either side.
+    acc = viol_ref[...]
+    for j in range(32):
+        acc += jax.lax.dot_general(
+            _bitplane(~lit, j), _bitplane(inc, j),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32)
+    viol_ref[...] = acc
 
     @pl.when(k == n_k - 1)
     def _finish():
@@ -166,13 +176,13 @@ def _mxu_kernel(lit_ref, inc_ref, out_ref, viol_ref, ne_ref, *,
 def packed_clause_eval_mxu(packed_literals: jax.Array,
                            packed_include: jax.Array,
                            eval_mode: bool = False, bt: int = 8,
-                           yt: int = 128, wt: int = 8,
+                           yt: int = 128, wt: int = 128,
                            interpret: bool | None = None) -> jax.Array:
     """MXU popcount leg: same contract as :func:`packed_clause_eval`
     (packed [B, W] × [C, W] uint32 -> clause [B, C] int32, identical tail-
     bit obligations), violations computed as int8 dot products over
-    in-register bitplane expansions.  ``wt`` defaults to 8 words = a
-    256-wide int8 contraction per grid step."""
+    in-register bitplane expansions: 32 int8 dot products per grid step,
+    one per bit position, each contracting ``wt`` words."""
     if interpret is None:
         from .ops import resolve_interpret     # local: ops imports us
         interpret = resolve_interpret()
@@ -181,8 +191,10 @@ def packed_clause_eval_mxu(packed_literals: jax.Array,
     assert W == W2 and B % bt == 0 and C % yt == 0 and W % wt == 0, (
         (B, C, W), (bt, yt, wt))
     grid = (B // bt, C // yt, W // wt)
+    need = (block_bytes(((bt, wt), 4), ((yt, wt), 4), ((bt, yt), 4))
+            + (bt * yt + yt) * 4)
     return pl.pallas_call(
-        functools.partial(_mxu_kernel, wt=wt, n_k=grid[2],
+        functools.partial(_mxu_kernel, n_k=grid[2],
                           eval_mode=eval_mode),
         grid=grid,
         in_specs=[
@@ -193,9 +205,9 @@ def packed_clause_eval_mxu(packed_literals: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, C), jnp.int32),
         scratch_shapes=[
             pltpu.VMEM((bt, yt), jnp.int32),
-            pltpu.VMEM((1, yt), jnp.uint32),
+            pltpu.VMEM((1, yt), jnp.int32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"), need),
         interpret=interpret,
     )(packed_literals.astype(jnp.uint32), packed_include.astype(jnp.uint32))

@@ -37,20 +37,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 from .ref import stream_advance, stream_start
+from .tpu_params import block_bytes, compiler_params
+
+# per-(datapoint, clause) feedback flags, packed into one int32 code so the
+# batch loop reads a single row per step: bit 0 = clause fired, bit 1 =
+# Type I feedback, bit 2 = Type II feedback
+_CL, _T1, _T2 = 1, 2, 4
 
 
-def _tile_delta(b, rand, lit, cl, t1, t2, include, p_ta, boost, delta):
-    """One batch element's Alg-5 delta accumulation on a (yt, xt) tile."""
+def feedback_code(clause_out, type1, type2) -> jax.Array:
+    """[B, C] {0,1} clause/Type-I/Type-II planes -> one int32 [B, C]
+    code plane (bits :data:`_CL`, :data:`_T1`, :data:`_T2`)."""
+    return ((clause_out > 0).astype(jnp.int32) * _CL
+            + (type1 > 0).astype(jnp.int32) * _T1
+            + (type2 > 0).astype(jnp.int32) * _T2)
+
+
+def _tile_delta(rand, lit_row, code_col, include, p_ta, boost, delta):
+    """One batch element's Alg-5 delta accumulation on a (yt, xt) tile.
+
+    ``lit_row`` [1, xt] is the element's literal row; ``code_col``
+    [yt, 1] its per-clause feedback code (:func:`feedback_code`)."""
     low = rand < p_ta                                 # P = 1/s
-    clb = (cl[b] > 0)[:, None]                        # [yt, 1]
-    litb = (lit[b] > 0)[None, :]                      # [1, xt]
-    t1b = (t1[b] > 0)[:, None]
-    t2b = (t2[b] > 0)[:, None]
+    clb = (code_col & _CL) != 0                       # [yt, 1]
+    litb = lit_row > 0                                # [1, xt]
+    t1b = (code_col & _T1) != 0
+    t2b = (code_col & _T2) != 0
     cl_and_lit = jnp.logical_and(clb, litb)
-    inc1 = jnp.where(boost, cl_and_lit,
-                     jnp.logical_and(cl_and_lit, jnp.logical_not(low)))
+    # boost: always +1 on cl∧lit; else w.p. (s-1)/s (boolean algebra, not
+    # a select — Mosaic has no select on i1 vectors)
+    inc1 = jnp.logical_and(cl_and_lit,
+                           jnp.logical_or(boost, jnp.logical_not(low)))
     dec1 = jnp.logical_and(jnp.logical_not(cl_and_lit), low)
     d1 = inc1.astype(jnp.int32) - dec1.astype(jnp.int32)
     inc2 = jnp.logical_and(jnp.logical_and(clb, jnp.logical_not(litb)),
@@ -58,9 +76,16 @@ def _tile_delta(b, rand, lit, cl, t1, t2, include, p_ta, boost, delta):
     return delta + jnp.where(t1b, d1, 0) + jnp.where(t2b, inc2, 0)
 
 
-def _tile_update(ci, li, ta_ref, lit_ref, cl_ref, t1_ref, t2_ref, lmask_ref,
-                 params_ref, out_ref, *, batch: int, n_l_tiles: int, yt: int,
-                 xt: int, rand_bits: int, prng: str = "counter",
+def _batch_rows(lit_ref, code_ref, b):
+    """Row ``b`` of the literal block ([1, xt]) and of the feedback-code
+    block, turned into a clause column ([yt, 1]) — ref-level dynamic
+    sublane reads, the form Mosaic lowers."""
+    return lit_ref[pl.ds(b, 1), :], code_ref[pl.ds(b, 1), :].T
+
+
+def _tile_update(ci, li, ta_ref, lit_ref, code_ref, lmask_ref, params_ref,
+                 out_ref, *, batch: int, n_l_tiles: int, yt: int, xt: int,
+                 rand_bits: int, prng: str = "counter",
                  lfsr_bits: int = 24, seed_refresh: bool = True):
     """Shared (yt, xt) TA-tile update body.
 
@@ -83,48 +108,45 @@ def _tile_update(ci, li, ta_ref, lit_ref, cl_ref, t1_ref, t2_ref, lmask_ref,
     boost = params_ref[0, 2] > 0
     n_states = params_ref[0, 3].astype(jnp.int32)
     row0 = params_ref[0, 4]
-    ta = ta_ref[...].astype(jnp.int32)                    # [yt, xt]
+    ta = ta_ref[...]                                      # [yt, xt] int32
     include = ta >= (n_states >> 1)
 
     # per-element stream keyed on GLOBAL element index — the result is
     # tile-layout independent (ref.py reproduces it exactly).
-    gy = (ci * yt + row0
+    gy = (ci.astype(jnp.uint32) * yt + row0
           + jax.lax.broadcasted_iota(jnp.uint32, (yt, xt), 0))
-    gx = li * xt + jax.lax.broadcasted_iota(jnp.uint32, (yt, xt), 1)
+    gx = (li.astype(jnp.uint32) * xt
+          + jax.lax.broadcasted_iota(jnp.uint32, (yt, xt), 1))
     key = gy * jnp.uint32(n_l_tiles * xt) + gx
     st0 = stream_start(seed, key, prng, lfsr_bits)
-
-    delta = jnp.zeros((yt, xt), jnp.int32)
-    lit = lit_ref[...]                                    # [B, xt] int8
-    cl = cl_ref[...]                                      # [B, yt] int8
-    t1 = t1_ref[...]                                      # [B, yt] int8
-    t2 = t2_ref[...]                                      # [B, yt] int8
 
     def body(b, carry):
         st, delta = carry
         st, rand = stream_advance(st, key, prng, lfsr_bits, seed_refresh,
                                   rand_bits)
-        delta = _tile_delta(b, rand, lit, cl, t1, t2, include, p_ta, boost,
+        lit_row, code_col = _batch_rows(lit_ref, code_ref, b)
+        delta = _tile_delta(rand, lit_row, code_col, include, p_ta, boost,
                             delta)
         return st, delta
 
+    delta = jnp.zeros((yt, xt), jnp.int32)
     _, delta = jax.lax.fori_loop(0, batch, body, (st0, delta))
-    delta = delta * lmask_ref[...].astype(jnp.int32)      # Fig 6a inverse mask
+    delta = delta * lmask_ref[...]                        # Fig 6a inverse mask
     out_ref[...] = jnp.clip(ta + delta, 0, n_states - 1)
 
 
-def _kernel(ta_ref, lit_ref, cl_ref, t1_ref, t2_ref, lmask_ref, params_ref,
-            out_ref, *, batch: int, n_l_tiles: int, yt: int, xt: int,
-            rand_bits: int, prng: str, lfsr_bits: int, seed_refresh: bool):
+def _kernel(ta_ref, lit_ref, code_ref, lmask_ref, params_ref, out_ref, *,
+            batch: int, n_l_tiles: int, yt: int, xt: int, rand_bits: int,
+            prng: str, lfsr_bits: int, seed_refresh: bool):
     _tile_update(pl.program_id(0), pl.program_id(1), ta_ref, lit_ref,
-                 cl_ref, t1_ref, t2_ref, lmask_ref, params_ref, out_ref,
+                 code_ref, lmask_ref, params_ref, out_ref,
                  batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
                  rand_bits=rand_bits, prng=prng, lfsr_bits=lfsr_bits,
                  seed_refresh=seed_refresh)
 
 
-def _sparse_kernel(idx_ref, params_ref, ta_ref, lit_ref, cl_ref, t1_ref,
-                   t2_ref, lmask_ref, out_ref, *, batch: int, n_l_tiles: int,
+def _sparse_kernel(idx_ref, params_ref, ta_ref, lit_ref, code_ref,
+                   lmask_ref, out_ref, *, batch: int, n_l_tiles: int,
                    yt: int, xt: int, rand_bits: int, prng: str,
                    lfsr_bits: int, seed_refresh: bool):
     """Compacted grid step: slot ``program_id(0)`` owns the ACTIVE clause
@@ -133,15 +155,14 @@ def _sparse_kernel(idx_ref, params_ref, ta_ref, lit_ref, cl_ref, t1_ref,
     PRNG stream is keyed on the original tile coordinates, so the update
     is bit-identical to the dense kernel's for that tile."""
     _tile_update(idx_ref[pl.program_id(0)], pl.program_id(1), ta_ref,
-                 lit_ref, cl_ref, t1_ref, t2_ref, lmask_ref, params_ref,
-                 out_ref, batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
+                 lit_ref, code_ref, lmask_ref, params_ref, out_ref,
+                 batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
                  rand_bits=rand_bits, prng=prng, lfsr_bits=lfsr_bits,
                  seed_refresh=seed_refresh)
 
 
-def _streamed_kernel(ta_ref, lit_ref, cl_ref, t1_ref, t2_ref, lmask_ref,
-                     rand_ref, params_ref, out_ref, *, batch: int, yt: int,
-                     xt: int):
+def _streamed_kernel(ta_ref, lit_ref, code_ref, lmask_ref, rand_ref,
+                     params_ref, out_ref, *, batch: int, yt: int, xt: int):
     """Streamed-rand baseline: the same tile body, but the randoms arrive
     as a pre-materialised [B, yt, xt] uint32 block from HBM
     (ref.ta_rand_stream) — exactly the traffic the in-kernel generator
@@ -151,21 +172,28 @@ def _streamed_kernel(ta_ref, lit_ref, cl_ref, t1_ref, t2_ref, lmask_ref,
     p_ta = params_ref[0, 1]
     boost = params_ref[0, 2] > 0
     n_states = params_ref[0, 3].astype(jnp.int32)
-    ta = ta_ref[...].astype(jnp.int32)                    # [yt, xt]
+    ta = ta_ref[...]                                      # [yt, xt] int32
     include = ta >= (n_states >> 1)
-    delta = jnp.zeros((yt, xt), jnp.int32)
-    lit = lit_ref[...]
-    cl = cl_ref[...]
-    t1 = t1_ref[...]
-    t2 = t2_ref[...]
 
     def body(b, delta):
-        return _tile_delta(b, rand_ref[b], lit, cl, t1, t2, include, p_ta,
+        lit_row, code_col = _batch_rows(lit_ref, code_ref, b)
+        return _tile_delta(rand_ref[b], lit_row, code_col, include, p_ta,
                            boost, delta)
 
-    delta = jax.lax.fori_loop(0, batch, body, delta)
-    delta = delta * lmask_ref[...].astype(jnp.int32)
+    delta = jax.lax.fori_loop(0, batch, body,
+                              jnp.zeros((yt, xt), jnp.int32))
+    delta = delta * lmask_ref[...]
     out_ref[...] = jnp.clip(ta + delta, 0, n_states - 1)
+
+
+def _vmem_need(B: int, yt: int, xt: int, rands: bool = False) -> int:
+    """Per-step VMEM of the TA-update kernels: TA tile in + out, literal
+    rows, feedback codes, l_mask (+ the streamed rand block)."""
+    blocks = [((yt, xt), 4), ((B, xt), 4), ((B, yt), 4), ((1, xt), 4),
+              ((yt, xt), 4)]
+    if rands:
+        blocks.append(((B, yt, xt), 4))
+    return block_bytes(*blocks)
 
 
 def _params(seed, p_ta, boost, n_states, row0):
@@ -229,8 +257,6 @@ def ta_update_sparse(ta: jax.Array, literals: jax.Array,
             pl.BlockSpec((yt, xt), lambda c, l, idx, prm: (idx[c], l)),
             pl.BlockSpec((B, xt), lambda c, l, idx, prm: (0, l)),
             pl.BlockSpec((B, yt), lambda c, l, idx, prm: (0, idx[c])),
-            pl.BlockSpec((B, yt), lambda c, l, idx, prm: (0, idx[c])),
-            pl.BlockSpec((B, yt), lambda c, l, idx, prm: (0, idx[c])),
             pl.BlockSpec((1, xt), lambda c, l, idx, prm: (0, l)),
         ],
         out_specs=pl.BlockSpec((yt, xt), lambda c, l, idx, prm: (c, l)),
@@ -241,13 +267,13 @@ def ta_update_sparse(ta: jax.Array, literals: jax.Array,
                           lfsr_bits=lfsr_bits, seed_refresh=seed_refresh),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((k * yt, L), jnp.int32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=compiler_params(("parallel", "parallel"),
+                                        _vmem_need(B, yt, xt)),
         interpret=interpret,
     )(tile_idx.astype(jnp.int32), params,
-      ta.astype(jnp.int32), literals.astype(jnp.int8),
-      clause_out.astype(jnp.int8), type1.astype(jnp.int8),
-      type2.astype(jnp.int8), l_mask.reshape(1, L).astype(jnp.int32))
+      ta.astype(jnp.int32), literals.astype(jnp.int32),
+      feedback_code(clause_out, type1, type2),
+      l_mask.reshape(1, L).astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("rand_bits", "yt", "xt",
@@ -290,22 +316,19 @@ def ta_update(ta: jax.Array, literals: jax.Array, clause_out: jax.Array,
         in_specs=[
             pl.BlockSpec((yt, xt), lambda c, l: (c, l)),       # ta
             pl.BlockSpec((B, xt), lambda c, l: (0, l)),        # literals
-            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # clause_out
-            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # type1
-            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # type2
+            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # fb codes
             pl.BlockSpec((1, xt), lambda c, l: (0, l)),        # l_mask
             pl.BlockSpec((1, 5), lambda c, l: (0, 0),
                          memory_space=pltpu.SMEM),             # scalars
         ],
         out_specs=pl.BlockSpec((yt, xt), lambda c, l: (c, l)),
         out_shape=jax.ShapeDtypeStruct((C, L), jnp.int32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=compiler_params(("parallel", "parallel"),
+                                        _vmem_need(B, yt, xt)),
         interpret=interpret,
-    )(ta.astype(jnp.int32), literals.astype(jnp.int8),
-      clause_out.astype(jnp.int8), type1.astype(jnp.int8),
-      type2.astype(jnp.int8), l_mask.reshape(1, L).astype(jnp.int32),
-      params)
+    )(ta.astype(jnp.int32), literals.astype(jnp.int32),
+      feedback_code(clause_out, type1, type2),
+      l_mask.reshape(1, L).astype(jnp.int32), params)
 
 
 @functools.partial(jax.jit, static_argnames=("yt", "xt", "interpret"))
@@ -336,9 +359,7 @@ def ta_update_streamed(ta: jax.Array, literals: jax.Array,
         in_specs=[
             pl.BlockSpec((yt, xt), lambda c, l: (c, l)),       # ta
             pl.BlockSpec((B, xt), lambda c, l: (0, l)),        # literals
-            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # clause_out
-            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # type1
-            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # type2
+            pl.BlockSpec((B, yt), lambda c, l: (0, c)),        # fb codes
             pl.BlockSpec((1, xt), lambda c, l: (0, l)),        # l_mask
             pl.BlockSpec((B, yt, xt), lambda c, l: (0, c, l)), # rands
             pl.BlockSpec((1, 5), lambda c, l: (0, 0),
@@ -346,10 +367,10 @@ def ta_update_streamed(ta: jax.Array, literals: jax.Array,
         ],
         out_specs=pl.BlockSpec((yt, xt), lambda c, l: (c, l)),
         out_shape=jax.ShapeDtypeStruct((C, L), jnp.int32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=compiler_params(("parallel", "parallel"),
+                                        _vmem_need(B, yt, xt, rands=True)),
         interpret=interpret,
-    )(ta.astype(jnp.int32), literals.astype(jnp.int8),
-      clause_out.astype(jnp.int8), type1.astype(jnp.int8),
-      type2.astype(jnp.int8), l_mask.reshape(1, L).astype(jnp.int32),
-      rands.astype(jnp.uint32), params)
+    )(ta.astype(jnp.int32), literals.astype(jnp.int32),
+      feedback_code(clause_out, type1, type2),
+      l_mask.reshape(1, L).astype(jnp.int32), rands.astype(jnp.uint32),
+      params)

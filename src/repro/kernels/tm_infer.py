@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+from .class_sum import N_LIMBS, limb_dot, weight_limbs
+from .tpu_params import block_bytes, compiler_params
 
 
 def _kernel(neg_lit_ref, inc_ref, w_ref, out_ref, viol_ref, cnt_ref, acc_ref,
@@ -40,23 +41,20 @@ def _kernel(neg_lit_ref, inc_ref, w_ref, out_ref, viol_ref, cnt_ref, acc_ref,
         viol_ref[...] = jnp.zeros_like(viol_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    neg = neg_lit_ref[...].astype(jnp.int32)          # [bt, xt]
-    inc = inc_ref[...].astype(jnp.int32)              # [yt, xt]
+    inc = inc_ref[...]                                # [yt, xt] int8
     viol_ref[...] += jax.lax.dot_general(
-        neg, inc, dimension_numbers=(((1,), (1,)), ((), ())),
+        neg_lit_ref[...], inc, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32)             # [bt, yt]
-    cnt_ref[...] += inc.sum(axis=1, keepdims=True).T  # [1, yt]
+    if eval_mode:
+        cnt_ref[...] += inc.astype(jnp.int32).sum(axis=1, keepdims=True).T
 
     @pl.when(k == n_k - 1)
     def _consume_clause_tile():
         fired = viol_ref[...] == 0
         if eval_mode:
             fired = jnp.logical_and(fired, cnt_ref[...] > 0)
-        clause = fired.astype(jnp.int32)              # [bt, yt] — VMEM only
-        w = w_ref[...].astype(jnp.int32)              # [H, yt]
-        acc_ref[...] += jax.lax.dot_general(
-            clause, w, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)         # [bt, H]
+        clause = fired.astype(jnp.int8)               # [bt, yt] — VMEM only
+        acc_ref[...] += limb_dot(clause, w_ref)       # [bt, H]
 
         @pl.when(c == n_c - 1)
         def _emit():
@@ -83,6 +81,9 @@ def tm_infer(literals: jax.Array, include: jax.Array, weights: jax.Array,
                                                          (bt, yt, xt))
     neg = (1 - literals).astype(jnp.int8)
     grid = (B // bt, C // yt, L // xt)
+    need = (block_bytes(((bt, xt), 1), ((yt, xt), 1), ((N_LIMBS, H, yt), 1),
+                        ((bt, H), 4))
+            + (bt * yt + yt + bt * H) * 4)
     return pl.pallas_call(
         functools.partial(_kernel, n_c=grid[1], n_k=grid[2],
                           eval_mode=eval_mode),
@@ -90,7 +91,7 @@ def tm_infer(literals: jax.Array, include: jax.Array, weights: jax.Array,
         in_specs=[
             pl.BlockSpec((bt, xt), lambda b, c, k: (b, k)),
             pl.BlockSpec((yt, xt), lambda b, c, k: (c, k)),
-            pl.BlockSpec((H, yt), lambda b, c, k: (0, c)),
+            pl.BlockSpec((N_LIMBS, H, yt), lambda b, c, k: (0, 0, c)),
         ],
         out_specs=pl.BlockSpec((bt, H), lambda b, c, k: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H), jnp.int32),
@@ -99,7 +100,7 @@ def tm_infer(literals: jax.Array, include: jax.Array, weights: jax.Array,
             pltpu.VMEM((1, yt), jnp.int32),
             pltpu.VMEM((bt, H), jnp.int32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), need),
         interpret=interpret,
-    )(neg, include.astype(jnp.int8), weights.astype(jnp.int32))
+    )(neg, include.astype(jnp.int8), weight_limbs(weights))

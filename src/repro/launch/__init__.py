@@ -1,9 +1,9 @@
 """Launch layer: mesh construction, perf models, and the DTM server."""
 from .mesh import (make_production_mesh, make_host_mesh, HardwareModel,
-                   V5E, mesh_chips, data_axes)
+                   PEAKS, V5E, hardware_model, mesh_chips, data_axes)
 
 __all__ = ["make_production_mesh", "make_host_mesh", "HardwareModel",
-           "V5E", "mesh_chips", "data_axes"]
+           "PEAKS", "V5E", "hardware_model", "mesh_chips", "data_axes"]
 
 # NOTE: the multi-tenant DTM server lives in repro.launch.serve_tm and
 # the async continuous-batching runtime in repro.launch.scheduler

@@ -413,6 +413,11 @@ class TMServer:
                     self._dirty.discard(n)
         return names, bank
 
+    def bank(self, conv: bool = False) -> ProgramBank:
+        """The resident bank of one stage family (built on first use) —
+        a :class:`repro.launch.pod.PodBank` in pod mode."""
+        return self._bank_for(conv)[1]
+
     def enqueue(self, name: str, x, encoded: bool = False) -> None:
         """Queue an inference request for the next stacked flush."""
         tenant = self.tenants[name]
@@ -761,6 +766,8 @@ def main(argv=None):
     ap.add_argument("--rounds", type=int, default=None)
     ap.add_argument("--out", default="BENCH_reconfig.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     rounds = args.rounds if args.rounds is not None else (
         4 if args.smoke else 16)
     rep = reconfig_benchmark(backend=args.backend,

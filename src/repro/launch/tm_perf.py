@@ -1,8 +1,8 @@
 """Analytic TM-datapath performance model (TPU v5e roofline terms).
 
-The benchmark harness reports interpret-mode wall-clock off-TPU, so the
-hardware-model figures here are the numbers EXPERIMENTS.md tracks across
-kernel iterations: analytic op counts / v5e roofline seconds.  Centralised
+Analytic op counts over the peaks of the device in use
+(``mesh.hardware_model``; CPU runs rehearse with the v5e row) — model
+figures, never measurements.  Centralised
 in launch/ (next to the LM flops model) so the per-figure benchmark modules
 don't each carry their own copy.
 
@@ -21,12 +21,14 @@ construction and the fused kernel eliminates on TPU.
 """
 from __future__ import annotations
 
-from .mesh import V5E
+from .mesh import hardware_model
 
 
 def roofline_s(flops: float, bytes_: float) -> float:
-    """Seconds at the v5e roofline: max(compute term, HBM term)."""
-    return max(flops / V5E.peak_flops_bf16, bytes_ / V5E.hbm_bw)
+    """Seconds at the device's roofline (``mesh.hardware_model``; the
+    v5e row on CPU): max(compute term, HBM term)."""
+    hw = hardware_model()
+    return max(flops / hw.peak_flops_bf16, bytes_ / hw.hbm_bw)
 
 
 def train_front_costs(B: int, L: int, C: int, H: int) -> dict:
@@ -80,7 +82,7 @@ def clause_shard_step_s(B: int, L: int, C: int, H: int,
     local = train_front_costs(B, L, max(C // shards, 1), H)
     psum_bytes = (0 if shards <= 1
                   else 2 * (shards - 1) / shards * B * H * 4)
-    ici_s = psum_bytes / V5E.collective_bw()
+    ici_s = psum_bytes / hardware_model().collective_bw()
     return {
         "local_s": local["fused_roofline_s"],
         "psum_bytes": psum_bytes,
@@ -107,16 +109,17 @@ def packed_eval_costs(B: int, L: int, C: int) -> dict:
     runs ~1/128 occupied and the VPU wins; by B≳32 the matmul recast is
     far ahead.  Returned seconds are v5e figures — autotune's measure
     mode replaces them with wall-clock on the actual device."""
+    hw = hardware_model()
     W = (L + 31) // 32
     io = clause_eval_bytes(B, L, C, packed=True)["total_bytes"]
-    # VPU: 8x128 lanes × ~0.94 GHz ≈ 1e12 uint32 word-ops/s
+    # VPU: one uint32 word op per (b, c, w) at the VPU word rate
     vpu_word_ops = B * C * W
-    vpu_s = max(vpu_word_ops / 1.0e12, io / V5E.hbm_bw)
-    # MXU: int8 throughput ≈ 2× bf16 peak, scaled by row occupancy
+    vpu_s = max(vpu_word_ops / hw.vpu_word_ops, io / hw.hbm_bw)
+    # MXU: int8 peak, scaled by row occupancy
     mxu_ops = 2 * B * C * (W * 32)
     occupancy = min(B, 128) / 128
-    mxu_s = max(mxu_ops / (2 * V5E.peak_flops_bf16 * max(occupancy, 1e-9)),
-                io / V5E.hbm_bw)
+    mxu_s = max(mxu_ops / (hw.peak_int8_ops * max(occupancy, 1e-9)),
+                io / hw.hbm_bw)
     return {
         "bytes": io,
         "vpu_word_ops": vpu_word_ops,
@@ -135,7 +138,7 @@ def ta_rand_bytes(B: int, L: int, C: int) -> dict:
     master seed (one SMEM scalar)."""
     streamed = B * C * L * 4
     return {"streamed_rand_bytes": streamed, "inkernel_rand_bytes": 0,
-            "streamed_rand_s": streamed / V5E.hbm_bw}
+            "streamed_rand_s": streamed / hardware_model().hbm_bw}
 
 
 def clause_eval_bytes(B: int, L: int, C: int, packed: bool) -> dict:

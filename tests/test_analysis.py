@@ -283,6 +283,22 @@ def test_kernel_checker_catches_broken_maps():
     assert any("non-affine" in v.detail for v in check_plan(nonaff))
 
 
+def test_kernel_checker_catches_untiled_block():
+    """The (8, 8) block on a [32, 56] packed-literal array that the
+    seed's ``wt=8`` tiles handed out: it divides its array, fits VMEM,
+    and still cannot lower — Mosaic takes (8, 128)-aligned blocks or
+    whole dims only."""
+    from repro.analysis import kernel_check
+    plan = kernel_check.plan_packed_clause(32, 1792, 2048, wt=8)
+    bad = kernel_check.check_plan(plan)
+    assert {v.kind for v in bad} == {"tiling"}
+    assert any("plits" in v.detail and "block 8" in v.detail
+               and "dim 56" in v.detail for v in bad)
+    # a word tile at least as wide as the row becomes the whole row
+    assert kernel_check.check_plan(
+        kernel_check.plan_packed_clause(32, 1792, 2048, wt=128)) == []
+
+
 # --------------------------------------------------------------------------- #
 # trace-contract audit                                                        #
 # --------------------------------------------------------------------------- #

@@ -61,7 +61,7 @@ def test_compressed_psum_shardmap():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.runtime.compression import compressed_psum
-        # the version-portable wrapper distributed.py resolves ONCE
+        # jax.shard_map with vma checking off, as the engine uses it
         from repro.core.distributed import shard_map
         mesh = jax.make_mesh((8,), ("data",))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 128)),

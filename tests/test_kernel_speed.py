@@ -23,6 +23,7 @@ The three optimisations must be pure wall-clock changes — never semantic:
   training with threefry.
 """
 import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -283,6 +284,45 @@ def test_packed_eval_costs_roofline():
     r = ta_rand_bytes(8, 1024, 512)
     assert r["streamed_rand_bytes"] == 8 * 512 * 1024 * 4
     assert r["inkernel_rand_bytes"] == 0
+
+
+def test_peaks_keyed_by_device_kind():
+    """The roofline reads the peaks row of the device in use: CPU runs
+    rehearse with the v5e row by name, an accelerator kind without a row
+    raises instead of borrowing another chip's peaks."""
+    from types import SimpleNamespace
+    from repro.launch.mesh import PEAKS, V5E, hardware_model
+    assert hardware_model() is V5E                # this CPU host
+    assert PEAKS["TPU v5 lite"] is V5E and V5E.source
+    chip = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert hardware_model(chip) is V5E
+    with pytest.raises(ValueError, match="no peaks"):
+        hardware_model(SimpleNamespace(platform="tpu",
+                                       device_kind="TPU v0 imaginary"))
+
+
+def test_compile_cache_placement(tmp_path):
+    """The compile cache goes where JAX_COMPILATION_CACHE_DIR put it (JAX
+    reads the variable into jax.config), else to one fixed directory in
+    the checkout; every compile is cached however quick."""
+    from repro.launch import compile_cache
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        jax.config.update("jax_compilation_cache_dir", None)
+        path = compile_cache.use_compile_cache()
+        assert path == str(compile_cache.DEFAULT_DIR)
+        assert compile_cache.DEFAULT_DIR.parent == (
+            pathlib.Path(__file__).resolve().parents[1])
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
 
 
 # ---------------------------------------------------------------------------
