@@ -77,11 +77,12 @@ print(f"\nserved {stats['completed']}/{stats['submitted']} requests in "
       f"(promotions={stats['promotions']} demotions={stats['demotions']})")
 print(f"resident now: {sched.server.resident_names()}  "
       f"cold-path requests: {stats['server']['cold_requests']}")
+print(f"mean queue wait: {1e3 * stats['infer_queue_wait_s'] / stats['infer_formed']:.2f} ms "
+      f"over {stats['infer_formed']} requests taken into batches")
 print("\nper-tenant:")
 for name, st in stats["tenants"].items():
     print(f"  {name:12s} sla={st['sla']:8s} completed={st['completed']:3d} "
-          f"ewma={st['ewma_qps']:7.1f}/s resident={st['resident']} "
-          f"last_latency={st['last_latency_ms']}ms")
+          f"ewma={st['ewma_qps']:7.1f}/s resident={st['resident']}")
 print("\nfull stats:")
 print(json.dumps(stats, indent=2, default=str))
 

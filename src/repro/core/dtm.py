@@ -71,6 +71,8 @@ from repro.kernels import ops as kops
 # Fig 6d: remainder class sums pinned to min (shared with the kernels)
 from repro.kernels.ref import NEG_INF_SUM as _NEG_INF_SUM
 from repro.kernels.ref import pack_include as _pack_include
+from repro.runtime import spans
+from repro.runtime.spans import span
 from .booleanize import pack_literals, unpack_literals
 from .evaluate import epoch_record
 from .prng import PRNG
@@ -1094,7 +1096,8 @@ class DTMEngine:
                             jnp.uint32(seed + 1 if seed + 1 else 0xC0FFEE))
         session = TMSession(self, program, prng, spec=spec)
         if x is not None:
-            session.stage(x, y)
+            with span(spans.FIT_BIND):
+                session.stage(x, y)
         return session
 
     # ------------------------------------------------------------------ #
@@ -1299,24 +1302,28 @@ class TMSession:
                else self.engine._fit_epoch)
         history = []
         for ep in range(epochs):
-            idx = rng.permutation(self.n)[:n].astype(np.int32)
-            # the epoch's ONE host->device transition, made explicit so
-            # the whole loop runs under jax.transfer_guard("disallow")
-            # (analysis/trace_audit.py) — an implicit transfer sneaking
-            # into the scan launch would fail the audit
-            plan = jax.device_put(idx.reshape(steps, batch))
-            self.program, self.prng, step_stats = fit(
-                self.program, self.prng, self._lits, self._labels, plan)
+            with span(spans.FIT_PLAN, epoch=ep):
+                idx = rng.permutation(self.n)[:n].astype(np.int32)
+                # the epoch's ONE host->device transition, made explicit
+                # so the whole loop runs under
+                # jax.transfer_guard("disallow") (analysis/trace_audit.py)
+                # — an implicit transfer sneaking into the scan launch
+                # would fail the audit
+                plan = jax.device_put(idx.reshape(steps, batch))
+            with span(spans.FIT_EPOCH, epoch=ep):
+                self.program, self.prng, step_stats = fit(
+                    self.program, self.prng, self._lits, self._labels, plan)
             self.dispatches += 1
             self.steps += steps
             # exact integer epoch totals from the per-step stats — the
             # same arithmetic fit_loop does with per-batch Python ints
             # (an in-carry int32 sum could wrap at paper scale); the
             # device_get is the epoch's one explicit device->host read
-            step_stats = jax.device_get(step_stats)
-            agg = {k: int(np.asarray(v).sum(dtype=np.int64))
-                   for k, v in step_stats.items()}
-            rec = epoch_record(ep, agg, n, extra_metrics)
+            with span(spans.FIT_FETCH, epoch=ep):
+                step_stats = jax.device_get(step_stats)
+                agg = {k: int(np.asarray(v).sum(dtype=np.int64))
+                       for k, v in step_stats.items()}
+                rec = epoch_record(ep, agg, n, extra_metrics)
             if score_fn is not None and x_test is not None:
                 rec["test_acc"] = score_fn(x_test, y_test)
             history.append(rec)

@@ -99,8 +99,10 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.launch.serve_tm import TMServer
+from repro.runtime import spans
 from repro.runtime.fault import (InjectedFault, RetryPolicy, StepMonitor,
                                  with_retry)
+from repro.runtime.spans import span
 
 
 class Backpressure(RuntimeError):
@@ -255,7 +257,6 @@ class _TenantState:
     completed: int = 0
     rejected: int = 0
     dwell: int = 10 ** 9         # ticks since last promote/demote
-    last_latency_s: Optional[float] = None
     # ---- online-training stream state (ISSUE 10) ---------------------------
     train_steps: int = 0         # applied training steps (durable cursor)
     skip_ewma: Optional[float] = None   # per-step Alg-6 skip fraction EWMA
@@ -291,6 +292,10 @@ class TMScheduler:
         self._t_last_tick = time.perf_counter()
         self.submitted = self.completed = self.rejected = 0
         self.launches = 0
+        # inference requests taken into a batch, and their summed seconds
+        # from submit to that moment (queue wait = the two's ratio)
+        self.infer_formed = 0
+        self.infer_queue_wait_s = 0.0
         self.promotions = self.demotions = 0
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
@@ -422,7 +427,7 @@ class TMScheduler:
         st = self._tenants[name]
         now = time.perf_counter()
         fut = TMFuture()
-        with self._work:
+        with span(spans.SCHED_SUBMIT), self._work:
             if st.sla.priority <= 0 and now < self._recover_until:
                 st.rejected += 1
                 self.rejected += 1
@@ -459,7 +464,7 @@ class TMScheduler:
         inline (per-tenant FIFO order — bit-identical to the sequential
         path), then dispatch its inference requests un-synced."""
         now = time.perf_counter()
-        with self._work:
+        with self._work, span(spans.SCHED_FORM):
             heads = [(st.queue[0], st.sla.priority)
                      for st in self._tenants.values() if st.queue]
             if not heads:
@@ -471,8 +476,12 @@ class TMScheduler:
                     return False          # keep filling the batch window
             heads.sort(key=lambda h: (h[0].deadline, -h[1], h[0].seq))
             batch = [r for r, _ in heads[:cap]]
+            taken = time.perf_counter()
             for req in batch:
                 self._tenants[req.tenant].queue.popleft()
+                if req.kind == "infer":
+                    self.infer_formed += 1
+                    self.infer_queue_wait_s += taken - req.t_submit
         # device work OUTSIDE the lock: host encode of this batch
         # overlaps whatever launch is still in flight on the device.
         # Training first: a trained tenant's bank slot is dirty and the
@@ -552,15 +561,12 @@ class TMScheduler:
         except (InjectedFault, RuntimeError) as e:
             self._resolve_failed(batch, e)
             return len(batch)
-        now = time.perf_counter()
         # per-flush heartbeat: the collect wall-time feeds the straggler
         # EWMA (stats() surfaces monitor.stragglers)
-        self.monitor.record(now - t0)
+        self.monitor.record(time.perf_counter() - t0)
         for req in batch:
-            st = self._tenants[req.tenant]
-            st.completed += 1
+            self._tenants[req.tenant].completed += 1
             self.completed += 1
-            st.last_latency_s = now - req.t_submit
             req.future.set_result(out[req.tenant])
         return len(batch)
 
@@ -583,11 +589,9 @@ class TMScheduler:
         except (InjectedFault, RuntimeError) as e:
             self._resolve_failed([req], e)
             return
-        now = time.perf_counter()
         with self._work:
             st.completed += 1
             self.completed += 1
-            st.last_latency_s = now - req.t_submit
         req.future.set_result(result)
 
     def _apply_train(self, req: _Request) -> dict:
@@ -667,17 +671,20 @@ class TMScheduler:
         idle).  Returns the number of requests completed.  ``force=False``
         honours the ``max_wait_s`` batch-formation window (the thread
         loop's mode); ``force=True`` launches whatever is queued."""
-        launched = self._launch(force)
-        done = 0
-        while self._in_flight and (
-                len(self._in_flight) > self.cfg.pipeline_depth
-                or (not launched and not self._queued())):
-            done += self._resolve_oldest()
-        self._cycles += 1
-        if (self.cfg.resident_slots is not None
-                and self._cycles % self.cfg.membership_every == 0):
-            self._membership_tick()
-        return done
+        with span(spans.SCHED_CYCLE, cycle=self._cycles):
+            launched = self._launch(force)
+            done = 0
+            while self._in_flight and (
+                    len(self._in_flight) > self.cfg.pipeline_depth
+                    or (not launched and not self._queued())):
+                with span(spans.SCHED_RESOLVE):
+                    done += self._resolve_oldest()
+            self._cycles += 1
+            if (self.cfg.resident_slots is not None
+                    and self._cycles % self.cfg.membership_every == 0):
+                with span(spans.SCHED_MEMBERSHIP):
+                    self._membership_tick()
+            return done
 
     def drain(self) -> int:
         """Run the driver inline until every queued and in-flight
@@ -746,13 +753,15 @@ class TMScheduler:
         while not self._stop.is_set():
             with self._work:
                 if not self._queued() and not self._in_flight:
-                    self._work.wait(self.cfg.idle_wait_s)
+                    with span(spans.SCHED_WAIT):
+                        self._work.wait(self.cfg.idle_wait_s)
                     continue
             before = self.launches
             done = self.step(force=False)
             if done == 0 and self.launches == before:
                 # batch window still filling — don't spin
-                time.sleep(poll)
+                with span(spans.SCHED_WAIT):
+                    time.sleep(poll)
         self.drain()
 
     def stop(self) -> None:
@@ -792,9 +801,6 @@ class TMScheduler:
                     "resident": n in resident,
                     "completed": st.completed,
                     "rejected": st.rejected,
-                    "last_latency_ms":
-                        (None if st.last_latency_s is None
-                         else round(st.last_latency_s * 1e3, 3)),
                     "train_steps": st.train_steps,
                     "paused": st.paused,
                     "probes": st.probes,
@@ -808,6 +814,8 @@ class TMScheduler:
                     "completed": self.completed,
                     "rejected": self.rejected,
                     "launches": self.launches,
+                    "infer_formed": self.infer_formed,
+                    "infer_queue_wait_s": self.infer_queue_wait_s,
                     "in_flight": len(self._in_flight),
                     "promotions": self.promotions,
                     "demotions": self.demotions,

@@ -78,6 +78,8 @@ from repro.api import ProgramBank, TM, TMSpec
 from repro.core.dtm import DTMEngine, DTMProgram
 from repro.core.prng import PRNG
 from repro.launch import pod as _pod
+from repro.runtime import spans
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass
@@ -97,8 +99,6 @@ class PendingFlush:
     stage-family bank (lazy output arrays), ``cold`` one per
     non-resident tenant served through the single-program fallback."""
 
-    t0: float                              # flush_async entry time
-    served: Dict[str, float]               # tenant -> enqueue time
     n_real: Dict[str, int]                 # tenant -> un-padded batch
     hot: list                              # (conv, names, out_a, out_b)
     cold: list                             # (name, sums, cl)
@@ -112,6 +112,12 @@ def _decode_np(spec: TMSpec, sums: np.ndarray, cl: np.ndarray,
         votes = np.clip(cl.sum(-1), 0, t)
         return votes.astype(np.float32) / t
     return np.argmax(sums, axis=-1)
+
+
+def _fetch(a) -> np.ndarray:
+    """One lazy launch output to host: the serving path's host sync."""
+    with span(spans.SERVER_FETCH):
+        return np.asarray(a)
 
 
 class TMServer:
@@ -143,7 +149,7 @@ class TMServer:
         self.swaps = 0
         self.requests = 0
         # stacked (program-major) serving state
-        self._pending: List[Tuple[str, jax.Array, int, float]] = []
+        self._pending: List[Tuple[str, jax.Array, int]] = []
         self._banks: Dict[bool, Tuple[List[str], ProgramBank]] = {}
         self._groups: Dict[bool, List[str]] = {}
         self._decode_info: Dict[str, Tuple[bool, int]] = {}
@@ -155,9 +161,6 @@ class TMServer:
         self._membership: Dict[bool, Optional[List[str]]] = {}
         self.cold_requests = 0
         self.membership_swaps = 0
-        # per-tenant latency of the last flush that served the tenant
-        # (enqueue -> collect wall, seconds)
-        self._last_flush: Dict[str, float] = {}
         # per-tenant Alg-6 skip accounting: device-lazy [active, total]
         # group-count accumulators (summed on the train path with zero
         # extra host syncs; materialised only by stats())
@@ -215,20 +218,23 @@ class TMServer:
                         encoded: bool) -> Tuple[jax.Array, int]:
         """Pad a request to the batch slot and encode it (unless the
         front-end already shipped packed engine literals)."""
-        if encoded:
-            # hot path: a full-slot device array passes straight through
-            # (no eager jnp ops — they dominate small-request latency)
-            if isinstance(x, jax.Array) and x.shape[0] == self.batch_slot:
-                return x, self.batch_slot
-            lits = jnp.asarray(x)
-            n = lits.shape[0]
-            assert n <= self.batch_slot, (n, self.batch_slot)
-            if n < self.batch_slot:
-                pad = jnp.repeat(lits[-1:], self.batch_slot - n, axis=0)
-                lits = jnp.concatenate([lits, pad], axis=0)
-            return lits, n
-        xp, n = self._pad(np.asarray(x))
-        return self.engine.encode(tenant.spec, jnp.asarray(xp)), n
+        with span(spans.SERVER_ENCODE):
+            if encoded:
+                # hot path: a full-slot device array passes straight
+                # through (no eager jnp ops — they dominate small-request
+                # latency)
+                if (isinstance(x, jax.Array)
+                        and x.shape[0] == self.batch_slot):
+                    return x, self.batch_slot
+                lits = jnp.asarray(x)
+                n = lits.shape[0]
+                assert n <= self.batch_slot, (n, self.batch_slot)
+                if n < self.batch_slot:
+                    pad = jnp.repeat(lits[-1:], self.batch_slot - n, axis=0)
+                    lits = jnp.concatenate([lits, pad], axis=0)
+                return lits, n
+            xp, n = self._pad(np.asarray(x))
+            return self.engine.encode(tenant.spec, jnp.asarray(xp)), n
 
     # ---- request paths ----------------------------------------------------
     def predict(self, name: str, x, encoded: bool = False) -> np.ndarray:
@@ -261,38 +267,39 @@ class TMServer:
         pure launch path with no eager encode ops on the driver thread
         (what the trace-contract audit drives under
         ``jax.transfer_guard``)."""
-        tenant = self._swap_to(name)
-        self.requests += 1
-        if encoded:
-            lits, lab = x, y
-            assert lits.shape[0] == self.batch_slot, (
-                f"encoded training request has {lits.shape[0]} examples; "
-                f"batch_slot is {self.batch_slot}")
-        else:
-            xp, yp = np.asarray(x), np.asarray(y)
-            assert xp.shape[0] == self.batch_slot, (
-                f"training request has {xp.shape[0]} examples; batch_slot "
-                f"is {self.batch_slot} — accumulate to a full slot before "
-                "train()")
-            lits = self.engine.encode(tenant.spec, jnp.asarray(xp))
-            lab = tenant.spec.encode_labels(yp)
-        step = self.engine.train_fn(tenant.spec)
-        tenant.program, tenant.prng, stats = step(tenant.program,
-                                                  tenant.prng, lits, lab)
-        # the tenant's bank slot is stale until the next flush swaps the
-        # fresh program back in (hot-swap at bank granularity)
-        self._dirty.add(name)
-        tenant.steps += 1
-        # step stats are device scalars: fetch them ALL in one explicit
-        # transfer so (a) the skip accumulator stays a host counter
-        # instead of a growing lazy device graph and (b) callers (the
-        # scheduler's drift/pause telemetry, the durable writer) get
-        # plain host ints with no further syncs
-        host = {k: int(v) for k, v in jax.device_get(stats).items()}
-        acc = self._skip_acc.setdefault(name, [0, 0])
-        acc[0] = acc[0] + host["active_groups"]
-        acc[1] = acc[1] + host["total_groups"]
-        return host
+        with span(spans.SERVER_TRAIN):
+            tenant = self._swap_to(name)
+            self.requests += 1
+            if encoded:
+                lits, lab = x, y
+                assert lits.shape[0] == self.batch_slot, (
+                    f"encoded training request has {lits.shape[0]} "
+                    f"examples; batch_slot is {self.batch_slot}")
+            else:
+                xp, yp = np.asarray(x), np.asarray(y)
+                assert xp.shape[0] == self.batch_slot, (
+                    f"training request has {xp.shape[0]} examples; "
+                    f"batch_slot is {self.batch_slot} — accumulate to a "
+                    "full slot before train()")
+                lits = self.engine.encode(tenant.spec, jnp.asarray(xp))
+                lab = tenant.spec.encode_labels(yp)
+            step = self.engine.train_fn(tenant.spec)
+            tenant.program, tenant.prng, stats = step(
+                tenant.program, tenant.prng, lits, lab)
+            # the tenant's bank slot is stale until the next flush swaps
+            # the fresh program back in (hot-swap at bank granularity)
+            self._dirty.add(name)
+            tenant.steps += 1
+            # step stats are device scalars: fetch them ALL in one
+            # explicit transfer so (a) the skip accumulator stays a host
+            # counter instead of a growing lazy device graph and (b)
+            # callers (the scheduler's drift/pause telemetry, the durable
+            # writer) get plain host ints with no further syncs
+            host = {k: int(v) for k, v in jax.device_get(stats).items()}
+            acc = self._skip_acc.setdefault(name, [0, 0])
+            acc[0] = acc[0] + host["active_groups"]
+            acc[1] = acc[1] + host["total_groups"]
+            return host
 
     # ---- stacked (program-major) serving ----------------------------------
     def _group_names(self, conv: bool) -> List[str]:
@@ -422,7 +429,7 @@ class TMServer:
         """Queue an inference request for the next stacked flush."""
         tenant = self.tenants[name]
         lits, n = self._encode_request(tenant, x, encoded)
-        self._pending.append((name, lits, n, time.perf_counter()))
+        self._pending.append((name, lits, n))
 
     def abandon_pending(self) -> int:
         """Drop every enqueued-but-unlaunched request (fault recovery:
@@ -444,94 +451,92 @@ class TMServer:
         calls this on a timer."""
         if not self._pending:
             return None
-        pending, self._pending = self._pending, []
-        t0 = time.perf_counter()
-        by_name: Dict[str, Tuple[jax.Array, int, float]] = {}
-        for name, lits, n, t_enq in pending:
-            by_name[name] = (lits, n, t_enq)
-            self.requests += 1
-        hot, cold, claimed = [], [], set()
-        for conv in (False, True):
-            group = self._groups.get(conv)
-            if group is None:
-                group = self._groups[conv] = self._group_names(conv)
-            req_names = [n for n in group if n in by_name]
-            if not req_names:
-                continue
-            claimed.update(req_names)
-            names, bank = self._bank_for(conv)
-            # idle slots replay a pending tenant's literals — their
-            # outputs are dropped, so the filler's values are irrelevant
-            # and no eager zeros/stack ops run (stacking happens in-trace
-            # via the tuple-taking bank executables)
-            filler = by_name[req_names[0]][0]
-            lits = tuple(by_name[n][0] if n in by_name else filler
-                         for n in names)
-            self.stacked_launches += 1
-            self.coalesced_requests += len(req_names)
-            if not conv:
-                # flat banks decode IN-TRACE: two tiny [K, B] planes, no
-                # host argmax, no clause-matrix round trip
-                hot.append((False, list(names)) + tuple(bank.predict(lits)))
-            else:
-                hot.append((True, list(names)) + tuple(bank.infer(lits)))
-        for name in by_name:
-            # requests for tenants OUTSIDE the resident roster (dynamic
-            # bank membership demoted them) fall back to a per-request
-            # single-program launch — the measured cold path
-            if name in claimed:
-                continue
-            tenant = self.tenants[name]
-            sums, cl = self.engine.infer_fn(tenant.spec)(
-                tenant.program, by_name[name][0])
-            self.cold_requests += 1
-            cold.append((name, sums, cl))
-        return PendingFlush(
-            t0=t0,
-            served={n: v[2] for n, v in by_name.items()},
-            n_real={n: v[1] for n, v in by_name.items()},
-            hot=hot, cold=cold)
+        with span(spans.SERVER_LAUNCH):
+            pending, self._pending = self._pending, []
+            by_name: Dict[str, Tuple[jax.Array, int]] = {}
+            for name, lits, n in pending:
+                by_name[name] = (lits, n)
+                self.requests += 1
+            hot, cold, claimed = [], [], set()
+            for conv in (False, True):
+                group = self._groups.get(conv)
+                if group is None:
+                    group = self._groups[conv] = self._group_names(conv)
+                req_names = [n for n in group if n in by_name]
+                if not req_names:
+                    continue
+                claimed.update(req_names)
+                names, bank = self._bank_for(conv)
+                # idle slots replay a pending tenant's literals — their
+                # outputs are dropped, so the filler's values are
+                # irrelevant and no eager zeros/stack ops run (stacking
+                # happens in-trace via the tuple-taking bank executables)
+                filler = by_name[req_names[0]][0]
+                lits = tuple(by_name[n][0] if n in by_name else filler
+                             for n in names)
+                self.stacked_launches += 1
+                self.coalesced_requests += len(req_names)
+                if not conv:
+                    # flat banks decode IN-TRACE: two tiny [K, B] planes,
+                    # no host argmax, no clause-matrix round trip
+                    hot.append((False, list(names))
+                               + tuple(bank.predict(lits)))
+                else:
+                    hot.append((True, list(names)) + tuple(bank.infer(lits)))
+            for name in by_name:
+                # requests for tenants OUTSIDE the resident roster
+                # (dynamic bank membership demoted them) fall back to a
+                # per-request single-program launch — the measured cold
+                # path
+                if name in claimed:
+                    continue
+                tenant = self.tenants[name]
+                sums, cl = self.engine.infer_fn(tenant.spec)(
+                    tenant.program, by_name[name][0])
+                self.cold_requests += 1
+                cold.append((name, sums, cl))
+            return PendingFlush(
+                n_real={n: v[1] for n, v in by_name.items()},
+                hot=hot, cold=cold)
 
     def collect(self, pf: Optional[PendingFlush]) -> Dict[str, np.ndarray]:
         """Fetch phase of :meth:`flush`: materialise a
-        :class:`PendingFlush`'s lazy outputs, decode per tenant, and
-        record per-tenant flush latency.  Returns {tenant: prediction}."""
+        :class:`PendingFlush`'s lazy outputs and decode per tenant.
+        Returns {tenant: prediction}."""
         if pf is None:
             return {}
-        out: Dict[str, np.ndarray] = {}
-        for conv, names, a, b in pf.hot:
-            if not conv:
-                preds_np = np.asarray(a)
-                votes_np = (np.asarray(b) if any(
-                    self._decode_info[n][0] for n in names
-                    if n in pf.n_real) else None)
+        with span(spans.SERVER_COLLECT):
+            out: Dict[str, np.ndarray] = {}
+            for conv, names, a, b in pf.hot:
+                if not conv:
+                    preds_np = _fetch(a)
+                    votes_np = (_fetch(b) if any(
+                        self._decode_info[n][0] for n in names
+                        if n in pf.n_real) else None)
+                    for k, name in enumerate(names):
+                        if name not in pf.n_real:
+                            continue
+                        is_reg, t = self._decode_info[name]
+                        n_real = pf.n_real[name]
+                        if is_reg:
+                            out[name] = (votes_np[k][:n_real]
+                                         .astype(np.float32) / t)
+                        else:
+                            out[name] = preds_np[k][:n_real]
+                    continue
+                preds = np.argmax(_fetch(a), axis=-1)
                 for k, name in enumerate(names):
-                    if name not in pf.n_real:
-                        continue
-                    is_reg, t = self._decode_info[name]
-                    n_real = pf.n_real[name]
-                    if is_reg:
-                        out[name] = (votes_np[k][:n_real]
-                                     .astype(np.float32) / t)
-                    else:
-                        out[name] = preds_np[k][:n_real]
-                continue
-            preds = np.argmax(np.asarray(a), axis=-1)
-            for k, name in enumerate(names):
-                if name in pf.n_real:
-                    out[name] = preds[k][:pf.n_real[name]]
-        for name, sums, cl in pf.cold:
-            is_reg, t = self._decode_info[name]
-            n_real = pf.n_real[name]
-            if is_reg:
-                votes = np.clip(np.asarray(cl).sum(-1), 0, t)
-                out[name] = votes[:n_real].astype(np.float32) / t
-            else:
-                out[name] = np.argmax(np.asarray(sums), axis=-1)[:n_real]
-        t_done = time.perf_counter()
-        for name, t_enq in pf.served.items():
-            self._last_flush[name] = t_done - t_enq
-        return out
+                    if name in pf.n_real:
+                        out[name] = preds[k][:pf.n_real[name]]
+            for name, sums, cl in pf.cold:
+                is_reg, t = self._decode_info[name]
+                n_real = pf.n_real[name]
+                if is_reg:
+                    votes = np.clip(_fetch(cl).sum(-1), 0, t)
+                    out[name] = votes[:n_real].astype(np.float32) / t
+                else:
+                    out[name] = np.argmax(_fetch(sums), axis=-1)[:n_real]
+            return out
 
     def flush(self) -> Dict[str, np.ndarray]:
         """Serve every pending request in ONE stacked launch per stage
@@ -613,15 +618,12 @@ class TMServer:
                 "stacked_launches": self.stacked_launches,
                 "coalesced_requests": self.coalesced_requests,
                 # operator visibility (ISSUE 7): backlog + bank membership
-                # + per-tenant service latency of the last flush
                 "queue_depth": len(self._pending),
                 "resident_tenants": len(resident),
                 "swapped_tenants": len(self.tenants) - len(resident),
                 "resident": sorted(resident),
                 "cold_requests": self.cold_requests,
                 "membership_swaps": self.membership_swaps,
-                "last_flush_latency_s": dict(sorted(
-                    self._last_flush.items())),
                 "program_nbytes": {n: self.program_nbytes(n)
                                    for n in sorted(self.tenants)},
                 "skip_frac": {n: self.skip_frac(n)

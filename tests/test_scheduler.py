@@ -355,13 +355,14 @@ def test_server_stats_surface(roster):
     st = srv.stats()
     assert st["queue_depth"] == 0
     assert st["resident_tenants"] == 1 and st["swapped_tenants"] == 1
-    assert st["last_flush_latency_s"] == {}
+    assert st["requests"] == 0 and st["stacked_launches"] == 0
     srv.enqueue("cotm", demo_batch(specs["cotm"], BATCH_SLOT, seed=4))
     assert srv.stats()["queue_depth"] == 1
     srv.flush()
     st = srv.stats()
     assert st["queue_depth"] == 0
-    assert st["last_flush_latency_s"]["cotm"] > 0
+    assert st["requests"] == 1 and st["stacked_launches"] == 1
+    assert st["coalesced_requests"] == 1
     assert st["cold_requests"] == 0
 
 
