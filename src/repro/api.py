@@ -205,17 +205,23 @@ class TMSpec:
                         **common)
 
     # ---- host-side input encoding (engine.encode finishes the layout) ------
+    @property
+    def raw_is_bool(self) -> bool:
+        """Raw input already is the Boolean features: :meth:`to_bool` is
+        the identity (vanilla, coalesced, regression)."""
+        return self.kind in ("vanilla", "coalesced", "regression")
+
     def to_bool(self, x: jax.Array) -> jax.Array:
         """Raw model input -> Boolean features.
 
         vanilla/coalesced/regression: [B, f] {0,1} passthrough;
         head: [B, f_raw] float -> thermometer bits [B, f_raw*k];
         conv: [B, H, W] {0,1} images -> patch features [B, P, f_patch]."""
+        if self.raw_is_bool:
+            return jnp.asarray(x)
         if self.kind == "head":
             return Booleanizer(self.thresholds)(jnp.asarray(x))
-        if self.kind == "conv":
-            return self._patch_features(jnp.asarray(x))
-        return jnp.asarray(x)
+        return self._patch_features(jnp.asarray(x))
 
     def _patch_features(self, images: jax.Array) -> jax.Array:
         """[B, H, W] {0,1} -> [B, P, patch² + pos_bits] (bits + thermometer
@@ -577,6 +583,14 @@ class ProgramBank:
         sums/clause tensors to the host."""
         assert not self.conv, "conv banks decode host-side (use infer)"
         return self.engine.predict_bank(self.progs, lits)
+
+    def predict_raw(self, feats: jax.Array, n_feats: jax.Array):
+        """:meth:`predict` from raw Boolean features (K feature-major
+        ``[L/2, B]`` int8 slots and their feature counts), encoded in the
+        same launch — the serving path's one-dispatch cycle
+        (``DTMEngine.predict_bank_raw``)."""
+        assert not self.conv, "conv banks decode host-side (use infer)"
+        return self.engine.predict_bank_raw(self.progs, feats, n_feats)
 
     def train(self, lits: jax.Array, labels: jax.Array) -> dict:
         """One stacked training step: program k consumes batch k

@@ -209,6 +209,9 @@ class DTMEngine:
         self._infer_conv_bank_list = jax.jit(
             self._infer_conv_bank_list_impl)
         self._predict_bank_list = jax.jit(self._predict_bank_list_impl)
+        # raw-feature variant: a whole serving cycle's requests arrive as
+        # ONE int8 block of K [B, L/2] slots and are encoded in-trace
+        self._predict_bank_raw = jax.jit(self._predict_bank_raw_impl)
 
     # ------------------------------------------------------------------ #
     # programming (paper §IV-D-a)                                         #
@@ -1133,16 +1136,40 @@ class DTMEngine:
     def _infer_conv_bank_list_impl(self, progs: DTMProgram, plits_list):
         return self._infer_conv_bank_impl(progs, jnp.stack(plits_list))
 
-    def _predict_bank_list_impl(self, progs: DTMProgram, lits_list):
+    def _predict_bank_impl(self, progs: DTMProgram, lits: jax.Array):
         """Stacked inference DECODED in-trace: (argmax preds [K, B],
         clipped clause votes [K, B]) — the serving flush fetches two tiny
         int32 planes instead of the [K, B, H] sums + [K, B, R] clause
         matrix (classification reads ``preds``, regression reads
         ``votes`` / T; same values as host-side decode)."""
-        sums, cl = self._infer_bank_impl(progs, jnp.stack(lits_list))
+        sums, cl = self._infer_bank_impl(progs, lits)
         preds = jnp.argmax(sums, axis=-1).astype(jnp.int32)
         votes = jnp.clip(cl.sum(axis=-1), 0, progs.T[:, None])
         return preds, votes.astype(jnp.int32)
+
+    def _predict_bank_list_impl(self, progs: DTMProgram, lits_list):
+        return self._predict_bank_impl(progs, jnp.stack(lits_list))
+
+    def _encode_bank(self, feats: jax.Array,
+                     n_feats: jax.Array) -> jax.Array:
+        """In-trace :meth:`encode` of K flat requests at once.
+
+        ``feats`` [K, L/2, B] int8 {0,1}: slot k is its request's
+        ``[B, n_feats[k]]`` rows, feature-major and zero-padded to L/2
+        features (feature-major, so a column-major request, as
+        ``np.asarray`` of a TPU array gives, is copied in its own memory
+        order).  Returns packed [K, B, W], each slot bit-identical to
+        ``encode`` of its block: ``_layout`` at the full half width, then
+        every column at or past a slot's feature count is zeroed in both
+        halves, so one trace serves any mix of widths."""
+        live = (jnp.arange(self.L // 2) < n_feats[:, None]).astype(jnp.int8)
+        keep = jnp.concatenate([live, live], axis=-1)[:, None, :]
+        return pack_literals(self._layout(feats.transpose(0, 2, 1)) * keep)
+
+    def _predict_bank_raw_impl(self, progs: DTMProgram, feats: jax.Array,
+                               n_feats: jax.Array):
+        return self._predict_bank_impl(progs,
+                                       self._encode_bank(feats, n_feats))
 
     def infer_bank(self, progs: DTMProgram, lits):
         """lits: stacked [K, B, W] array, or a K-tuple of [B, W] arrays
@@ -1162,6 +1189,16 @@ class DTMEngine:
         if not isinstance(lits, (list, tuple)):
             lits = tuple(lits)
         return self._predict_bank_list(progs, tuple(lits))
+
+    def predict_bank_raw(self, progs: DTMProgram, feats: jax.Array,
+                         n_feats: jax.Array):
+        """Flat-bank inference from raw Boolean features, encoded and
+        decoded in ONE launch: ``feats`` [K, L/2, B] int8 (slot k's
+        ``[B, n_feats[k]]`` features, feature-major, zero-padded to L/2),
+        ``n_feats`` [K] int32 -> (preds [K, B], votes [K, B]), equal to
+        :meth:`predict_bank` of the per-slot :meth:`encode` literals.
+        For the kinds whose ``TMSpec.raw_is_bool`` holds."""
+        return self._predict_bank_raw(progs, feats, n_feats)
 
     def train_bank(self, progs: DTMProgram, prngs: PRNG, lits: jax.Array,
                    labels: jax.Array):
@@ -1206,6 +1243,7 @@ class DTMEngine:
             "infer_conv_bank_list":
                 self._infer_conv_bank_list._cache_size(),
             "predict_bank_list": self._predict_bank_list._cache_size(),
+            "predict_bank_raw": self._predict_bank_raw._cache_size(),
             "train_bank": self._train_bank._cache_size(),
             "path_per_stage": dict(self._stage_paths),
         }

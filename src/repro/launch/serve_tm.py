@@ -28,6 +28,20 @@ reported per tenant as ``program_nbytes`` in :meth:`TMServer.stats` — is
 ~7× smaller than the int32 TA + re-thresholded include pair it replaced;
 literals ship packed 32-per-word from ``engine.encode``.
 
+Raw requests to resident tenants whose ``TMSpec.raw_is_bool`` holds
+(vanilla, coalesced, regression) are checked and padded at ``enqueue``
+and stay on the host; ``flush_async`` stacks the flat cycle's rows into
+one fresh int8 array of K feature-major ``[L/2, B]`` slots, makes one
+``device_put`` and one dispatch (``ProgramBank.predict_raw``) that
+encodes, evaluates and decodes the whole cycle in-trace.  Pre-encoded literals, head and conv requests,
+non-resident tenants, pod mode, and a flat cycle that mixes any of those
+with raw rows keep the per-request ``engine.encode``.  Spans and counters:
+``tm.server.encode`` times one request at ``enqueue`` (the pad alone for
+a deferred raw request); ``tm.server.encode_batch`` times the stack,
+transfer and dispatch of a batched cycle inside ``tm.server.launch``;
+:meth:`TMServer.stats` counts ``encode_batches``,
+``encode_batched_requests`` and ``encode_eager_requests``.
+
 Async serving (ISSUE 7): ``flush`` is split into a launch phase
 (:meth:`TMServer.flush_async` — dispatches the stacked bank executables
 and returns a :class:`PendingFlush` WITHOUT fetching) and a fetch phase
@@ -149,13 +163,19 @@ class TMServer:
         self.swaps = 0
         self.requests = 0
         # stacked (program-major) serving state
-        self._pending: List[Tuple[str, jax.Array, int]] = []
+        # (tenant, device literals or padded host rows, un-padded rows)
+        self._pending: List[Tuple[str, jax.Array | np.ndarray, int]] = []
         self._banks: Dict[bool, Tuple[List[str], ProgramBank]] = {}
         self._groups: Dict[bool, List[str]] = {}
         self._decode_info: Dict[str, Tuple[bool, int]] = {}
         self._dirty: set = set()
         self.stacked_launches = 0
         self.coalesced_requests = 0
+        # literal encode: cycles encoded in one dispatch, and the
+        # requests served through that path or encoded one at a time
+        self.encode_batches = 0
+        self.encode_batched_requests = 0
+        self.encode_eager_requests = 0
         # dynamic bank membership (scheduler-driven): per stage family,
         # the ordered resident roster — None = every registered tenant
         self._membership: Dict[bool, Optional[List[str]]] = {}
@@ -214,10 +234,13 @@ class TMServer:
                 [x, np.repeat(x[-1:], self.batch_slot - n, axis=0)])
         return x, n
 
-    def _encode_request(self, tenant: _Tenant, x,
-                        encoded: bool) -> Tuple[jax.Array, int]:
+    def _encode_request(self, tenant: _Tenant, x, encoded: bool,
+                        defer: bool = False
+                        ) -> Tuple[jax.Array | np.ndarray, int]:
         """Pad a request to the batch slot and encode it (unless the
-        front-end already shipped packed engine literals)."""
+        front-end already shipped packed engine literals, or ``defer``
+        keeps the padded raw rows on the host for the cycle's batched
+        encode in :meth:`flush_async`)."""
         with span(spans.SERVER_ENCODE):
             if encoded:
                 # hot path: a full-slot device array passes straight
@@ -233,7 +256,18 @@ class TMServer:
                     pad = jnp.repeat(lits[-1:], self.batch_slot - n, axis=0)
                     lits = jnp.concatenate([lits, pad], axis=0)
                 return lits, n
-            xp, n = self._pad(np.asarray(x))
+            x = np.asarray(x)
+            if defer and (x.ndim != 2 or x.shape[1] > self.engine.L // 2
+                          or x.dtype.kind not in "biuf"):
+                # refused here, as encode would refuse it: a bad block
+                # must fail alone, not the flush that stacks it
+                raise ValueError(
+                    f"raw request {x.dtype}{list(x.shape)}: expected "
+                    f"numeric [rows, features], at most "
+                    f"{self.engine.L // 2} features")
+            xp, n = self._pad(x)
+            if defer:
+                return xp, n
             return self.engine.encode(tenant.spec, jnp.asarray(xp)), n
 
     # ---- request paths ----------------------------------------------------
@@ -302,6 +336,13 @@ class TMServer:
             return host
 
     # ---- stacked (program-major) serving ----------------------------------
+    def _group(self, conv: bool) -> List[str]:
+        """:meth:`_group_names`, cached until the roster changes."""
+        group = self._groups.get(conv)
+        if group is None:
+            group = self._groups[conv] = self._group_names(conv)
+        return group
+
     def _group_names(self, conv: bool) -> List[str]:
         member = self._membership.get(conv)
         if member is not None:
@@ -426,9 +467,17 @@ class TMServer:
         return self._bank_for(conv)[1]
 
     def enqueue(self, name: str, x, encoded: bool = False) -> None:
-        """Queue an inference request for the next stacked flush."""
+        """Queue an inference request for the next stacked flush.
+
+        A raw request to a resident flat tenant whose spec needs no
+        booleanizing (``TMSpec.raw_is_bool``), outside pod mode, is only
+        checked and padded and stays on the host: :meth:`flush_async`
+        encodes the whole cycle in one transfer and one dispatch.  Every
+        other request is encoded here, one request at a time."""
         tenant = self.tenants[name]
-        lits, n = self._encode_request(tenant, x, encoded)
+        defer = (not encoded and tenant.spec.raw_is_bool
+                 and self.pod_devices == 1 and name in self._group(False))
+        lits, n = self._encode_request(tenant, x, encoded, defer)
         self._pending.append((name, lits, n))
 
     def abandon_pending(self) -> int:
@@ -446,36 +495,45 @@ class TMServer:
         launch per pending NON-resident tenant — the cold path) and
         return a :class:`PendingFlush` WITHOUT fetching any result, so a
         driver can overlap the device work with host encode of the next
-        batch.  An empty queue is a cheap no-op (``None``): no bank
-        build, no launch, no device sync — the background flush loop
-        calls this on a timer."""
+        batch.  When every pending request of the flat bank is a host
+        block (see :meth:`enqueue`), that bank's launch encodes them all
+        in-trace from one transfer.  An empty queue is a cheap no-op
+        (``None``): no bank build, no launch, no device sync — the
+        background flush loop calls this on a timer.  The queue is
+        cleared only once every launch is dispatched, so a launch that
+        raises leaves it whole for a retry."""
         if not self._pending:
             return None
         with span(spans.SERVER_LAUNCH):
-            pending, self._pending = self._pending, []
-            by_name: Dict[str, Tuple[jax.Array, int]] = {}
-            for name, lits, n in pending:
-                by_name[name] = (lits, n)
-                self.requests += 1
+            by_name: Dict[str, tuple] = {}
+            for name, x, n in self._pending:
+                by_name[name] = (x, n)
             hot, cold, claimed = [], [], set()
+            launches = batched = 0
             for conv in (False, True):
-                group = self._groups.get(conv)
-                if group is None:
-                    group = self._groups[conv] = self._group_names(conv)
-                req_names = [n for n in group if n in by_name]
+                req_names = [n for n in self._group(conv) if n in by_name]
                 if not req_names:
                     continue
                 claimed.update(req_names)
                 names, bank = self._bank_for(conv)
+                launches += 1
+                if not conv and all(isinstance(by_name[n][0], np.ndarray)
+                                    for n in req_names):
+                    # the whole cycle in ONE transfer and ONE launch
+                    # that encodes and decodes in-trace
+                    with span(spans.SERVER_ENCODE_BATCH):
+                        out = bank.predict_raw(*jax.device_put(
+                            self._stack_raw(names, by_name)))
+                    batched = len(req_names)
+                    hot.append((False, list(names)) + tuple(out))
+                    continue
                 # idle slots replay a pending tenant's literals — their
                 # outputs are dropped, so the filler's values are
                 # irrelevant and no eager zeros/stack ops run (stacking
                 # happens in-trace via the tuple-taking bank executables)
-                filler = by_name[req_names[0]][0]
-                lits = tuple(by_name[n][0] if n in by_name else filler
-                             for n in names)
-                self.stacked_launches += 1
-                self.coalesced_requests += len(req_names)
+                lits = {n: self._lits(n, by_name[n][0]) for n in req_names}
+                filler = lits[req_names[0]]
+                lits = tuple(lits.get(n, filler) for n in names)
                 if not conv:
                     # flat banks decode IN-TRACE: two tiny [K, B] planes,
                     # no host argmax, no clause-matrix round trip
@@ -483,7 +541,7 @@ class TMServer:
                                + tuple(bank.predict(lits)))
                 else:
                     hot.append((True, list(names)) + tuple(bank.infer(lits)))
-            for name in by_name:
+            for name, (x, _) in by_name.items():
                 # requests for tenants OUTSIDE the resident roster
                 # (dynamic bank membership demoted them) fall back to a
                 # per-request single-program launch — the measured cold
@@ -492,12 +550,47 @@ class TMServer:
                     continue
                 tenant = self.tenants[name]
                 sums, cl = self.engine.infer_fn(tenant.spec)(
-                    tenant.program, by_name[name][0])
-                self.cold_requests += 1
+                    tenant.program, self._lits(name, x))
                 cold.append((name, sums, cl))
+            self.requests += len(self._pending)
+            self._pending = []
+            self.stacked_launches += launches
+            self.coalesced_requests += len(claimed)
+            self.cold_requests += len(cold)
+            self.encode_batches += int(batched > 0)
+            self.encode_batched_requests += batched
+            self.encode_eager_requests += len(by_name) - batched
             return PendingFlush(
                 n_real={n: v[1] for n, v in by_name.items()},
                 hot=hot, cold=cold)
+
+    def _lits(self, name: str, x) -> jax.Array:
+        """Device literals of a queued request.  A host block whose
+        cycle cannot be encoded in one launch (its tenant left the
+        roster, or its bank's cycle holds other requests) is encoded
+        here, as :meth:`enqueue` would have encoded it."""
+        if isinstance(x, np.ndarray):
+            return self.engine.encode(self.tenants[name].spec,
+                                      jnp.asarray(x))
+        return x
+
+    def _stack_raw(self, names: List[str], by_name: Dict[str, tuple]
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+        """One flat cycle of host blocks as the raw bank launch takes
+        them: a fresh [K, L/2, B] int8 array and the [K] int32 feature
+        counts.  Slot k holds its request's rows, feature-major,
+        zero-padded from their feature count to L/2; idle slots are all
+        zero and their outputs dropped.  Allocated per cycle, never
+        reused while a transfer may still read it."""
+        feats = np.zeros((len(names), self.engine.L // 2, self.batch_slot),
+                         np.int8)
+        n_feats = np.zeros(len(names), np.int32)
+        for k, name in enumerate(names):
+            if name in by_name:
+                x = by_name[name][0]
+                n_feats[k] = x.shape[1]
+                feats[k, :x.shape[1]] = x.T
+        return feats, n_feats
 
     def collect(self, pf: Optional[PendingFlush]) -> Dict[str, np.ndarray]:
         """Fetch phase of :meth:`flush`: materialise a
@@ -617,6 +710,9 @@ class TMServer:
                 "pod_devices": self.pod_devices,
                 "stacked_launches": self.stacked_launches,
                 "coalesced_requests": self.coalesced_requests,
+                "encode_batches": self.encode_batches,
+                "encode_batched_requests": self.encode_batched_requests,
+                "encode_eager_requests": self.encode_eager_requests,
                 # operator visibility (ISSUE 7): backlog + bank membership
                 "queue_depth": len(self._pending),
                 "resident_tenants": len(resident),

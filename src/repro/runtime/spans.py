@@ -22,7 +22,8 @@ SCHED_MEMBERSHIP = "tm.sched.membership"  # EWMA, promotions, swaps
 SCHED_WAIT = "tm.sched.wait"              # idle wait / batch-window sleep
 SCHED_SUBMIT = "tm.sched.submit"          # admission (client thread)
 # server (launch/serve_tm.py)
-SERVER_ENCODE = "tm.server.encode"        # pad + encode of one request
+SERVER_ENCODE = "tm.server.encode"        # pad (+ encode) of one request
+SERVER_ENCODE_BATCH = "tm.server.encode_batch"  # stack, put, raw launch
 SERVER_LAUNCH = "tm.server.launch"        # bank sync, stacked + cold launch
 SERVER_COLLECT = "tm.server.collect"      # fetch + decode of one flush
 SERVER_FETCH = "tm.server.fetch"          # the host sync of a collect

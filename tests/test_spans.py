@@ -142,3 +142,26 @@ def test_queue_wait_counters_count_inference_taken_into_batches(roster):
     st2 = sched.stats()
     assert st2["infer_formed"] == 15 and st2["trains"] == 1
     assert st2["infer_queue_wait_s"] > st1["infer_queue_wait_s"]
+
+
+def test_raw_cycles_emit_one_encode_batch_each(roster, tmp_path):
+    """Raw requests to resident coalesced, vanilla and regression tenants
+    are padded under ``tm.server.encode`` (one per request) and encoded
+    together under one ``tm.server.encode_batch`` per cycle, inside that
+    cycle's launch."""
+    specs, engine = roster
+    raw = {n: specs[n] for n in ("cotm", "regression", "vanilla")}
+    sched = _scheduler(engine, raw)
+    got, events = _traced(tmp_path, lambda: _serve(sched, _requests(raw)))
+    assert len(got) == 6
+    st = sched.server.stats()
+    assert st["encode_batches"] == sched.stats()["launches"] == 2
+    assert st["encode_batched_requests"] == 6
+    assert st["encode_eager_requests"] == 0
+    batches = _named(events, spans.SERVER_ENCODE_BATCH)
+    assert len(batches) == 2
+    assert len(_named(events, spans.SERVER_ENCODE)) == 6
+    launches = _named(events, spans.SERVER_LAUNCH)
+    for line, _, s, e, _ in batches:
+        assert any(c[0] == line and c[2] <= s and e <= c[3]
+                   for c in launches)

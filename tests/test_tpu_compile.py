@@ -170,3 +170,16 @@ def test_epoch_scan_and_bank_compile_for_v5e(mnist_engine):
     lits = tuple(place(jax.ShapeDtypeStruct((B, engine.W), jnp.uint32))
                  for _ in range(K))
     _compile(engine._predict_bank_list, progs, lits)
+
+
+def test_raw_bank_predict_compiles_for_v5e(mnist_engine):
+    """The serving cycle's one launch: K raw feature-major [L/2, B] int8
+    slots and their feature counts encoded, evaluated and decoded in one
+    program."""
+    engine, prog, _, place = mnist_engine
+    B, K = 32, 2
+    progs = place(jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((K,) + x.shape, x.dtype), prog))
+    _compile(engine._predict_bank_raw, progs,
+             place(jax.ShapeDtypeStruct((K, engine.L // 2, B), jnp.int8)),
+             place(jax.ShapeDtypeStruct((K,), jnp.int32)))
