@@ -1,18 +1,27 @@
 """The benchmark harness: one cell of ``BENCHMARK.json``, one run.
 
 A cell names a configuration (``configs/<config>.json``: the model's
-sizes, where they come from, and the data recipe) and a traffic mix
-(``traffic/<traffic>.json``: the parameters the one generator reads).
-Its metrics are the ``end_to_end`` (``--trace 0``) or ``per_layer``
-(``--trace 1``) entries of ``BENCHMARK.json`` that list the cell, or list
-no cells; each is read by ``metrics/<name>.py``.  Adding a cell, a
-configuration, a traffic mix or a metric therefore adds files and an
-entry, and edits nothing here.
+TM kind, its sizes, where they come from, and the data recipe) and a
+traffic mix (``traffic/<traffic>.json``: the parameters the one generator
+reads).  Everything that depends on the kind (the ``api.TMSpec``, the
+inputs and tenant programs, the plain reference, the work at published
+sizes) is the kind module's, ``kinds/<kind>.py`` (``kinds/coalesced.py``
+states the contract).  Its metrics are the ``end_to_end`` (``--trace 0``)
+or ``per_layer`` (``--trace 1``) entries of ``BENCHMARK.json`` that list
+the cell, or list no cells; each is read by ``metrics/<name>.py``.
+
+Adding a configuration of a new kind therefore adds files and entries
+and edits nothing here: ``kinds/<kind>.py`` with its reference beside it
+(``kinds/<kind>_ref.py``, importing nothing of the program), the
+configuration ``configs/<config>.json`` naming that kind, a traffic file,
+any ``stages/<stage>.json`` its kernels need, the ``metrics/<name>.py``
+of new metrics, and its ``BENCHMARK.json`` entries.  A configuration of a
+kind that is already here needs no kind module or reference of its own.
 
 A run: set-up (data, tenant programs, the served stack or the estimator,
 warm-up of every shape the traffic uses) -> the measured window ->
-the comparison with the plain reference (``reference.py``) -> one JSON
-line.  Lines before the last are diagnostics.
+the comparison with the kind's plain reference -> one JSON line.  Lines
+before the last are diagnostics.
 """
 from __future__ import annotations
 
@@ -100,7 +109,11 @@ def load_json(path: pathlib.Path) -> dict:
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT) -> dict:
-    """The cell ``name`` with its configuration, traffic and metrics."""
+    """The cell ``name`` with its configuration, traffic and metrics, read
+    from ``root`` (data).  The configuration's kind module, like a metric
+    reader, is code and comes from this package: ``kinds.of`` finds it,
+    here to fail early on a missing kind, later at every use."""
+    import kinds
     bench = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -111,8 +124,9 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> dict:
     def mine(metrics):
         return [m for m in metrics if name in m.get("workloads", [name])]
 
-    return {"cell": cell,
-            "config": load_json(root / conf["file"]),
+    config = load_json(root / conf["file"])
+    kinds.of(config)
+    return {"cell": cell, "config": config,
             "traffic": load_json(root / HERE.name / "traffic"
                                  / f"{cell['traffic']}.json"),
             "end_to_end": mine(bench["end_to_end"]),
@@ -136,26 +150,6 @@ def check_chip(chips: int) -> None:
         raise NoChip(f"JAX found no TPU (platform {devs[0].platform!r})")
     if len(devs) < chips:
         raise NoChip(f"the cell needs {chips} chips, JAX found {len(devs)}")
-
-
-def tm_spec(cfg: dict):
-    from repro import api
-    return api.TMSpec.coalesced(
-        features=cfg["features"], classes=cfg["classes"],
-        clauses=cfg["clauses"], T=cfg["T"], s=cfg["s"],
-        ta_bits=cfg["ta_bits"], weight_bits=cfg["weight_bits"],
-        rand_bits=cfg["rand_bits"], prng_backend=cfg["prng"],
-        lfsr_bits=cfg["lfsr_bits"], seed_refresh=cfg["seed_refresh"],
-        boost_true_positive=cfg["boost_true_positive"])
-
-
-def unpad(cfg: dict, ta, w) -> tuple:
-    """A program's TA plane and weights at the published sizes."""
-    ta, w = np.asarray(ta).astype(np.int32), np.asarray(w)
-    C, F, H = cfg["clauses"], cfg["features"], cfg["classes"]
-    half = cfg["engine"]["negated_literal_column"]
-    return (np.concatenate([ta[:C, :F], ta[:C, half:half + F]], axis=1),
-            w[:H, :C])
 
 
 # ------------------------------------------------------------------ serving
@@ -200,11 +194,13 @@ class ServeRun:
         from repro.launch.scheduler import SchedulerConfig, TMScheduler
         from repro.launch.serve_tm import TMServer
         import data
+        import kinds
         cfg, tr = self.cfg, self.tr
-        self.mot = data.motifs(cfg)
-        x, _ = data.rows(cfg, self.mot, self.seed, tr["pool_rows"])
+        kind = kinds.of(cfg)
+        self.mot = kind.motifs(cfg)
+        x, _ = kind.rows(cfg, self.mot, self.seed, tr["pool_rows"])
         self.x = np.asarray(x)
-        self.spec = tm_spec(cfg)
+        self.spec = kind.spec(cfg)
         self.engine = api.compile(api.tile_for(self.spec))
         self.server = TMServer(self.engine, batch_slot=tr["batch_slot"])
         self.sched = TMScheduler(self.server, config=SchedulerConfig(
@@ -216,7 +212,7 @@ class ServeRun:
                      for i, n in enumerate(self.names)}
         key = jax.random.PRNGKey(0)
         for i, name in enumerate(self.names):
-            ta, w = data.program(cfg, self.mot, tr["programs"], self.seed, i)
+            ta, w = kind.program(cfg, self.mot, tr["programs"], self.seed, i)
             prog = self.engine.lower(self.spec, key, ta=ta, weights=w)
             self.sched.register(name, self.spec, program=prog,
                                 seed=data.tenant_seed(self.seed, i))
@@ -386,11 +382,11 @@ def compare_serving(cfg: dict, traffic: dict, seed: int, recs: list,
     ``lower`` (e.g. ``{"weight_bits": 8}``) computes the reference at a
     precision below the configuration's (the control)."""
     import jax.numpy as jnp
-    import data
-    import reference as ref
-    mot = data.motifs(cfg)
-    x = np.asarray(data.rows(cfg, mot, seed, traffic["pool_rows"])[0])
-    wb = ref.hyper(cfg, **(lower or {}))["weight_bits"]
+    import kinds
+    kind = kinds.of(cfg)
+    mot = kind.motifs(cfg)
+    x = np.asarray(kind.rows(cfg, mot, seed, traffic["pool_rows"])[0])
+    hp = kind.hyper(cfg, **(lower or {}))
     infer = [r for r in recs if r.ok()]
     n = traffic["check"]["sample_requests"]
     if len(infer) > n:
@@ -403,12 +399,11 @@ def compare_serving(cfg: dict, traffic: dict, seed: int, recs: list,
     out = {"wrong_answers": 0,
            "unanswered": sum(not r.answered for r in recs)}
     for name, asks in sorted(by_tenant.items()):
-        ta, w = data.program(cfg, mot, traffic["programs"], seed,
+        ta, w = kind.program(cfg, mot, traffic["programs"], seed,
                              int(name[1:]))
         for r in asks:
-            want = np.asarray(ref.predict(ta, w,
-                                          jnp.asarray(x[r.idx:r.idx + r.rows]),
-                                          cfg["ta_bits"], wb))
+            want = np.asarray(kind.predict(
+                hp, ta, w, jnp.asarray(x[r.idx:r.idx + r.rows])))
             got = np.asarray(r.fut.result())
             out["wrong_answers"] += int(not np.array_equal(got, want))
     return out
@@ -431,13 +426,15 @@ class FitRun:
         import jax
         from repro import api
         import data
+        import kinds
         cfg, f = self.cfg, self.tr["fit"]
-        mot = data.motifs(cfg)
-        x, y = data.rows(cfg, mot, self.seed, f["rows"])
+        kind = kinds.of(cfg)
+        mot = kind.motifs(cfg)
+        x, y = kind.rows(cfg, mot, self.seed, f["rows"])
         self.x, self.y = np.asarray(x), np.asarray(y)
-        self.spec = tm_spec(self.cfg)
+        self.spec = kind.spec(cfg)
         self.tm = api.TM(self.spec, seed=data.tenant_seed(self.seed, 0))
-        self.ta0, self.w0 = data.program(cfg, mot, self.tr["programs"],
+        self.ta0, self.w0 = kind.program(cfg, mot, self.tr["programs"],
                                          self.seed, 0)
         self.tm.program = self.tm.engine.lower(
             self.spec, jax.random.PRNGKey(0), ta=self.ta0, weights=self.w0)
@@ -445,8 +442,8 @@ class FitRun:
                                 jax.tree.leaves(self.tm.program))
         # the first epoch: compiles, and is what the reference follows
         self.first = self.tm.fit(self.x, self.y, epochs=1, batch=f["batch"])
-        self.snap = unpad(cfg, np.asarray(self.tm.program.ta),
-                          np.asarray(self.tm.program.weights))
+        self.snap = kind.unpad(cfg, np.asarray(self.tm.program.ta),
+                               np.asarray(self.tm.program.weights))
 
     def window(self, seconds: float) -> dict:
         import jax
@@ -479,20 +476,21 @@ def compare_fit(cfg: dict, traffic: dict, seed: int, first: list, snap,
     (the epoch's summed selections, skipped groups and correct rows)."""
     import jax.numpy as jnp
     import data
-    import reference as ref
+    import kinds
+    kind = kinds.of(cfg)
     f = traffic["fit"]
-    mot = data.motifs(cfg)
-    x, y = (np.asarray(a) for a in data.rows(cfg, mot, seed, f["rows"]))
-    ta, w = data.program(cfg, mot, traffic["programs"], seed, 0)
+    mot = kind.motifs(cfg)
+    x, y = (np.asarray(a) for a in kind.rows(cfg, mot, seed, f["rows"]))
+    ta, w = kind.program(cfg, mot, traffic["programs"], seed, 0)
     B = f["batch"]
     n = f["rows"] - f["rows"] % B
     # TMSession.fit_epochs' batch order: one permutation per fit call
     idx = np.random.default_rng(0).permutation(f["rows"])[:n]
-    xs = jnp.asarray(x[idx].reshape(n // B, B, -1))
+    xs = jnp.asarray(x[idx].reshape(n // B, B, *x.shape[1:]))
     ys = jnp.asarray(y[idx].reshape(n // B, B))
-    hp = ref.hyper(cfg, **(lower or {}))
-    (r_ta, r_w, _), st = ref.train(hp, ta, w, data.tenant_seed(seed, 0) + 1,
-                                   xs, ys)
+    hp = kind.hyper(cfg, **(lower or {}))
+    (r_ta, r_w, _), st = kind.train(hp, ta, w,
+                                    data.tenant_seed(seed, 0) + 1, xs, ys)
     st = jax_host(st)
     rec = first[0]
     got = {"selected": rec["selected_clauses"],
@@ -510,11 +508,11 @@ LIMITS = {"wrong_answers": 0, "unanswered": 0, "wrong_ta_states": 0,
           "wrong_weights": 0, "wrong_epoch_stats": 0}
 
 
-def evidence(R, kind: str, res: dict) -> dict:
+def evidence(R, mode: str, res: dict) -> dict:
     """What the comparison needs from a finished run; frees the run's
     device state (the reference runs after it, so that it sets no
     memory peak)."""
-    if kind == "fit":
+    if mode == "fit":
         ev = {"first": R.first, "snap": R.snap}
     else:
         ev = {"recs": res["records"]}
@@ -522,9 +520,9 @@ def evidence(R, kind: str, res: dict) -> dict:
     return ev
 
 
-def compare(kind: str, cfg: dict, traffic: dict, seed: int, ev: dict,
+def compare(mode: str, cfg: dict, traffic: dict, seed: int, ev: dict,
             lower=None) -> dict:
-    if kind == "fit":
+    if mode == "fit":
         return compare_fit(cfg, traffic, seed, ev["first"], ev["snap"],
                            lower)
     return compare_serving(cfg, traffic, seed, ev["recs"], lower)
@@ -539,14 +537,14 @@ def control_readings(cfg: dict, traffic: dict, seed: int,
     (``control``).  Where the program is sound, the control's numbers
     are those of the lower-precision reference against the configured
     one."""
-    kind = "fit" if "fit" in traffic else "serve"
-    R = (FitRun if kind == "fit" else ServeRun)(cfg, traffic, seed)
+    mode = "fit" if "fit" in traffic else "serve"
+    R = (FitRun if mode == "fit" else ServeRun)(cfg, traffic, seed)
     R.setup()
-    res = R.window(seconds) if kind == "serve" else None
+    res = R.window(seconds) if mode == "serve" else None
     R.stop()
-    ev = evidence(R, kind, res)
-    return {"sound": compare(kind, cfg, traffic, seed, ev),
-            "control": compare(kind, cfg, traffic, seed, ev,
+    ev = evidence(R, mode, res)
+    return {"sound": compare(mode, cfg, traffic, seed, ev),
+            "control": compare(mode, cfg, traffic, seed, ev,
                                traffic["check"]["control"])}
 
 
@@ -559,10 +557,10 @@ def device_info(devices) -> dict:
             "count": len(jax.devices()), "memory_peak_bytes": int(peak)}
 
 
-def summarize(kind: str, res: dict, run) -> dict:
+def summarize(mode: str, res: dict, run) -> dict:
     """What the metric readers read."""
-    ctx = {"window_s": res["seconds"], "kind": kind}
-    if kind == "fit":
+    ctx = {"window_s": res["seconds"], "mode": mode}
+    if mode == "fit":
         f = run.tr["fit"]
         epochs = res["epochs"]
         ctx["train_rows"] = res["steps"] * f["batch"]
@@ -608,8 +606,8 @@ def run(args, t_start: float, root: pathlib.Path = ROOT,
     from repro.launch.compile_cache import use_compile_cache
     cache = use_compile_cache()
     meter = CompileMeter().install()
-    kind = "fit" if "fit" in traffic else "serve"
-    R = (FitRun if kind == "fit" else ServeRun)(cfg, traffic, args.seed)
+    mode = "fit" if "fit" in traffic else "serve"
+    R = (FitRun if mode == "fit" else ServeRun)(cfg, traffic, args.seed)
     R.setup()
     # the load generator shares the process with the served stack: a full
     # collection in the window would walk every object set-up made (the
@@ -628,7 +626,7 @@ def run(args, t_start: float, root: pathlib.Path = ROOT,
         res = R.window(float(args.seconds))
     compiles = meter.programs - compiles0
     R.stop()
-    ctx = summarize(kind, res, R)
+    ctx = summarize(mode, res, R)
     ctx["setup_s"] = setup_s
     ctx["config"], ctx["traffic"] = cfg, traffic
     dev = device_info(jax.devices()[:cell["chips"]])
@@ -639,7 +637,7 @@ def run(args, t_start: float, root: pathlib.Path = ROOT,
         f"by generation: {gcm.gens}; frozen at set-up: {gc.get_freeze_count()}")
     log(f"roster bytes: {R.roster_bytes}; peak_bytes_in_use: "
         f"{dev['memory_peak_bytes']}")
-    if kind == "serve":
+    if mode == "serve":
         late = np.asarray(res["late"]) * 1e3
         if late.size:
             log(f"generator lateness ms: p50 {np.percentile(late, 50)} "
@@ -649,7 +647,7 @@ def run(args, t_start: float, root: pathlib.Path = ROOT,
     else:
         log(f"epochs in the window: {len(res['epochs'])}")
     t_check = time.perf_counter()
-    compared = compare(kind, cfg, traffic, args.seed, evidence(R, kind, res))
+    compared = compare(mode, cfg, traffic, args.seed, evidence(R, mode, res))
     log(f"reference check: {time.perf_counter() - t_check} s")
     trace = None
     if args.trace:

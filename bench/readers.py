@@ -3,11 +3,14 @@
 Each reader takes the run's context and returns a number, or ``None``
 where the run has nothing to read (no trace, no such work, no such
 kernel in the trace): the metric is then left out of the result line.
+The work behind a share comes from the kind module of the run's
+configuration (``kinds.of(ctx["config"])``).
 """
 from __future__ import annotations
 
 import math
 
+import kinds
 import work
 
 
@@ -41,6 +44,7 @@ def cold_share(ctx):
 
 
 def mfu(ctx, kind, ops_per_row):
+    """Rows/s of ``kind`` x ``ops_per_row(cfg)`` over the int8 peak, %."""
     r = rate(ctx, kind)
     if r is None or ctx.get("peaks") is None:
         return None
@@ -66,7 +70,7 @@ def roofline(ctx, stage, w):
 def clause_eval_roofline(ctx):
     if not ctx.get("infer_rows_all"):
         return None
-    return roofline(ctx, "clause_eval", work.clause_eval(
+    return roofline(ctx, "clause_eval", kinds.of(ctx["config"]).clause_eval(
         ctx["config"], ctx["infer_rows_all"], ctx["infer_requests_all"]))
 
 
@@ -74,5 +78,5 @@ def ta_update_roofline(ctx):
     share = ctx.get("active_share")
     if share is None or not ctx.get("train_rows"):
         return None
-    return roofline(ctx, "ta_update", work.ta_update(
+    return roofline(ctx, "ta_update", kinds.of(ctx["config"]).ta_update(
         ctx["config"], ctx["train_rows"], ctx["train_steps"], share))

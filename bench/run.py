@@ -4,6 +4,11 @@
 
 The cells, their configurations, traffic and metrics are defined by
 ``BENCHMARK.json`` and the files under ``bench/`` (see ``harness.py``).
+A configuration of a new TM kind joins by new files and entries alone:
+``kinds/<kind>.py`` and its plain reference ``kinds/<kind>_ref.py`` (the
+contract is in ``kinds/coalesced.py``), ``configs/<config>.json`` naming
+the kind, a traffic file, the ``stages/`` and ``metrics/`` files of any
+new kernel or metric, and its ``BENCHMARK.json`` entries.
 The last line of standard output is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and with ``--trace 1``
 ``breakdown``) and, last, ``compared``: every number compared with the

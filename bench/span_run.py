@@ -47,8 +47,8 @@ def main(argv=None, **run_kw) -> int:
         seen["idle_spans"] = span_reduce.idle_spans(events, lo, hi)
         return reduce(events, lo, hi, *a, **k)
 
-    def keep_summarize(kind, res, run):
-        seen["ctx"] = summarize(kind, res, run)
+    def keep_summarize(mode, res, run):
+        seen["ctx"] = summarize(mode, res, run)
         return seen["ctx"]
 
     def with_queue_wait(run):
@@ -67,7 +67,7 @@ def main(argv=None, **run_kw) -> int:
         trace_reduce.reduce, harness.summarize = reduce, summarize
         harness.ServeRun.counters = counters
     ctx = dict(seen["ctx"], spans=seen["spans"])
-    kind = "train" if ctx["kind"] == "fit" else "infer"
+    kind = "train" if ctx["mode"] == "fit" else "infer"
     print(json.dumps({
         "workload": args.workload, "seed": args.seed,
         "metrics": {n: f(ctx) for n, f in span_reduce.READERS.items()},

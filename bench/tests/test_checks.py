@@ -45,45 +45,43 @@ def test_sound_run_is_correct(tiny_root, name):
 
 
 def test_training_reference_follows_the_program_exactly():
-    import jax.numpy as jnp
-    import data
-    import reference as ref
+    import kinds
     from repro import api
     from repro.core.prng import PRNG
     cfg = tiny_config()
-    spec = harness.tm_spec(cfg)
+    kind = kinds.of(cfg)
+    spec = kind.spec(cfg)
     eng = api.compile(api.tile_for(spec), backend="ref")
-    mot = data.motifs(cfg)
-    ta, w = data.program(cfg, mot, "paper_init", 5, 0)
-    x, y = data.rows(cfg, mot, 5, 4 * 8)
+    mot = kind.motifs(cfg)
+    ta, w = kind.program(cfg, mot, "paper_init", 5, 0)
+    x, y = kind.rows(cfg, mot, 5, 4 * 8)
     prog = eng.lower(spec, jax.random.PRNGKey(0), ta=ta, weights=w)
     prng = PRNG.create(spec.tm_config(), 99)
     for s in range(4):
         prog, prng, _ = eng.train_step(
             prog, prng, eng.encode(spec, x[8 * s:8 * s + 8]),
             spec.encode_labels(y[8 * s:8 * s + 8]))
-    (r_ta, r_w, _), _ = ref.train(ref.hyper(cfg), ta, w, 99,
-                                  x.reshape(4, 8, -1), y.reshape(4, 8))
-    p_ta, p_w = harness.unpad(cfg, prog.ta, prog.weights)
+    (r_ta, r_w, _), _ = kind.train(kind.hyper(cfg), ta, w, 99,
+                                   x.reshape(4, 8, -1), y.reshape(4, 8))
+    p_ta, p_w = kind.unpad(cfg, prog.ta, prog.weights)
     assert np.array_equal(p_ta, np.asarray(r_ta))
     assert np.array_equal(p_w, np.asarray(r_w))
     assert not np.array_equal(p_ta, np.asarray(ta, np.int32))
 
 
 def test_inference_reference_matches_the_program():
-    import data
-    import reference as ref
+    import kinds
     from repro import api
     cfg = tiny_config()
-    spec = harness.tm_spec(cfg)
+    kind = kinds.of(cfg)
+    spec = kind.spec(cfg)
     eng = api.compile(api.tile_for(spec), backend="ref")
-    mot = data.motifs(cfg)
-    ta, w = data.program(cfg, mot, "trained_like", 3, 1)
-    x, _ = data.rows(cfg, mot, 3, 64)
+    mot = kind.motifs(cfg)
+    ta, w = kind.program(cfg, mot, "trained_like", 3, 1)
+    x, _ = kind.rows(cfg, mot, 3, 64)
     prog = eng.lower(spec, jax.random.PRNGKey(0), ta=ta, weights=w)
     got = np.asarray(eng.predict(prog, eng.encode(spec, x)))
-    want = np.asarray(ref.predict(ta, w, x, cfg["ta_bits"],
-                                  cfg["weight_bits"]))
+    want = np.asarray(kind.predict(kind.hyper(cfg), ta, w, x))
     assert np.array_equal(got, want)
 
 

@@ -12,11 +12,12 @@ import sys
 import pytest
 
 import harness
+import kinds
 import readers
 import trace_reduce
 import work
 
-from conftest import BENCH, ROOT
+from conftest import BENCH, ROOT, TINY_TRAFFIC, tiny_config
 
 
 def test_p95_counts_from_due_time_and_refusals_miss():
@@ -62,24 +63,154 @@ def test_open_loop_schedule():
         (r.due, r.tenant, r.rows) for r in recs]     # same seed, same work
 
 
-@pytest.mark.parametrize("name,C,L2,H", [("mnist-cotm", 2000, 1568, 10),
-                                         ("kws6-cotm", 2000, 3200, 6)])
-def test_work_counts_at_published_widths(name, C, L2, H):
+@pytest.mark.parametrize("name,C,L2,H,infer,train", [
+    ("mnist-cotm", 2000, 1568, 10, 6_312_000, 9_448_000),
+    ("kws6-cotm", 2000, 3200, 6, 12_824_000, 19_224_000)])
+def test_work_counts_at_published_widths(name, C, L2, H, infer, train):
+    """The coalesced kind counts the closed forms of ``kinds/coalesced.py``
+    at the published widths, whatever the engine pads them to."""
     cfg = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    assert work.sizes(cfg) == (C, L2, H)
-    assert work.infer_ops_per_row(cfg) == 2 * C * L2 + 2 * C * H
-    assert work.train_ops_per_row(cfg) == 3 * C * L2 + 2 * C * H
-    ce = work.clause_eval(cfg, rows=32, requests=2)
+    kind = kinds.of(cfg)
+    assert kind is kinds.of({"kind": "coalesced"})
+    assert kind.sizes(cfg) == (C, L2, H)
+    assert kind.infer_ops_per_row(cfg) == 2 * C * L2 + 2 * C * H == infer
+    assert kind.train_ops_per_row(cfg) == 3 * C * L2 + 2 * C * H == train
+    ce = kind.clause_eval(cfg, rows=32, requests=2)
     assert ce["ops"] == 2 * C * L2 * 32
     assert ce["bytes"] == 2 * C * L2 / 8 + 32 * L2 / 8 + 32 * C / 8
-    ta = work.ta_update(cfg, rows=64, steps=2, active_share=0.5)
+    ta = kind.ta_update(cfg, rows=64, steps=2, active_share=0.5)
     assert ta["bytes"] == 2 * C * L2 * 2 * 0.5
     assert ta["ops"] == C * L2 * 64 * 0.5
     peak = work.peaks("TPU v5 lite")
     # the MNIST include plane at HBM peak: 392 KB / 819 GB/s
-    one = work.clause_eval(cfg, rows=1, requests=1)
+    one = kind.clause_eval(cfg, rows=1, requests=1)
     assert work.roofline_s(one, peak) == pytest.approx(
         one["bytes"] / 819e9)
+
+
+def test_shares_read_the_kind_modules_work():
+    """``infer_mfu``, ``train_mfu`` and both rooflines, from a context
+    with peaks and stage seconds, are the kind's counts over them."""
+    cfg = json.loads((BENCH / "configs" / "kws6-cotm.json").read_text())
+    kind = kinds.of(cfg)
+    peak = work.peaks("TPU v5 lite")
+    ctx = {"config": cfg, "peaks": peak, "infer_rows": 64_000,
+           "infer_span_s": 2.0, "infer_rows_all": 70_000,
+           "infer_requests_all": 2_000, "train_rows": 6_400,
+           "train_span_s": 4.0, "train_steps": 200, "active_share": 0.25,
+           "trace": {"stage_s": {"clause_eval": 0.5, "ta_update": 3.0},
+                     "devices": 1, "busy_s": 3.5, "window_s": 4.0}}
+    assert harness.reader("infer_mfu")(ctx) == pytest.approx(
+        100 * kind.infer_ops_per_row(cfg) * 32_000 / 393e12)
+    assert harness.reader("train_mfu")(ctx) == pytest.approx(
+        100 * kind.train_ops_per_row(cfg) * 1_600 / 393e12)
+    assert harness.reader("clause_eval_roofline.infer_rows")(ctx) == (
+        pytest.approx(100 * work.roofline_s(
+            kind.clause_eval(cfg, 70_000, 2_000), peak) / 0.5))
+    assert harness.reader("ta_update_roofline")(ctx) == pytest.approx(
+        100 * work.roofline_s(kind.ta_update(cfg, 6_400, 200, 0.25),
+                              peak) / 3.0)
+
+
+@pytest.mark.parametrize("kind", [None, "warp"])
+def test_a_configuration_needs_a_kind_with_a_module(tmp_path, kind):
+    """No ``"kind"``, or a kind with no ``kinds/<kind>.py``: ``load_cell``
+    fails naming the file it looked for, and never falls back.  The
+    configuration is read from ``root``; the kind module, being code, is
+    looked for in the package."""
+    cfg = tiny_config()
+    del cfg["kind"]
+    if kind:
+        cfg["kind"] = kind
+    (tmp_path / "bench" / "configs").mkdir(parents=True)
+    (tmp_path / "bench" / "configs" / "tiny.json").write_text(json.dumps(cfg))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"] = [{"name": "tiny", "source": "tests",
+                         "file": "bench/configs/tiny.json", "reduced": [],
+                         "why": "tiny"}]
+    bench["workloads"] = [{"name": "kws6-batch-b32", "config": "tiny",
+                           "traffic": "batch-b32-closed", "chips": 1,
+                           "why": "tiny"}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(LookupError) as e:
+        harness.load_cell("kws6-batch-b32", tmp_path)
+    assert str(BENCH / "kinds" / f"{kind or '<kind>'}.py") in str(e.value)
+
+
+NEW_KIND = '''"""A kind of its own name that runs as the coalesced kind."""
+from kinds.coalesced import *  # noqa: F401,F403
+'''
+
+
+def _files(root) -> dict:
+    return {str(p.relative_to(root)): p.read_bytes()
+            for p in sorted(root.rglob("*"))
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+@pytest.mark.parametrize("cell,rate", [("kws6-batch-b32", "infer_rows_per_s"),
+                                       ("cotm-fit-b32", "train_rows_per_s")])
+def test_a_new_kind_joins_by_new_files_alone(tmp_path, cell, rate):
+    """A copy of the benchmark gains a kind module, a configuration of that
+    kind, a traffic file and its entries: a tiny cell of it runs end to end
+    through the copy's own harness, correct, and no file of the copy that
+    was there before changed (``BENCHMARK.json`` only gained entries)."""
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "BENCHMARK.json").write_text((ROOT / "BENCHMARK.json").read_text())
+    before = _files(root)
+    old = json.loads(before["BENCHMARK.json"])
+
+    cfg = dict(tiny_config(), name="tiny-relabelled", kind="relabelled")
+    (root / "bench" / "kinds" / "relabelled.py").write_text(NEW_KIND)
+    (root / "bench" / "configs" / "tiny-relabelled.json").write_text(
+        json.dumps(cfg))
+    (root / "bench" / "traffic" / "tiny-relabelled.json").write_text(
+        json.dumps(TINY_TRAFFIC[cell]))
+    bench = json.loads(json.dumps(old))
+    bench["configs"].append({"name": "tiny-relabelled", "source": "tests",
+                             "file": "bench/configs/tiny-relabelled.json",
+                             "reduced": [], "why": "tiny"})
+    bench["workloads"].append({"name": "relabelled", "config":
+                               "tiny-relabelled", "traffic": "tiny-relabelled",
+                               "chips": 1, "why": "tiny"})
+    for m in bench["end_to_end"]:
+        if m["name"] == rate:
+            m["workloads"].append("relabelled")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
+    code = ("import sys; sys.path.insert(0, 'bench'); import harness; "
+            "sys.exit(harness.run(harness.parse(['--workload', 'relabelled', "
+            "'--seed', '3000000019', '--seconds', '1.5']), 0.0, "
+            "require_chip=False))")
+    p = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    res = json.loads(p.stdout.strip().splitlines()[-1])
+    assert res["correct"], res["compared"]
+    assert set(res["metrics"]) == {rate, "setup_s"}
+    assert all(c["value"] == 0 for c in res["compared"].values())
+
+    after = _files(root)
+    assert {k: v for k, v in after.items() if k in before
+            and k != "BENCHMARK.json"} == {
+        k: v for k, v in before.items() if k != "BENCHMARK.json"}
+    new = json.loads(after["BENCHMARK.json"])
+
+    def without_cell(e):
+        if "workloads" not in e:
+            return e
+        return dict(e, workloads=[w for w in e["workloads"]
+                                  if w != "relabelled"])
+
+    for key, entries in old.items():
+        if isinstance(entries, list):
+            assert [without_cell(e) for e in new[key][:len(entries)]] == \
+                entries
+        else:
+            assert new[key] == entries
 
 
 def test_unknown_device_has_no_peaks():
@@ -92,6 +223,8 @@ def test_every_cell_is_found_by_name():
     for w in bench["workloads"]:
         d = harness.load_cell(w["name"])
         assert d["config"]["name"] == w["config"]
+        kind = d["config"]["kind"]
+        assert kinds.of(d["config"]).__name__ == f"kinds.{kind}"
         assert d["end_to_end"] and d["per_layer"]
         for m in d["end_to_end"] + d["per_layer"]:
             assert callable(harness.reader(m["name"]))
