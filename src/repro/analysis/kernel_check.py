@@ -222,6 +222,26 @@ def plan_ta_update_streamed(B, L, C, yt=128, xt=256) -> KernelPlan:
         uses=base.uses[:-1] + (rands, base.uses[-1]))
 
 
+def plan_ta_update_conv(B, L, C, yt=128, xt=256) -> KernelPlan:
+    """The conv TA update: ``B`` datapoints' chosen-patch literal words,
+    word-major ``[B, L/32, C]``, and both feedback rounds' codes
+    ``[2B, C]``."""
+    C, L = _pad_to(C, yt), _pad_to(L, xt)
+    grid = (C // yt, L // xt)
+    return KernelPlan(
+        "ta_update_conv", f"B{B} L{L} C{C} yt{yt} xt{xt}", grid,
+        (BlockUse("ta", (C, L), (yt, xt), lambda c, l: (c, l), 4),
+         BlockUse("lit_words", (B, L // _WORD, C), (B, xt // _WORD, yt),
+                  lambda c, l: (0, l, c), 4),
+         BlockUse("fb_code", (2 * B, C), (2 * B, yt), lambda c, l: (0, c),
+                  4),
+         BlockUse("l_mask", (1, L), (1, xt), lambda c, l: (0, l), 4),
+         BlockUse("params", (1, 5), (1, 5), lambda c, l: (0, 0), 4,
+                  smem=True),
+         BlockUse("ta_out", (C, L), (yt, xt), lambda c, l: (c, l), 4,
+                  is_output=True)))
+
+
 # --------------------------------------------------------------------------- #
 # checks                                                                      #
 # --------------------------------------------------------------------------- #
@@ -323,6 +343,10 @@ def check_plan(plan: KernelPlan,
 # the audit space: every plan the tuner can emit                              #
 # --------------------------------------------------------------------------- #
 
+# the Conv CoTM at the CTM's MNIST geometry (bench/configs/mnist-convtm.json)
+CONV_MNIST_SHAPE = (384, 2048, 16)
+
+
 def _audit_shapes() -> List[Tuple[int, int, int]]:
     """(L, R, H) plan-key shapes: the benchmark sweep grid plus the
     committed TileConfig geometries (padded, as the engine pads them)."""
@@ -330,7 +354,7 @@ def _audit_shapes() -> List[Tuple[int, int, int]]:
     from repro.configs.tm_paper import DTM_L_TILE, DTM_S_TILE
     for tile in (DTM_L_TILE, DTM_S_TILE):
         shapes.add(tuple(tile.padded_dims()))
-    return sorted(shapes)
+    return sorted(shapes | {CONV_MNIST_SHAPE})
 
 
 # batch buckets the plan key can hold: edge regime through the largest
@@ -360,6 +384,8 @@ def build_plans() -> List[KernelPlan]:
                 n_groups = _pad_to(R, t["yt"]) // t["yt"]
                 for k in (*_skip_caps(n_groups), n_groups):
                     plans.append(plan_ta_update_sparse(B, L, R, k, **t))
+            # the conv update launches at the engine's default tile
+            plans.append(plan_ta_update_conv(B, L, R))
         for B in STREAMED_BATCHES:
             for t in TA_TILES:
                 plans.append(plan_ta_update_streamed(B, L, R, **t))

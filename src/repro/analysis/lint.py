@@ -54,8 +54,7 @@ RULES: Sequence[Rule] = (
          "flight; any block_until_ready outside collect() re-serialises "
          "the device and silently erases the continuous-batching win."),
     Rule("DTM004", "python-branch-on-traced",
-         "src/repro/kernels/, core/dtm.py, core/feedback.py, "
-         "core/conv_tm.py",
+         "src/repro/kernels/, core/dtm.py, core/feedback.py",
          "Traced-module invariant: Python if/while on a jnp/lax value "
          "concretises the tracer (ConcretizationTypeError at best, a "
          "silent host sync + retrace at worst).  Use jnp.where/lax.cond."),
@@ -103,8 +102,7 @@ RULES: Sequence[Rule] = (
 _RULES_BY_CODE = {r.code: r for r in RULES}
 
 _ENV_OK = ("repro/kernels/ops.py", "repro/kernels/autotune.py")
-_TRACED_MODULES = ("repro/core/dtm.py", "repro/core/feedback.py",
-                   "repro/core/conv_tm.py")
+_TRACED_MODULES = ("repro/core/dtm.py", "repro/core/feedback.py")
 _PACKED_MODULES = ("repro/core/dtm.py",)
 
 

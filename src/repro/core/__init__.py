@@ -11,7 +11,7 @@ from .evaluate import (accuracy, batched_predict, epoch_record,
                        feedback_fit, fit_loop)
 from .dtm import DTMEngine, DTMProgram, TMSession
 from .tm_head import TMHead, pool_backbone_features
-from . import conv_tm, regression_tm
+from . import regression_tm
 
 __all__ = [
     "TMConfig", "TileConfig", "TMState", "init_state", "ta_actions",
@@ -20,7 +20,7 @@ __all__ = [
     "clause_outputs_matmul", "class_sums", "predict", "vanilla_polarity",
     "PRNG", "LFSRState", "make_cluster", "lfsr_step", "cluster_next",
     "train_step", "FeedbackStats", "DTMEngine", "TMSession",
-    "conv_tm", "regression_tm", "accuracy", "batched_predict",
+    "regression_tm", "accuracy", "batched_predict",
     "epoch_record", "feedback_fit", "fit_loop",
     "DTMProgram", "TMHead", "pool_backbone_features",
 ]

@@ -20,11 +20,13 @@ a shared pool.  One engine, both algorithms.
 Unified front-end (ISSUE 2): the engine also lowers the rest of the TM
 family onto the same fixed stage executables —
 
-* **Conv TM** — patch extraction is host-side data prep (:meth:`encode`);
-  per-patch clause evaluation reuses the shared clause datapath over a
-  ``[B·P, L]`` view; OR-over-patches / random-matching-patch feedback are
-  the conv pre/post stages (``_infer_conv`` / ``_train_conv``, compiled
-  once, patch axis padded to ``tile.max_patches`` and masked per program).
+* **Conv TM** — patch extraction is data prep (:meth:`encode`, staged in
+  chunks); per-patch clause evaluation reuses the shared clause datapath
+  over a ``[B·P, L]`` view; OR-over-patches / one-firing-patch feedback
+  are the conv pre/post stages (``_infer_conv`` / ``_train_conv``,
+  compiled once, patch axis padded to ``tile.max_patches`` and masked per
+  program), the TA update the ``ta_update_conv`` kernel on the flat
+  update's keyed streams.
 * **Regression TM** — a *program flag* (``DTMProgram.regression``): the
   same ``_train`` executable computes the error-driven clause selection
   with the Alg-3 fixed-point margin compare and routes it into the shared
@@ -83,6 +85,24 @@ from .types import COALESCED, TMConfig, TileConfig, VANILLA
 # the same plain ints the host fit_loop aggregates.
 STAT_KEYS = ("selected", "active_groups", "total_groups", "correct",
              "abs_err")
+
+# Conv input is staged in launches of this many rows (TMSession.stage)
+STAGE_CHUNK_ROWS = 1024
+
+
+def choose_patch(fired: jax.Array, u: jax.Array) -> jax.Array:
+    """The patch each (datapoint, clause row) is updated against, as
+    Granmo et al. choose it: uniformly among the patches on which the
+    clause fired — the ``(u mod n)``-th of those ``n``, in patch order —
+    and patch 0 where none fired (Type I and II then ignore the
+    literals).
+
+    ``fired`` int32 {0,1} [B, P, R], ``u`` uint32 [B, R] -> int32 [B, R]."""
+    n = fired.sum(axis=1)                                      # [B, R]
+    k = (u % jnp.maximum(n, 1).astype(jnp.uint32)).astype(jnp.int32)
+    rank = jnp.cumsum(fired, axis=1) - 1                       # [B, P, R]
+    return jnp.argmax((fired > 0) & (rank == k[:, None, :]),
+                      axis=1).astype(jnp.int32)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -212,6 +232,10 @@ class DTMEngine:
         # raw-feature variant: a whole serving cycle's requests arrive as
         # ONE int8 block of K [B, L/2] slots and are encoded in-trace
         self._predict_bank_raw = jax.jit(self._predict_bank_raw_impl)
+        # staging: encode one chunk of conv input into the staged [N, P, W]
+        # literals in place (TMSession.stage)
+        self._encode_into = jax.jit(self._encode_into_impl,
+                                    static_argnums=(0,), donate_argnums=(1,))
 
     # ------------------------------------------------------------------ #
     # programming (paper §IV-D-a)                                         #
@@ -335,6 +359,13 @@ class DTMEngine:
                                   (0, 0)))
         return pack_literals(lits)
 
+    def _encode_into_impl(self, spec, out: jax.Array, x: jax.Array,
+                          start: jax.Array) -> jax.Array:
+        """:meth:`encode` of rows ``x``, written into ``out`` at row
+        ``start`` (one staged chunk)."""
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, self.encode(spec, x), start, axis=0)
+
     def refresh_include(self, prog: DTMProgram) -> DTMProgram:
         """Rebuild the packed include bitplane from TA states.
 
@@ -369,7 +400,8 @@ class DTMEngine:
         self._stage_paths[stage] = path
         return path
 
-    def _ta_prng(self, prng: PRNG, stage: str) -> tuple:
+    def _ta_prng(self, prng: PRNG, stage: str,
+                 streamable: bool = True) -> tuple:
         """Resolve the TA-update random-stream family + provenance for
         this trace and record it (key ``<stage>_prng`` in
         ``path_per_stage``, e.g. ``lfsr-inkernel``).
@@ -381,9 +413,12 @@ class DTMEngine:
         the TPU-native counter chains.  The PROVENANCE is
         ``REPRO_TA_PRNG``: ``inkernel`` (default, zero random-bits HBM
         traffic) or ``stream`` (the materialised [B, C, L] baseline,
-        bit-identical — benchmarks/fig15_lfsr.py)."""
+        bit-identical — benchmarks/fig15_lfsr.py).  A stage with no
+        streamed baseline (``streamable=False``: the conv update) always
+        generates in place, and says so."""
         family = "lfsr" if prng.backend == "lfsr" else "counter"
-        stream = kops.resolve_ta_prng() == kops.TA_PRNG_STREAM
+        stream = (streamable
+                  and kops.resolve_ta_prng() == kops.TA_PRNG_STREAM)
         self._stage_paths[stage + "_prng"] = (
             f"{family}-{'stream' if stream else 'inkernel'}")
         return family, stream
@@ -680,34 +715,65 @@ class DTMEngine:
     # ------------------------------------------------------------------ #
     def _train_conv_impl(self, prog: DTMProgram, prng: PRNG,
                          plits: jax.Array, labels: jax.Array):
-        """One batched Conv-TM train step.
+        """One batched Conv-TM train step (:meth:`_conv_step` on one
+        device)."""
+        return self._conv_step(prog, prng, plits, labels, "train_conv")
 
-        Pre-stage: per-patch clause eval on the shared clause datapath
-        ([B·P, W] packed view).  Post-stages: OR over real patches, the
-        ordinary weight-matrix + Alg-3 selection machinery, then Type I/II
-        feedback against ONE random *matching* patch per (datapoint,
-        clause) — the per-clause literal gather makes this the jnp stage of
-        the engine (the shared-literal TA kernel cannot express it).  The
-        updated include bitplane is packed in the same jitted stage."""
+    def _conv_step(self, prog: DTMProgram, prng: PRNG, plits: jax.Array,
+                   labels: jax.Array, stage: str,
+                   axis: Optional[str] = None):
+        """One batched Conv-TM train step, on one device or, given the
+        mesh ``axis``, as one clause shard's body (``launch/pod.py``).
+
+        Draws: the flat step's (negated class [B], selection [2, B, R],
+        a two-word TA seed), then one patch-choice number per
+        (datapoint, clause row) [B, R] — at B=32, R=2048 about 26 cycles
+        of an 8192-lane LFSR cluster; no random tensor of patch or
+        literal width exists.  Pre-stage: per-patch clause eval on the
+        shared clause datapath ([B·P, W] packed view).  Post-stages: OR
+        over real patches, the ordinary weight-matrix + Alg-3 selection
+        machinery, then Type I/II feedback against ONE firing patch per
+        (datapoint, clause) (:func:`choose_patch`).  The chosen patches'
+        packed literal words [B, R, W] are gathered from the staged
+        patches, and the ``ta_update_conv`` kernel updates every TA cell
+        from the flat kernel's keyed in-place streams and emits the
+        updated include bitplane.
+
+        Sharded: every shard draws the same full-width numbers (the PRNG
+        is replicated) and slices its row window, class sums are psum'd
+        raw and pinned after, and the TA streams are keyed at GLOBAL rows
+        (``row0``) — the shards together are bit-identical to the
+        single-device step, with zero cross-shard TA traffic."""
         B, P, W = plits.shape
-        L, R = self.L, self.R
-        pl_dense = unpack_literals(plits, L)                       # [B,P,L]
+        row0, r_loc = 0, self.R
+        if axis is not None:
+            row0, r_loc, shards = self._shard_window(prog, axis)
+            self._stage_paths[stage + "_shard"] = f"{axis}:{shards}"
         n_cls = prog.h_mask.sum()
 
         prng, c_rand = prng.bits((B,))
-        prng, patch_rand = prng.bits((B, P, R))
-        prng, sel_rand = prng.bits((2, B, R))
-        prng, ta_rand = prng.bits((2, B, R, L))
+        prng, sel_rand = prng.bits((2, B, self.R))
+        prng, seed_bits = prng.bits((2,))
+        prng, patch_rand = prng.bits((B, self.R))
+        ta_seed = (seed_bits[0] << jnp.uint32(self.rand_bits)) | seed_bits[1]
+        if axis is not None:
+            sel_rand = jax.lax.dynamic_slice_in_dim(sel_rand, row0, r_loc,
+                                                    axis=2)
+            patch_rand = jax.lax.dynamic_slice_in_dim(patch_rand, row0,
+                                                      r_loc, axis=1)
 
         rn = (c_rand % (jnp.maximum(n_cls - 1, 1).astype(jnp.uint32))
               ).astype(jnp.int32)
         neg = jnp.where(rn < labels, rn, rn + 1)                       # [B]
 
         cl_p = self._clause_outputs(prog, plits.reshape(B * P, W),
-                                    eval_mode=False, stage="train_conv")
-        cl_p = cl_p.reshape(B, P, R) * prog.p_mask[None, :, None]
-        cl = cl_p.max(axis=1)                                          # [B,R]
-        sums = self._class_sums(prog, cl)
+                                    eval_mode=False, stage=stage)
+        cl_p = cl_p.reshape(B, P, r_loc) * prog.p_mask[None, :, None]
+        cl = cl_p.max(axis=1)                                  # [B, r_loc]
+        sums = self._class_sums_raw(prog, cl)
+        if axis is not None:
+            sums = jax.lax.psum(sums, axis)
+        sums = self._pin_class_sums(prog, sums)
         correct = (jnp.argmax(sums, -1) == labels).sum()
 
         # Alg-3 selection (same fixed-point compare as the fused kernel)
@@ -719,50 +785,34 @@ class DTMEngine:
             sums, neg, 0, sel_rand[1], prog.weights, prog.cl_mask,
             prog.T, wf, rand_bits=self.rand_bits)
 
-        # ONE random matching patch per (datapoint, clause): perturbed
-        # argmax over the patch axis (p_mask already zeroed padded slots)
-        noise = (patch_rand % jnp.uint32(997)).astype(jnp.int32)   # [B,P,R]
-        patch_idx = jnp.argmax(cl_p * 1000 + noise, axis=1)        # [B,R]
-        onehot = (patch_idx[:, :, None]
-                  == jnp.arange(P)[None, None, :]).astype(jnp.int8)
-        sel_lits = jnp.einsum("brp,bpl->brl", onehot, pl_dense,
-                              preferred_element_type=jnp.int32)    # [B,R,L]
-
-        w_lab = jnp.take(prog.weights, labels, axis=0)             # [B,R]
+        # Type I/II against the chosen patch's literals (Alg 5, gated by
+        # the OR-level clause output as Granmo et al. gate it), both
+        # rounds stacked as the flat update stacks them
+        patch = choose_patch(cl_p, patch_rand)                 # [B, r_loc]
+        words = jnp.take_along_axis(plits, patch[:, :, None], axis=1)
+        w_lab = jnp.take(prog.weights, labels, axis=0)         # [B, r_loc]
         w_neg = jnp.take(prog.weights, neg, axis=0)
-        rounds = ((sel_lab * (w_lab >= 0), sel_lab * (w_lab < 0),
-                   ta_rand[0]),
-                  (sel_neg * (w_neg < 0), sel_neg * (w_neg >= 0),
-                   ta_rand[1]))
+        t1 = jnp.concatenate([sel_lab * (w_lab >= 0), sel_neg * (w_neg < 0)])
+        t2 = jnp.concatenate([sel_lab * (w_lab < 0), sel_neg * (w_neg >= 0)])
+        self._stage_paths[stage + "_ta"] = self._kb
+        ta_prng, _ = self._ta_prng(prng, stage + "_ta", streamable=False)
+        new_ta, new_inc = kops.ta_update_conv_op(
+            prog.ta, words, jnp.concatenate([cl, cl]), t1, t2, prog.l_mask,
+            seed=ta_seed, p_ta=prog.p_ta, rand_bits=self.rand_bits,
+            boost=prog.boost, n_states=prog.n_states, backend=self._kb,
+            row0=row0, prng=ta_prng, lfsr_bits=prng.lfsr_bits,
+            seed_refresh=prng.seed_refresh)
 
-        # Type I/II deltas against the selected patch's literals (Alg 5,
-        # gated by the OR-level clause output exactly like conv_tm.py)
-        clb = (cl > 0)[:, :, None]                                 # [B,R,1]
-        litb = sel_lits > 0                                        # [B,R,L]
-        # include from the maintained bitplane — no TA re-threshold
-        incb = (unpack_literals(prog.inc, L) > 0)[None]            # [1,R,L]
-        cl_and_lit = clb & litb
-        inc2 = (clb & ~litb & ~incb).astype(jnp.int8)
-        delta = jnp.zeros((R, L), jnp.int32)
-        for t1, t2, tr in rounds:
-            low = tr < prog.p_ta
-            inc1 = jnp.where(prog.boost, cl_and_lit, cl_and_lit & ~low)
-            d1 = inc1.astype(jnp.int8) - (~cl_and_lit & low).astype(jnp.int8)
-            delta = (delta
-                     + jnp.einsum("br,brl->rl", t1.astype(jnp.int32),
-                                  d1.astype(jnp.int32))
-                     + jnp.einsum("br,brl->rl", t2.astype(jnp.int32),
-                                  inc2.astype(jnp.int32)))
-        delta = delta * prog.l_mask[None, :] * prog.cl_mask[:, None]
-        new_ta = jnp.clip(prog.ta.astype(jnp.int32) + delta, 0,
-                          prog.n_states - 1)
-
-        new_w, stats = self._weights_and_stats(
-            prog, cl, sel_lab, sel_neg, labels, neg, correct,
-            abs_err=jnp.asarray(0, jnp.int32))
+        zero = jnp.asarray(0, jnp.int32)
+        if axis is None:
+            new_w, stats = self._weights_and_stats(
+                prog, cl, sel_lab, sel_neg, labels, neg, correct, zero)
+        else:
+            new_w, stats = self._weights_and_stats_sharded(
+                prog, cl, sel_lab, sel_neg, labels, neg, correct, zero, axis)
         new_prog = dataclasses.replace(
             prog, ta=new_ta.astype(prog.ta.dtype), weights=new_w,
-            inc=_pack_include(new_ta, prog.n_states))
+            inc=new_inc)
         return new_prog, prng, stats
 
     def train_conv(self, prog: DTMProgram, prng: PRNG, plits: jax.Array,
@@ -923,90 +973,9 @@ class DTMEngine:
                                  plits: jax.Array, labels: jax.Array,
                                  axis: str = "clauses",
                                  stage: str = "train_conv_sharded"):
-        """Clause-sharded Conv-TM train-step body (mirrors
-        :meth:`_train_conv_impl` with row-sliced draws and local patch
-        feedback).  The full-width ``ta_rand`` draw means transient
-        memory scales with the GLOBAL R — the price of bit-exact streams;
-        the conv TA stage is the engine's jnp stage anyway."""
-        B, P, W = plits.shape
-        L, R = self.L, self.R
-        row0, r_loc, shards = self._shard_window(prog, axis)
-        pl_dense = unpack_literals(plits, L)                   # [B, P, L]
-        n_cls = prog.h_mask.sum()
-
-        prng, c_rand = prng.bits((B,))
-        prng, patch_rand_f = prng.bits((B, P, R))
-        prng, sel_rand_f = prng.bits((2, B, R))
-        prng, ta_rand_f = prng.bits((2, B, R, L))
-        patch_rand = jax.lax.dynamic_slice_in_dim(patch_rand_f, row0,
-                                                  r_loc, axis=2)
-        sel_rand = jax.lax.dynamic_slice_in_dim(sel_rand_f, row0, r_loc,
-                                                axis=2)
-        ta_rand = jax.lax.dynamic_slice_in_dim(ta_rand_f, row0, r_loc,
-                                               axis=2)
-
-        rn = (c_rand % (jnp.maximum(n_cls - 1, 1).astype(jnp.uint32))
-              ).astype(jnp.int32)
-        neg = jnp.where(rn < labels, rn, rn + 1)                   # [B]
-
-        cl_p = self._clause_outputs(prog, plits.reshape(B * P, W),
-                                    eval_mode=False, stage=stage)
-        cl_p = cl_p.reshape(B, P, r_loc) * prog.p_mask[None, :, None]
-        cl = cl_p.max(axis=1)                                  # [B, r_loc]
-        sums = self._pin_class_sums(
-            prog, jax.lax.psum(self._class_sums_raw(prog, cl), axis))
-        correct = (jnp.argmax(sums, -1) == labels).sum()
-        self._stage_paths[stage + "_shard"] = f"{axis}:{shards}"
-
-        wf = prog.w_frozen.astype(jnp.int32)
-        sel_lab = kops.round_select_op(
-            sums, labels, 1, sel_rand[0], prog.weights, prog.cl_mask,
-            prog.T, wf, rand_bits=self.rand_bits)
-        sel_neg = kops.round_select_op(
-            sums, neg, 0, sel_rand[1], prog.weights, prog.cl_mask,
-            prog.T, wf, rand_bits=self.rand_bits)
-
-        noise = (patch_rand % jnp.uint32(997)).astype(jnp.int32)
-        patch_idx = jnp.argmax(cl_p * 1000 + noise, axis=1)    # [B, r_loc]
-        onehot = (patch_idx[:, :, None]
-                  == jnp.arange(P)[None, None, :]).astype(jnp.int8)
-        sel_lits = jnp.einsum("brp,bpl->brl", onehot, pl_dense,
-                              preferred_element_type=jnp.int32)
-
-        w_lab = jnp.take(prog.weights, labels, axis=0)         # [B, r_loc]
-        w_neg = jnp.take(prog.weights, neg, axis=0)
-        rounds = ((sel_lab * (w_lab >= 0), sel_lab * (w_lab < 0),
-                   ta_rand[0]),
-                  (sel_neg * (w_neg < 0), sel_neg * (w_neg >= 0),
-                   ta_rand[1]))
-
-        clb = (cl > 0)[:, :, None]
-        litb = sel_lits > 0
-        incb = (unpack_literals(prog.inc, L) > 0)[None]
-        cl_and_lit = clb & litb
-        inc2 = (clb & ~litb & ~incb).astype(jnp.int8)
-        delta = jnp.zeros((r_loc, L), jnp.int32)
-        for t1, t2, tr in rounds:
-            low = tr < prog.p_ta
-            inc1 = jnp.where(prog.boost, cl_and_lit, cl_and_lit & ~low)
-            d1 = (inc1.astype(jnp.int8)
-                  - (~cl_and_lit & low).astype(jnp.int8))
-            delta = (delta
-                     + jnp.einsum("br,brl->rl", t1.astype(jnp.int32),
-                                  d1.astype(jnp.int32))
-                     + jnp.einsum("br,brl->rl", t2.astype(jnp.int32),
-                                  inc2.astype(jnp.int32)))
-        delta = delta * prog.l_mask[None, :] * prog.cl_mask[:, None]
-        new_ta = jnp.clip(prog.ta.astype(jnp.int32) + delta, 0,
-                          prog.n_states - 1)
-
-        new_w, stats = self._weights_and_stats_sharded(
-            prog, cl, sel_lab, sel_neg, labels, neg, correct,
-            jnp.asarray(0, jnp.int32), axis)
-        new_prog = dataclasses.replace(
-            prog, ta=new_ta.astype(prog.ta.dtype), weights=new_w,
-            inc=_pack_include(new_ta, prog.n_states))
-        return new_prog, prng, stats
+        """Clause-sharded Conv-TM train-step body: :meth:`_conv_step`
+        over this shard's row window."""
+        return self._conv_step(prog, prng, plits, labels, stage, axis=axis)
 
     def _weights_and_stats_sharded(self, prog: DTMProgram, cl, sel_lab,
                                    sel_neg, lab, neg, correct, abs_err,
@@ -1299,11 +1268,40 @@ class TMSession:
 
         Row-wise encoding commutes with gathering, so device-side
         ``take`` of staged rows is bit-identical to encoding the gathered
-        host batch (the fit_loop order of operations)."""
-        self._lits = self._encode(x)
+        host batch (the fit_loop order of operations).
+
+        Flat kinds encode in one launch.  Conv input is encoded in chunks
+        of :data:`STAGE_CHUNK_ROWS` rows, each one jitted launch that
+        writes its
+        packed [rows, P, W] literals into the staged [N, P, W] array in
+        place; the last chunk ends at row N (re-encoding rows of the one
+        before it), so every launch has one shape.  The peak is the packed
+        epoch plus one chunk's intermediates — the one-shot encode would
+        hold the [N, P, L] int8 layout and its temporaries (≈ 14 GB at
+        60,000 MNIST images, for 1.04 GB of packed literals).  Each
+        launch is one ``tm.fit.encode`` span (``chunk=i``)."""
+        if self.conv:
+            self._lits = self._stage_conv(x)
+        else:
+            with span(spans.FIT_ENCODE, chunk=0):
+                self._lits = self._encode(x)
         self._labels = self._encode_labels(y)
         self.n = int(self._lits.shape[0])
         return self
+
+    def _stage_conv(self, x) -> jax.Array:
+        eng = self.engine
+        x = x if isinstance(x, jax.Array) else np.asarray(x)
+        n = x.shape[0]
+        c = min(STAGE_CHUNK_ROWS, n)
+        starts = list(range(0, n - c + 1, c))
+        if starts[-1] + c < n:
+            starts.append(n - c)
+        out = jnp.zeros((n, eng.P, eng.W), jnp.uint32)
+        for i, s in enumerate(starts):
+            with span(spans.FIT_ENCODE, chunk=i):
+                out = eng._encode_into(self.spec, out, x[s:s + c], s)
+        return out
 
     @property
     def conv(self) -> bool:

@@ -47,7 +47,8 @@ from .class_sum import class_sum
 from .clause_eval import clause_eval
 from .fused_step import fused_step
 from .packed_clause import packed_clause_eval, packed_clause_eval_mxu
-from .ta_update import ta_update, ta_update_sparse, ta_update_streamed
+from .ta_update import (ta_update, ta_update_conv, ta_update_sparse,
+                        ta_update_streamed)
 
 from .tm_infer import tm_infer
 
@@ -420,6 +421,47 @@ def ta_update_op(ta, literals, clause_out, type1, type2, l_mask, seed, p_ta,
     if emit_include:
         return new_ta, ref.pack_include(new_ta, n_states)
     return new_ta
+
+
+@functools.partial(jax.jit, static_argnames=("rand_bits", "backend", "yt",
+                                             "xt", "prng", "lfsr_bits",
+                                             "seed_refresh"))
+def ta_update_conv_op(ta, lit_words, clause_out, type1, type2, l_mask, seed,
+                      p_ta, rand_bits=16, boost=True, n_states=256,
+                      backend="pallas", yt=128, xt=256, row0=0,
+                      prng="counter", lfsr_bits=24, seed_refresh=True):
+    """Conv-TM TA update [C, L] -> ``(new_ta int32 [C, L], new_inc uint32
+    [C, W])``: every clause row of datapoint ``b`` is updated against its
+    own chosen patch, whose packed literals ``lit_words`` [B, C, W] carry
+    (W = ceil(L/32)); ``clause_out``/``type1``/``type2`` are [2B, C] over
+    both feedback rounds (target rounds first).  Pads C/L to whole tiles
+    (W to the padded width) and strips on return, like
+    :func:`ta_update_op`, whose streams (``seed``, ``row0``, ``prng``,
+    ``lfsr_bits``, ``seed_refresh``, padded row stride) it shares: the
+    Pallas kernel ``ta_update_conv`` generates the randoms in place, the
+    ``ref`` backend runs its oracle.  Always dense; the packed include
+    bitplane of the updated states is emitted in the same call."""
+    C, L = ta.shape
+    if backend == "ref":
+        rows = (jnp.asarray(row0, jnp.int32)
+                + jnp.arange(C, dtype=jnp.int32))
+        new_ta = ref.ta_update_conv_ref(
+            ta, lit_words, clause_out, type1, type2, l_mask, seed, p_ta,
+            rand_bits, boost, n_states, xt=xt, row_idx=rows, prng=prng,
+            lfsr_bits=lfsr_bits, seed_refresh=seed_refresh)
+    else:
+        ta_p = _pad2(ta, yt, xt)
+        C_pad, L_pad = ta_p.shape
+        words = jnp.pad(lit_words, ((0, 0), (0, C_pad - C),
+                                    (0, L_pad // 32 - lit_words.shape[2])))
+        out = ta_update_conv(
+            ta_p, words, _pad2(clause_out, 1, yt), _pad2(type1, 1, yt),
+            _pad2(type2, 1, yt), jnp.pad(l_mask, (0, L_pad - L)), seed=seed,
+            p_ta=p_ta, rand_bits=rand_bits, boost=boost, n_states=n_states,
+            yt=yt, xt=xt, row0=row0, prng=prng, lfsr_bits=lfsr_bits,
+            seed_refresh=seed_refresh, interpret=resolve_interpret())
+        new_ta = out[:C, :L]
+    return new_ta, ref.pack_include(new_ta, n_states)
 
 
 def _skip_caps(n_groups: int) -> tuple:

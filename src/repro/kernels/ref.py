@@ -337,10 +337,12 @@ def ta_rand_stream(seed, batch: int, C: int, L: int, rand_bits: int = 16,
 
 
 def _ta_delta_step(rand, lit_b, cl_b, t1_b, t2_b, include, p_ta, boost):
-    """One batch element's Alg-5 TA delta [C, L] given its random words."""
+    """One batch element's Alg-5 TA delta [C, L] given its random words.
+    ``lit_b`` is the element's literal row [L], or one row per clause
+    [C, L] (the conv update: each clause's chosen patch)."""
     low = rand < jnp.asarray(p_ta, jnp.uint32)
     clb = (cl_b > 0)[:, None]
-    litb = (lit_b > 0)[None, :]
+    litb = jnp.atleast_2d(lit_b > 0)
     cl_and_lit = clb & litb
     inc1 = jnp.where(boost, cl_and_lit, cl_and_lit & ~low)
     dec1 = ~cl_and_lit & low
@@ -405,5 +407,46 @@ def ta_update_ref(ta, literals, clause_out, type1, type2, l_mask, seed,
 
         delta, _ = jax.lax.scan(
             body, zero, (literals, clause_out, type1, type2, rands))
+    delta = delta * l_mask.astype(jnp.int32)[None, :]
+    return jnp.clip(ta.astype(jnp.int32) + delta, 0, n_states - 1)
+
+
+def ta_update_conv_ref(ta, lit_words, clause_out, type1, type2, l_mask,
+                       seed, p_ta, rand_bits=16, boost=True, n_states=256,
+                       xt=256, row_idx=None, prng="counter", lfsr_bits=24,
+                       seed_refresh=True):
+    """Bit-exact oracle for kernels.ta_update_conv: :func:`ta_update_ref`
+    with a literal row of its own for every (element, clause row).
+
+    ``lit_words`` uint32 [Bl, C, W] holds, per element and clause row,
+    the packed literals of the patch that row is updated against (W*32 >=
+    L).  ``clause_out``/``type1``/``type2`` are [E, C] over the stacked
+    feedback rounds (E = 2·Bl: every target round, then every negated
+    round); element ``e`` reads ``lit_words[e % Bl]``, so both rounds of a
+    datapoint use its one patch choice.  Streams, keys (``row_idx``,
+    ``xt``) and families are exactly :func:`ta_update_ref`'s: a TA cell
+    sees the same random numbers in a conv update as in a flat one."""
+    C, L = ta.shape
+    boost = jnp.asarray(boost)
+    n_states = jnp.asarray(n_states, jnp.int32)
+    include = ta.astype(jnp.int32) >= (n_states >> 1)
+    key = stream_keys(C, L, xt, row_idx)
+    st0 = stream_start(seed, key, prng, lfsr_bits)
+    reps = clause_out.shape[0] // lit_words.shape[0]
+    words = jnp.concatenate([lit_words] * reps)                  # [E, C, W]
+
+    def body(carry, xs):
+        st, delta = carry
+        w_b, cl_b, t1_b, t2_b = xs
+        st, rand = stream_advance(st, key, prng, lfsr_bits, seed_refresh,
+                                  rand_bits)
+        lit_b = unpack_bitplanes_i8(w_b)[:, :L]                  # [C, L]
+        delta = delta + _ta_delta_step(rand, lit_b, cl_b, t1_b, t2_b,
+                                       include, p_ta, boost)
+        return (st, delta), None
+
+    (_, delta), _ = jax.lax.scan(
+        body, (st0, jnp.zeros((C, L), jnp.int32)),
+        (words, clause_out, type1, type2))
     delta = delta * l_mask.astype(jnp.int32)[None, :]
     return jnp.clip(ta.astype(jnp.int32) + delta, 0, n_states - 1)

@@ -22,6 +22,11 @@ mapping:
     xorshift-advanced master every 2^lfsr_bits−1 cycles when
     ``seed_refresh`` is set — the FPGA's per-TA LFSR bank, in place.
 
+The Conv-TM update (``ta_update_conv``) runs the same tile body and
+streams with one change: every (batch element, clause row) reads the
+literals of its own chosen patch, packed 32 to a word and unpacked in the
+kernel, instead of one literal row shared by every clause.
+
 Semantics (validated bit-exactly against ref.py):
   Type I  (t1): cl∧lit → +1 w.p. (s-1)/s (boost: always);
                 ¬(cl∧lit) → −1 w.p. 1/s        [p_ta = ⌊2^rand_bits/s⌋]
@@ -83,10 +88,29 @@ def _batch_rows(lit_ref, code_ref, b):
     return lit_ref[pl.ds(b, 1), :], code_ref[pl.ds(b, 1), :].T
 
 
+def _conv_rows(words_ref, code_ref, b, *, n_lit: int, xt: int):
+    """Element ``b``'s literal tile [yt, xt], one literal row per clause
+    row (the patch that row is updated against), and its feedback-code
+    column [yt, 1].  ``words_ref`` [n_lit, xt/32, yt] is word-major, so
+    each word of the tile is one [1, yt] row turned into a clause column
+    (the same dynamic read as :func:`_batch_rows`) and spread over its 32
+    lanes; element ``b`` reads literal block ``b % n_lit`` (both feedback
+    rounds of a datapoint share its patch choice)."""
+    e = jax.lax.rem(b, n_lit)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, xt), 1)
+    word, bit = lane >> 5, lane & 31
+    lit = None
+    for w in range(xt // 32):
+        col = words_ref[e, pl.ds(w, 1), :].T                  # [yt, 1]
+        lit = col if lit is None else jnp.where(word == w, col, lit)
+    return (lit >> bit) & 1, code_ref[pl.ds(b, 1), :].T
+
+
 def _tile_update(ci, li, ta_ref, lit_ref, code_ref, lmask_ref, params_ref,
                  out_ref, *, batch: int, n_l_tiles: int, yt: int, xt: int,
                  rand_bits: int, prng: str = "counter",
-                 lfsr_bits: int = 24, seed_refresh: bool = True):
+                 lfsr_bits: int = 24, seed_refresh: bool = True,
+                 rows=_batch_rows):
     """Shared (yt, xt) TA-tile update body.
 
     ``ci``/``li`` are the tile's GLOBAL grid coordinates — the dense kernel
@@ -100,7 +124,10 @@ def _tile_update(ci, li, ta_ref, lit_ref, code_ref, lmask_ref, params_ref,
 
     ``prng``/``lfsr_bits``/``seed_refresh`` select the stream family
     (module docstring); all stream state lives in registers/VMEM — only
-    the uint32 master seed crosses from SMEM."""
+    the uint32 master seed crosses from SMEM.  ``rows(lit_ref, code_ref,
+    b)`` reads batch element ``b``'s literals and feedback codes:
+    :func:`_batch_rows` (one literal row shared by every clause) or, for
+    the conv update, :func:`_conv_rows`."""
     # dynamic model scalars ride in SMEM — a DTMProgram swap or a fresh
     # per-step seed never retraces (cache-size == 1 semantics, §IV-D-a).
     seed = params_ref[0, 0]
@@ -124,7 +151,7 @@ def _tile_update(ci, li, ta_ref, lit_ref, code_ref, lmask_ref, params_ref,
         st, delta = carry
         st, rand = stream_advance(st, key, prng, lfsr_bits, seed_refresh,
                                   rand_bits)
-        lit_row, code_col = _batch_rows(lit_ref, code_ref, b)
+        lit_row, code_col = rows(lit_ref, code_ref, b)
         delta = _tile_delta(rand, lit_row, code_col, include, p_ta, boost,
                             delta)
         return st, delta
@@ -159,6 +186,20 @@ def _sparse_kernel(idx_ref, params_ref, ta_ref, lit_ref, code_ref,
                  batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
                  rand_bits=rand_bits, prng=prng, lfsr_bits=lfsr_bits,
                  seed_refresh=seed_refresh)
+
+
+def _conv_kernel(ta_ref, words_ref, code_ref, lmask_ref, params_ref,
+                 out_ref, *, batch: int, n_lit: int, n_l_tiles: int, yt: int,
+                 xt: int, rand_bits: int, prng: str, lfsr_bits: int,
+                 seed_refresh: bool):
+    """Dense conv grid step: the flat tile body, with each clause row's
+    literals read from its own chosen patch (:func:`_conv_rows`)."""
+    _tile_update(pl.program_id(0), pl.program_id(1), ta_ref, words_ref,
+                 code_ref, lmask_ref, params_ref, out_ref,
+                 batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
+                 rand_bits=rand_bits, prng=prng, lfsr_bits=lfsr_bits,
+                 seed_refresh=seed_refresh,
+                 rows=functools.partial(_conv_rows, n_lit=n_lit, xt=xt))
 
 
 def _streamed_kernel(ta_ref, lit_ref, code_ref, lmask_ref, rand_ref,
@@ -374,3 +415,66 @@ def ta_update_streamed(ta: jax.Array, literals: jax.Array,
       feedback_code(clause_out, type1, type2),
       l_mask.reshape(1, L).astype(jnp.int32), rands.astype(jnp.uint32),
       params)
+
+
+@functools.partial(jax.jit, static_argnames=("rand_bits", "yt", "xt",
+                                             "prng", "lfsr_bits",
+                                             "seed_refresh", "interpret"))
+def ta_update_conv(ta: jax.Array, lit_words: jax.Array,
+                   clause_out: jax.Array, type1: jax.Array,
+                   type2: jax.Array, l_mask: jax.Array, seed, p_ta,
+                   rand_bits: int = 16, boost=True, n_states=256,
+                   yt: int = 128, xt: int = 256, row0=0,
+                   prng: str = "counter", lfsr_bits: int = 24,
+                   seed_refresh: bool = True,
+                   interpret: bool | None = None) -> jax.Array:
+    """Batched Conv-TM TA update: :func:`ta_update` with a literal row of
+    its own for every (batch element, clause row).
+
+    ``lit_words`` uint32 [Bl, C, L/32] holds the packed literals of the
+    patch each clause row of datapoint ``b`` is updated against (the
+    engine gathers them from the staged [B, P, W] patches by the chosen
+    patch index — no [B, C, L] tensor, no one-hot matmul);
+    ``clause_out``/``type1``/``type2`` are [E, C] over the stacked
+    feedback rounds (E = 2·Bl, target rounds first), and element ``e``
+    reads literal block ``e % Bl``.  The in-kernel streams are keyed,
+    seeded and advanced exactly as :func:`ta_update`'s (global row
+    ``row0 + row`` times the padded row width, plus the column), so a
+    conv TA cell sees the random numbers a flat one does.  Returns new
+    ta [C, L] int32; ``ops.ta_update_conv_op`` pads and emits the packed
+    include bitplane.  Oracle: ``ref.ta_update_conv_ref``."""
+    if interpret is None:
+        from .ops import resolve_interpret     # local: ops imports us
+        interpret = resolve_interpret()
+    C, L = ta.shape
+    E, Bl = clause_out.shape[0], lit_words.shape[0]
+    assert C % yt == 0 and L % xt == 0 and xt % 32 == 0, ((C, L), (yt, xt))
+    assert lit_words.shape == (Bl, C, L // 32), (lit_words.shape, (C, L))
+    # word-major so a tile's words are (8, 128)-aligned rows of the block
+    words = jax.lax.bitcast_convert_type(lit_words, jnp.int32).transpose(
+        0, 2, 1)                                            # [Bl, L/32, C]
+    grid = (C // yt, L // xt)
+    params = _params(seed, p_ta, boost, n_states, row0)
+    need = block_bytes(((yt, xt), 4), ((Bl, xt // 32, yt), 4), ((E, yt), 4),
+                       ((1, xt), 4), ((yt, xt), 4))
+    return pl.pallas_call(
+        functools.partial(_conv_kernel, batch=E, n_lit=Bl,
+                          n_l_tiles=grid[1], yt=yt, xt=xt,
+                          rand_bits=rand_bits, prng=prng,
+                          lfsr_bits=lfsr_bits, seed_refresh=seed_refresh),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((yt, xt), lambda c, l: (c, l)),       # ta
+            pl.BlockSpec((Bl, xt // 32, yt),
+                         lambda c, l: (0, l, c)),              # lit words
+            pl.BlockSpec((E, yt), lambda c, l: (0, c)),        # fb codes
+            pl.BlockSpec((1, xt), lambda c, l: (0, l)),        # l_mask
+            pl.BlockSpec((1, 5), lambda c, l: (0, 0),
+                         memory_space=pltpu.SMEM),             # scalars
+        ],
+        out_specs=pl.BlockSpec((yt, xt), lambda c, l: (c, l)),
+        out_shape=jax.ShapeDtypeStruct((C, L), jnp.int32),
+        compiler_params=compiler_params(("parallel", "parallel"), need),
+        interpret=interpret,
+    )(ta.astype(jnp.int32), words, feedback_code(clause_out, type1, type2),
+      l_mask.reshape(1, L).astype(jnp.int32), params)

@@ -30,6 +30,7 @@ SERVER_FETCH = "tm.server.fetch"          # the host sync of a collect
 SERVER_TRAIN = "tm.server.train"          # one online training step
 # fit session (core/dtm.py)
 FIT_BIND = "tm.fit.bind"                  # encode + stage the dataset
+FIT_ENCODE = "tm.fit.encode"              # one staged chunk (chunk=n)
 FIT_PLAN = "tm.fit.plan"                  # permutation + plan device_put
 FIT_EPOCH = "tm.fit.epoch"                # the epoch's scan dispatch
 FIT_FETCH = "tm.fit.fetch"                # step stats device_get + record

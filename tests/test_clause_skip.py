@@ -273,12 +273,18 @@ def test_engine_skip_bit_identical_ref(kind, monkeypatch):
     for leaf1, leaf0 in zip(jax.tree.leaves(tm1.program),
                             jax.tree.leaves(tm0.program)):
         np.testing.assert_array_equal(np.asarray(leaf1), np.asarray(leaf0))
-    if kind != "conv":      # conv's TA stage is the jnp conv-feedback path
+    if kind != "conv":
         # the skip dimension is recorded (and differs between the runs)
         assert tm1.engine.cache_report()["path_per_stage"]["train_ta"] == \
             kops.TA_COMPACT
         assert tm0.engine.cache_report()["path_per_stage"]["train_ta"] == \
             kops.TA_DENSE
+    else:
+        # the conv TA update is the dense ta_update_conv stage either way;
+        # it records its backend, the oracle here
+        for tm in (tm1, tm0):
+            assert tm.engine.cache_report()["path_per_stage"][
+                "train_conv_ta"] == "ref"
     # lifetime skip accounting agrees between the two execution modes
     assert tm1.skip_frac == tm0.skip_frac
     assert tm1.skip_frac is not None

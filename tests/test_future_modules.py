@@ -1,4 +1,9 @@
-"""Paper §VI future-work modules: Convolutional TM and Regression TM."""
+"""Paper §VI future-work modules: the Convolutional TM's plain reference
+(``bench/kinds/conv_ref.py``) and the Regression TM."""
+import json
+import pathlib
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,12 +14,30 @@ import pytest
 pytestmark = pytest.mark.slow
 
 from repro.core import to_literals
-from repro.core.conv_tm import (ConvTMConfig, init as conv_init,
-                                predict as conv_predict,
-                                train_step as conv_step)
 from repro.core.regression_tm import (RegressionTMConfig, init as rtm_init,
                                       predict as rtm_predict,
                                       train_step as rtm_step)
+
+BENCH = pathlib.Path(__file__).resolve().parents[1] / "bench"
+if str(BENCH) not in sys.path:
+    sys.path.insert(0, str(BENCH))
+
+from kinds import conv  # noqa: E402
+
+
+def _conv_config(img: int, patch: int, clauses: int, classes: int,
+                 T: int) -> dict:
+    """``mnist-convtm`` at a toy size; the engine block only keys the
+    reference's random streams, so any layout that holds the model does."""
+    cfg = json.loads((BENCH / "configs" / "mnist-convtm.json").read_text())
+    cfg.update(img_h=img, img_w=img, patch=patch, clauses=clauses,
+               classes=classes, T=T, s=3.0)
+    cfg["engine"] = dict(literal_columns=128, selection_rows=128,
+                         classes_padded=8, negated_literal_column=64,
+                         ta_row_stride=256, skip_group_rows=128,
+                         draw_lanes=8192,
+                         patch_slots=(img - patch + 1) ** 2)
+    return cfg
 
 
 def _translated_motifs(n, seed=0):
@@ -33,32 +56,29 @@ def _translated_motifs(n, seed=0):
 
 
 def test_conv_tm_position_invariance():
-    """ConvTM classifies motifs at RANDOM positions (flat TMs cannot —
-    measured gap > 0.4; see benchmarks/convtm_bench.py)."""
-    cfg = ConvTMConfig(img_h=8, img_w=8, patch=3, clauses=48, classes=3,
-                       T=12, s=3.0)
-    state, prng = conv_init(cfg, jax.random.PRNGKey(0))
+    """The conv reference classifies motifs at RANDOM positions (flat TMs
+    cannot — measured gap > 0.4; see benchmarks/convtm_bench.py)."""
+    cfg = _conv_config(img=8, patch=3, clauses=48, classes=3, T=12)
+    hp = conv.hyper(cfg)
+    ta, w = conv.program(cfg, None, "paper_init", 0, 0)
     x, y = _translated_motifs(640)
-    xtr, ytr, xte, yte = x[:512], y[:512], x[512:], y[512:]
-    step = jax.jit(lambda s, p, im, lb: conv_step(cfg, s, p, im, lb))
+    xs = jnp.asarray(x[:512].reshape(16, 32, 8, 8))
+    ys = jnp.asarray(y[:512].reshape(16, 32))
     for ep in range(4):
-        for i in range(0, 512, 32):
-            state, prng, _ = step(state, prng, jnp.asarray(xtr[i:i + 32]),
-                                  jnp.asarray(ytr[i:i + 32]))
-    pred = np.asarray(conv_predict(cfg, state, jnp.asarray(xte)))
-    assert (pred == yte).mean() > 0.85
+        (ta, w, _), _ = conv.train(hp, ta, w, 1 + ep, xs, ys)
+    pred = np.asarray(conv.predict(hp, ta, w, jnp.asarray(x[512:])))
+    assert (pred == y[512:]).mean() > 0.85
 
 
 def test_conv_tm_state_bounds():
-    cfg = ConvTMConfig(img_h=6, img_w=6, patch=3, clauses=16, classes=2,
-                       T=8, s=3.0)
-    state, prng = conv_init(cfg, jax.random.PRNGKey(0))
+    cfg = _conv_config(img=6, patch=3, clauses=16, classes=2, T=8)
+    ta, w = conv.program(cfg, None, "paper_init", 0, 0)
     x, y = _translated_motifs(32)
-    x = x[:, :6, :6]
-    state, prng, _ = conv_step(cfg, state, prng, jnp.asarray(x),
-                               jnp.asarray(y % 2))
-    ta = np.asarray(state.ta)
-    assert ta.min() >= 0 and ta.max() <= cfg.tm_config().n_states - 1
+    (ta, _, _), _ = conv.train(conv.hyper(cfg), ta, w, 1,
+                               jnp.asarray(x[None, :, :6, :6]),
+                               jnp.asarray(y[None] % 2))
+    ta = np.asarray(ta)
+    assert ta.min() >= 0 and ta.max() <= (1 << cfg["ta_bits"]) - 1
 
 
 def test_regression_tm_learns_boolean_function():

@@ -402,8 +402,8 @@ def test_engine_invariant_across_axes_ref(kind, prng_backend, monkeypatch):
                                           err_msg=f"{kind}/{name}")
     fam = "lfsr" if prng_backend == "lfsr" else "counter"
     paths = base_tm.engine.cache_report()["path_per_stage"]
-    if kind != "conv":        # conv's TA stage is the jnp conv-feedback path
-        assert paths["train_prng"] == f"{fam}-inkernel"
+    key = "train_conv_ta_prng" if kind == "conv" else "train_prng"
+    assert paths[key] == f"{fam}-inkernel"
 
 
 @pytest.mark.parametrize("kind", ["cotm", "conv"])

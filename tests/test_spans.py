@@ -114,6 +114,10 @@ def test_fit_emits_bind_once_and_three_spans_per_epoch(tmp_path):
                                                      batch=16))
     assert len(hist) == 2
     assert len(_named(events, spans.FIT_BIND)) == 1
+    # a flat dataset is staged in one encode, inside the bind
+    (bind,) = _named(events, spans.FIT_BIND)
+    (enc,) = _named(events, spans.FIT_ENCODE)
+    assert enc[0] == bind[0] and bind[2] <= enc[2] and enc[3] <= bind[3]
     for name in (spans.FIT_PLAN, spans.FIT_EPOCH, spans.FIT_FETCH):
         evs = sorted(_named(events, name), key=lambda ev: ev[2])
         assert [ev[4].get("epoch") for ev in evs] == [0, 1], name
@@ -124,6 +128,30 @@ def test_fit_emits_bind_once_and_three_spans_per_epoch(tmp_path):
                               for n in (spans.FIT_PLAN, spans.FIT_EPOCH,
                                         spans.FIT_FETCH))
         assert plan[3] <= epoch[2] and epoch[3] <= fetch[2]
+
+
+def test_conv_staging_emits_one_encode_span_per_chunk(tmp_path,
+                                                      monkeypatch):
+    """Conv input is staged chunk by chunk (``dtm.STAGE_CHUNK_ROWS``
+    rows a launch; the last chunk ends at the last row): 40 images in
+    chunks of 16 are three ``tm.fit.encode`` spans, ``chunk`` 0..2, each
+    inside the one ``tm.fit.bind``."""
+    from repro.core import dtm
+    monkeypatch.setattr(dtm, "STAGE_CHUNK_ROWS", 16)
+    spec = api.TMSpec.conv(img_h=6, img_w=6, patch=3, classes=2, clauses=8,
+                           T=8, s=3.0)
+    rng = np.random.default_rng(4)
+    x = (rng.random((40, 6, 6)) < 0.4).astype(np.int8)
+    y = rng.integers(0, 2, 40)
+    tm = api.TM(spec, seed=1)
+    hist, events = _traced(tmp_path, lambda: tm.fit(x, y, epochs=1,
+                                                     batch=8))
+    assert len(hist) == 1
+    (bind,) = _named(events, spans.FIT_BIND)
+    encodes = sorted(_named(events, spans.FIT_ENCODE), key=lambda ev: ev[2])
+    assert [ev[4].get("chunk") for ev in encodes] == [0, 1, 2]
+    for line, _, s, e, _ in encodes:
+        assert line == bind[0] and bind[2] <= s and e <= bind[3]
 
 
 def test_queue_wait_counters_count_inference_taken_into_batches(roster):

@@ -6,7 +6,8 @@ slices of loaded values, blocks off the (8, 128) tiling, VMEM over the
 scoped limit.  These tests hand the real TPU compiler a described — not
 attached — ``v5e:2x2`` topology and compile each kernel, and the engine's
 jitted ``infer``/``train`` stages, at the paper's MNIST-CoTM and KWS-6
-widths.  Nothing runs; a compile that passes is not a chip run.
+widths, and the Conv CoTM's TA-update kernel and epoch scan at its MNIST
+geometry.  Nothing runs; a compile that passes is not a chip run.
 
 The topology is described inside a fixture (never at import): only one
 process may load the TPU library, and the test workers each import every
@@ -183,3 +184,51 @@ def test_raw_bank_predict_compiles_for_v5e(mnist_engine):
     _compile(engine._predict_bank_raw, progs,
              place(jax.ShapeDtypeStruct((K, engine.L // 2, B), jnp.int8)),
              place(jax.ShapeDtypeStruct((K,), jnp.int32)))
+
+
+@pytest.fixture(scope="module")
+def conv_engine(chip):
+    """The Conv CoTM at its MNIST geometry (28x28 images, 10x10 window:
+    361 patches, 272 literals padded to L=384; 2000 clauses, R=2048) on
+    compiled kernels, with a lowered program and PRNG as shapes."""
+    spec = api.TMSpec.conv(img_h=28, img_w=28, patch=10, classes=10,
+                           clauses=2000, T=500, s=10.0, prng_backend="lfsr")
+    engine = api.compile(api.tile_for(spec), backend="kernel")
+    assert (engine.L, engine.R, engine.P, engine.W) == (384, 2048, 361, 12)
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    prog = place(jax.eval_shape(lambda k: engine.lower(spec, k),
+                                jax.random.PRNGKey(0)))
+    prng = place(jax.eval_shape(lambda: PRNG.create(spec.tm_config(), 1)))
+    return engine, prog, prng, place
+
+
+def test_conv_ta_update_kernel_compiles_for_v5e(chip):
+    B, R, L, W = 32, 2048, 384, 12
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fb = s((2 * B, R), jnp.int32)
+    text = _compile(ops.ta_update_conv_op, s((R, L), jnp.uint8),
+                    s((B, R, W), jnp.uint32), fb, fb, fb, s((L,), jnp.int32),
+                    s((), jnp.uint32), s((), jnp.uint32), prng="lfsr")
+    assert "%ta_update_conv" in text
+
+
+def test_conv_epoch_scan_compiles_for_v5e(conv_engine):
+    engine, prog, prng, place = conv_engine
+    n, B = 64, 32
+    text = _compile(
+        engine._fit_epoch_conv, prog, prng,
+        place(jax.ShapeDtypeStruct((n, engine.P, engine.W), jnp.uint32)),
+        place(jax.ShapeDtypeStruct((n,), jnp.int32)),
+        place(jax.ShapeDtypeStruct((n // B, B), jnp.int32)))
+    assert "%ta_update_conv" in text
+    paths = engine.cache_report()["path_per_stage"]
+    assert paths["train_conv"] == ops.PATH_PACKED_MXU
+    assert paths["train_conv_ta"] == "pallas"
+    assert paths["train_conv_ta_prng"] == "lfsr-inkernel"

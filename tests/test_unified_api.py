@@ -114,13 +114,18 @@ def test_program_swap_keeps_cache_at_one(backend):
     conv_batch = BATCH * max(s.n_patches for s in SPECS.values())
     # the train stage also records the SKIP dimension of its TA-update
     # back half (compact by default; dense under REPRO_SKIP=0) and the
-    # PRNG stream family/placement of the Alg-5 update
+    # PRNG stream family/placement of the Alg-5 update; the conv train
+    # stage records its TA-update backend (the ta_update_conv kernel or
+    # its oracle) and the same stream family, generated in place
     assert paths == {"infer": expect(BATCH),
                      "train": expect(BATCH, training=True),
                      "train_ta": select_ta_path(shape=shape),
                      "train_prng": "counter-inkernel",
                      "infer_conv": expect(conv_batch),
-                     "train_conv": expect(conv_batch)}, paths
+                     "train_conv": expect(conv_batch),
+                     "train_conv_ta": ("ref" if backend == "ref"
+                                       else "pallas"),
+                     "train_conv_ta_prng": "counter-inkernel"}, paths
     # programs are pure data: swapping through the whole roster and back
     # reproduces the first variant's outputs bit-for-bit
     first = out["cotm"]
