@@ -36,6 +36,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 from repro.kernels.autotune import EVAL_TILES, TA_TILES, TRAIN_TILES
 from repro.kernels.class_sum import N_LIMBS
 from repro.kernels.ops import _skip_caps
+from repro.kernels.ta_update import _events_shape
 from repro.kernels.tpu_params import SCOPED_VMEM_BYTES, vmem_limit
 
 __all__ = ["KernelPlan", "Violation", "build_plans", "check_plan",
@@ -179,6 +180,11 @@ def plan_fused_step(B, L, R, H, bt=8, yt=128, xt=256) -> KernelPlan:
         scratch_bytes=(bt * yt + bt * H) * 4)
 
 
+def _ta_events_bytes(B, yt, xt) -> int:
+    """The TA-update kernels' int32 event-tile scratch."""
+    return math.prod(_events_shape(B, yt, xt)) * 4
+
+
 def plan_ta_update(B, L, C, yt=128, xt=256) -> KernelPlan:
     C, L = _pad_to(C, yt), _pad_to(L, xt)
     grid = (C // yt, L // xt)
@@ -191,7 +197,8 @@ def plan_ta_update(B, L, C, yt=128, xt=256) -> KernelPlan:
          BlockUse("params", (1, 5), (1, 5), lambda c, l: (0, 0), 4,
                   smem=True),
          BlockUse("ta_out", (C, L), (yt, xt), lambda c, l: (c, l), 4,
-                  is_output=True)))
+                  is_output=True)),
+        scratch_bytes=_ta_events_bytes(B, yt, xt))
 
 
 def plan_ta_update_sparse(B, L, C, k, yt=128, xt=256) -> KernelPlan:
@@ -209,7 +216,8 @@ def plan_ta_update_sparse(B, L, C, k, yt=128, xt=256) -> KernelPlan:
                   gather_axes=(1,)),
          BlockUse("l_mask", (1, L), (1, xt), lambda c, l: (0, l), 4),
          BlockUse("ta_out", (k * yt, L), (yt, xt), lambda c, l: (c, l), 4,
-                  is_output=True)))
+                  is_output=True)),
+        scratch_bytes=_ta_events_bytes(B, yt, xt))
 
 
 def plan_ta_update_streamed(B, L, C, yt=128, xt=256) -> KernelPlan:
@@ -219,7 +227,7 @@ def plan_ta_update_streamed(B, L, C, yt=128, xt=256) -> KernelPlan:
                      lambda c, l: (0, c, l), 4)
     return dataclasses.replace(
         base, kernel="ta_update_streamed",
-        uses=base.uses[:-1] + (rands, base.uses[-1]))
+        uses=base.uses[:-1] + (rands, base.uses[-1]), scratch_bytes=0)
 
 
 def plan_ta_update_conv(B, L, C, yt=128, xt=256) -> KernelPlan:
@@ -239,7 +247,8 @@ def plan_ta_update_conv(B, L, C, yt=128, xt=256) -> KernelPlan:
          BlockUse("params", (1, 5), (1, 5), lambda c, l: (0, 0), 4,
                   smem=True),
          BlockUse("ta_out", (C, L), (yt, xt), lambda c, l: (c, l), 4,
-                  is_output=True)))
+                  is_output=True)),
+        scratch_bytes=_ta_events_bytes(2 * B, yt, xt))
 
 
 # --------------------------------------------------------------------------- #

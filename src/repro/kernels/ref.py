@@ -248,9 +248,8 @@ def _lfsr_seed(master, key, lfsr_bits: int):
 def _lfsr_advance(lanes, lfsr_bits: int):
     """One Galois LFSR shift per lane (== core.prng.lfsr_step)."""
     taps = jnp.uint32(LFSR_TAPS[lfsr_bits])
-    lsb = lanes & jnp.uint32(1)
-    shifted = lanes >> 1
-    return jnp.where(lsb == 1, shifted ^ taps, shifted).astype(jnp.uint32)
+    return ((lanes >> 1) ^ ((lanes & jnp.uint32(1)) * taps)).astype(
+        jnp.uint32)
 
 
 def _lfsr_emit(lanes, lfsr_bits: int, rand_bits: int):
@@ -278,42 +277,56 @@ def stream_keys(C: int, L: int, xt: int, row_idx=None):
     return gy * jnp.uint32(stride) + gx
 
 
-def stream_start(seed, key, prng: str, lfsr_bits: int):
+def stream_start(seed, key, prng: str, lfsr_bits: int,
+                 seed_refresh: bool = True):
     """Initial per-element stream state (a tuple — ``prng`` is static).
 
     ``counter`` — splitmix32(seed ^ key) xorshift chains (the TPU-native
     counter mode).  ``lfsr`` — the paper's master–slave cluster with lane
     identity == key: lanes seeded splitmix32(seed ^ key) (masked, nonzero),
-    plus the scalar (master, cycles) refresh state.  Pure elementwise jnp,
-    shared verbatim by the Pallas TA-update kernels — generate where you
-    consume, no random tensor in HBM."""
+    plus the scalar (master, cycles) refresh state when ``seed_refresh``
+    is set; without it the state is the lanes alone.  Pure elementwise
+    jnp, shared verbatim by the Pallas TA-update kernels — generate where
+    you consume, no random tensor in HBM."""
     seed = jnp.asarray(seed, jnp.uint32)
     if prng == "counter":
         return (_splitmix32(seed ^ key),)
     if prng != "lfsr":
         raise ValueError(f"unknown TA prng mode {prng!r}")
-    return (_lfsr_seed(seed, key, lfsr_bits), seed, jnp.uint32(0))
+    lanes = _lfsr_seed(seed, key, lfsr_bits)
+    return (lanes, seed, jnp.uint32(0)) if seed_refresh else (lanes,)
 
 
-def stream_advance(st, key, prng: str, lfsr_bits: int, seed_refresh: bool,
-                   rand_bits: int):
-    """Advance one cycle, emit rand_bits-wide numbers (mirrors
-    core.prng.cluster_next for the lfsr mode: shift every lane, master
-    xorshift + per-key reseed when the 2^L−1 period elapses)."""
+def stream_step(st, key, prng: str, lfsr_bits: int):
+    """Advance the state one cycle (mirrors core.prng.cluster_next for the
+    lfsr mode: shift every lane; a state that carries (master, cycles)
+    xorshifts its master and re-seeds every lane when the 2^L−1 period
+    elapses)."""
     if prng == "counter":
-        state, = st
-        state = _xorshift32(state)
-        return (state,), state >> (32 - rand_bits)
-    lanes, master, cycles = st
-    lanes = _lfsr_advance(lanes, lfsr_bits)
+        return (_xorshift32(st[0]),)
+    lanes = _lfsr_advance(st[0], lfsr_bits)
+    if len(st) == 1:
+        return (lanes,)
+    _, master, cycles = st
     cycles = cycles + jnp.uint32(1)
-    if seed_refresh:
-        period = jnp.uint32((1 << lfsr_bits) - 1)
-        do = cycles >= period
-        master = jnp.where(do, _xorshift32(master), master)
-        lanes = jnp.where(do, _lfsr_seed(master, key, lfsr_bits), lanes)
-        cycles = jnp.where(do, jnp.uint32(0), cycles)
-    return (lanes, master, cycles), _lfsr_emit(lanes, lfsr_bits, rand_bits)
+    do = cycles >= jnp.uint32((1 << lfsr_bits) - 1)
+    master = jnp.where(do, _xorshift32(master), master)
+    lanes = jnp.where(do, _lfsr_seed(master, key, lfsr_bits), lanes)
+    cycles = jnp.where(do, jnp.uint32(0), cycles)
+    return (lanes, master, cycles)
+
+
+def stream_word(st, prng: str, lfsr_bits: int, rand_bits: int):
+    """The rand_bits-wide comparator word of the current state."""
+    if prng == "counter":
+        return st[0] >> (32 - rand_bits)
+    return _lfsr_emit(st[0], lfsr_bits, rand_bits)
+
+
+def stream_advance(st, key, prng: str, lfsr_bits: int, rand_bits: int):
+    """Advance one cycle and emit its rand_bits-wide words."""
+    st = stream_step(st, key, prng, lfsr_bits)
+    return st, stream_word(st, prng, lfsr_bits, rand_bits)
 
 
 def ta_rand_stream(seed, batch: int, C: int, L: int, rand_bits: int = 16,
@@ -325,12 +338,10 @@ def ta_rand_stream(seed, batch: int, C: int, L: int, rand_bits: int = 16,
     batch·C·L·4 bytes of HBM random-bits traffic per step
     (benchmarks/fig15_lfsr.py measures the two against each other)."""
     key = stream_keys(C, L, xt, row_idx)
-    st0 = stream_start(seed, key, prng, lfsr_bits)
+    st0 = stream_start(seed, key, prng, lfsr_bits, seed_refresh)
 
     def body(st, _):
-        st, rand = stream_advance(st, key, prng, lfsr_bits, seed_refresh,
-                                  rand_bits)
-        return st, rand
+        return stream_advance(st, key, prng, lfsr_bits, rand_bits)
 
     _, rows = jax.lax.scan(body, st0, None, length=batch)
     return rows
@@ -375,7 +386,7 @@ def ta_update_ref(ta, literals, clause_out, type1, type2, l_mask, seed,
     ``prng`` selects the stream family: ``counter`` (splitmix/xorshift
     chains) or ``lfsr`` (the paper-faithful Galois master–slave cluster,
     ``lfsr_bits`` wide with optional ``seed_refresh`` — see
-    :func:`stream_advance`).  ``rands`` (optional, [B, C, L] uint32 from
+    :func:`stream_step`).  ``rands`` (optional, [B, C, L] uint32 from
     :func:`ta_rand_stream`) consumes pre-materialised randoms instead of
     generating in place — the streamed baseline path."""
     C, L = ta.shape
@@ -386,13 +397,12 @@ def ta_update_ref(ta, literals, clause_out, type1, type2, l_mask, seed,
 
     if rands is None:
         key = stream_keys(C, L, xt, row_idx)
-        st0 = stream_start(seed, key, prng, lfsr_bits)
+        st0 = stream_start(seed, key, prng, lfsr_bits, seed_refresh)
 
         def body(carry, xs):
             st, delta = carry
             lit_b, cl_b, t1_b, t2_b = xs
-            st, rand = stream_advance(st, key, prng, lfsr_bits,
-                                      seed_refresh, rand_bits)
+            st, rand = stream_advance(st, key, prng, lfsr_bits, rand_bits)
             delta = delta + _ta_delta_step(rand, lit_b, cl_b, t1_b, t2_b,
                                            include, p_ta, boost)
             return (st, delta), None
@@ -431,15 +441,14 @@ def ta_update_conv_ref(ta, lit_words, clause_out, type1, type2, l_mask,
     n_states = jnp.asarray(n_states, jnp.int32)
     include = ta.astype(jnp.int32) >= (n_states >> 1)
     key = stream_keys(C, L, xt, row_idx)
-    st0 = stream_start(seed, key, prng, lfsr_bits)
+    st0 = stream_start(seed, key, prng, lfsr_bits, seed_refresh)
     reps = clause_out.shape[0] // lit_words.shape[0]
     words = jnp.concatenate([lit_words] * reps)                  # [E, C, W]
 
     def body(carry, xs):
         st, delta = carry
         w_b, cl_b, t1_b, t2_b = xs
-        st, rand = stream_advance(st, key, prng, lfsr_bits, seed_refresh,
-                                  rand_bits)
+        st, rand = stream_advance(st, key, prng, lfsr_bits, rand_bits)
         lit_b = unpack_bitplanes_i8(w_b)[:, :L]                  # [C, L]
         delta = delta + _ta_delta_step(rand, lit_b, cl_b, t1_b, t2_b,
                                        include, p_ta, boost)

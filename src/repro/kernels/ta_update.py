@@ -13,7 +13,7 @@ mapping:
   on the global element index, so no [B, C, L] random tensor ever touches
   HBM (the PRNG-bandwidth insight of paper §IV-C, re-expressed: generate
   where you consume).  Two stream families share the tile body (static
-  ``prng`` arg, mirrored bit-exactly by ref.stream_start/stream_advance):
+  ``prng`` arg, mirrored bit-exactly by ref.stream_start/stream_step):
 
   - ``counter`` — splitmix32→xorshift32 chains (TPU-native default);
   - ``lfsr``    — the paper-faithful Galois LFSR master–slave cluster
@@ -21,6 +21,26 @@ mapping:
     advanced one Galois shift per batch element, re-seeded from an
     xorshift-advanced master every 2^lfsr_bits−1 cycles when
     ``seed_refresh`` is set — the FPGA's per-TA LFSR bank, in place.
+
+The tile body (:func:`_tile_update`) spends four VPU ops per (element, TA
+cell) beyond the stream's own step:
+
+* an element's feedback-code row is decoded on the row (one vreg an op)
+  into four event bits per clause, packed at the bit offsets of four
+  counters (:func:`_event_tile`), and turned into clause rows by a
+  transpose.  These event tiles depend on the clause rows alone: they are
+  built once per clause tile into VMEM scratch and reused by every literal
+  tile (grid axis 1 runs in order);
+* per cell the draw (``lanes < p_ta << (lfsr_bits − rand_bits)`` on the
+  raw LFSR word, or the emitted word for the counter chains and for an
+  LFSR narrower than the comparison) and the literal select which
+  counters the cell's event bits add to (:func:`_tile_delta`); a run of
+  at most :data:`_RUN` elements is decoded into the delta at its end
+  (:func:`_decode`);
+* the batch loop runs per strip of :data:`_STRIP` rows, so a strip's
+  LFSR lanes and counters stay in vector registers across it;
+* the master–slave re-seed is compiled in only when the static batch
+  reaches the 2^L−1 period.
 
 The Conv-TM update (``ta_update_conv``) runs the same tile body and
 streams with one change: every (batch element, clause row) reads the
@@ -36,13 +56,14 @@ Semantics (validated bit-exactly against ref.py):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ref import stream_advance, stream_start
+from .ref import stream_start, stream_step, stream_word
 from .tpu_params import block_bytes, compiler_params
 
 # per-(datapoint, clause) feedback flags, packed into one int32 code so the
@@ -59,58 +80,108 @@ def feedback_code(clause_out, type1, type2) -> jax.Array:
             + (type2 > 0).astype(jnp.int32) * _T2)
 
 
-def _tile_delta(rand, lit_row, code_col, include, p_ta, boost, delta):
-    """One batch element's Alg-5 delta accumulation on a (yt, xt) tile.
-
-    ``lit_row`` [1, xt] is the element's literal row; ``code_col``
-    [yt, 1] its per-clause feedback code (:func:`feedback_code`)."""
-    low = rand < p_ta                                 # P = 1/s
-    clb = (code_col & _CL) != 0                       # [yt, 1]
-    litb = lit_row > 0                                # [1, xt]
-    t1b = (code_col & _T1) != 0
-    t2b = (code_col & _T2) != 0
-    cl_and_lit = jnp.logical_and(clb, litb)
-    # boost: always +1 on cl∧lit; else w.p. (s-1)/s (boolean algebra, not
-    # a select — Mosaic has no select on i1 vectors)
-    inc1 = jnp.logical_and(cl_and_lit,
-                           jnp.logical_or(boost, jnp.logical_not(low)))
-    dec1 = jnp.logical_and(jnp.logical_not(cl_and_lit), low)
-    d1 = inc1.astype(jnp.int32) - dec1.astype(jnp.int32)
-    inc2 = jnp.logical_and(jnp.logical_and(clb, jnp.logical_not(litb)),
-                           jnp.logical_not(include)).astype(jnp.int32)
-    return delta + jnp.where(t1b, d1, 0) + jnp.where(t2b, inc2, 0)
+# The batch loop counts four kinds of event per TA cell in one int32
+# word, one byte each: Type I +1 (byte 0), the 1/s −1 where the literal is
+# 1 (byte 1), Type II +1 (byte 2), the 1/s −1 where the literal is 0
+# (byte 3).  A run of at most _RUN elements cannot overflow a byte; it is
+# then decoded into the delta.  _RUN also bounds the event-tile scratch
+# (8 MiB at yt=128).
+_RUN = 128
+# TA rows one batch loop carries: a strip's LFSR lanes and counts (2 x 8
+# vregs at xt=256) stay in registers across the loop, where a whole
+# 128-row tile's (2 x 32) would fill the 64 vregs and spill.
+_STRIP = 32
+# The conv update unpacks each strip's literals anew (word transposes per
+# strip), so it takes fewer, taller strips.
+_CONV_STRIP = 64
 
 
-def _batch_rows(lit_ref, code_ref, b):
-    """Row ``b`` of the literal block ([1, xt]) and of the feedback-code
-    block, turned into a clause column ([yt, 1]) — ref-level dynamic
-    sublane reads, the form Mosaic lowers."""
-    return lit_ref[pl.ds(b, 1), :], code_ref[pl.ds(b, 1), :].T
+def _event_tile(code_row, keep, lanes: int):
+    """One element's feedback-code row [1, yt] -> its event bits as a
+    [yt, lanes] tile, each clause row's bits across every lane.  Decoded
+    on the row (one vreg an op) into
+
+      P = t1∧cl, Q = t1∧¬cl, R = t2∧cl;
+      +1 where the literal is 1: P;  +1 where it is 0: R (if excluded);
+      −1 w.p. 1/s where the literal is 1: Q ∨ (P∧¬boost)
+                                   (a boosted P keeps its +1);
+      −1 w.p. 1/s where the literal is 0: P ∨ Q,
+
+    packed one to a byte, then turned into clause rows by
+    transposing the row broadcast over ``lanes`` sublanes.  ``keep`` is
+    ¬boost as an int32 0/1 scalar."""
+    cl = code_row & _CL
+    t1 = (code_row >> 1) & 1
+    p = t1 & cl
+    q = t1 ^ p
+    r = (code_row >> 2) & cl
+    ev = p | ((q | (p & keep)) << 8) | (r << 16) | ((p | q) << 24)
+    return jnp.broadcast_to(ev, (lanes, ev.shape[1])).T
 
 
-def _conv_rows(words_ref, code_ref, b, *, n_lit: int, xt: int):
-    """Element ``b``'s literal tile [yt, xt], one literal row per clause
-    row (the patch that row is updated against), and its feedback-code
-    column [yt, 1].  ``words_ref`` [n_lit, xt/32, yt] is word-major, so
-    each word of the tile is one [1, yt] row turned into a clause column
-    (the same dynamic read as :func:`_batch_rows`) and spread over its 32
-    lanes; element ``b`` reads literal block ``b % n_lit`` (both feedback
-    rounds of a datapoint share its patch choice)."""
+def _tile_delta(low, litb, ev, acc):
+    """One batch element's Alg-5 events on a TA block: ``low`` the cells
+    whose random word is below p_ta (P = 1/s), ``litb`` the element's
+    literals (a [1, xt] row, or one row per clause for the conv update),
+    ``ev`` its :func:`_event_tile` rows.  Each cell adds the one or two
+    bytes its literal and its draw select: four VPU ops."""
+    sel = jnp.where(low, jnp.where(litb, 0x101, 0x1010000),
+                    jnp.where(litb, 0x1, 0x10000))
+    return acc + (ev & sel)
+
+
+def _decode(acc, include):
+    """Packed event counts -> the run's int32 delta: Type I +1s less both
+    1/s −1s, plus the Type II +1s of excluded cells."""
+    f = [jax.lax.shift_right_logical(acc, jnp.int32(8 * k)) & 0xFF
+         for k in range(4)]
+    return f[0] - f[1] - f[3] + jnp.where(include, 0, f[2])
+
+
+def _batch_rows(lit_ref, b, r: int, S: int):
+    """Row ``b`` of the literal block ([1, xt]), shared by every clause
+    row — a ref-level dynamic sublane read, the form Mosaic lowers."""
+    return lit_ref[pl.ds(b, 1), :]
+
+
+def _conv_rows(words_ref, b, r: int, S: int, *, n_lit: int, xt: int):
+    """Element ``b``'s literals for clause rows [r, r + S): [S, xt], one
+    literal row per clause row (the patch that row is updated against).
+    ``words_ref`` [n_lit, xt/32, yt] is word-major, so each word of the
+    tile is one [1, yt] row turned into a clause column and spread over
+    its 32 lanes; element ``b`` reads literal block ``b % n_lit`` (both
+    feedback rounds of a datapoint share its patch choice)."""
     e = jax.lax.rem(b, n_lit)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, xt), 1)
     word, bit = lane >> 5, lane & 31
     lit = None
     for w in range(xt // 32):
-        col = words_ref[e, pl.ds(w, 1), :].T                  # [yt, 1]
+        col = words_ref[e, pl.ds(w, 1), :][:, r:r + S].T      # [S, 1]
         lit = col if lit is None else jnp.where(word == w, col, lit)
-    return (lit >> bit) & 1, code_ref[pl.ds(b, 1), :].T
+    return (lit >> bit) & 1
+
+
+def _low_mask(p_ta, prng: str, lfsr_bits: int, rand_bits: int):
+    """``st -> word < p_ta`` for the stream family.  An LFSR at least
+    rand_bits wide (and under 31) compares its raw lanes against p_ta
+    shifted up to the lane width, as int32 — exact: the word is the
+    lanes' top rand_bits, and p_ta is first capped at 2^rand_bits, past
+    which every word is below it; the shift is built once per tile from
+    the SMEM scalar.  A narrower LFSR (zero-extended words) and the
+    counter chains emit the word."""
+    sh = lfsr_bits - rand_bits
+    if prng != "lfsr" or sh < 0 or lfsr_bits > 30:
+        return lambda st: stream_word(st, prng, lfsr_bits, rand_bits) < p_ta
+    thr = (jnp.minimum(p_ta, jnp.uint32(1 << rand_bits)) << sh).astype(
+        jnp.int32)
+    return lambda st: jax.lax.bitcast_convert_type(st[0], jnp.int32) < thr
 
 
 def _tile_update(ci, li, ta_ref, lit_ref, code_ref, lmask_ref, params_ref,
-                 out_ref, *, batch: int, n_l_tiles: int, yt: int, xt: int,
-                 rand_bits: int, prng: str = "counter",
+                 out_ref, ev_ref, *, batch: int, n_l_tiles: int, yt: int,
+                 xt: int, rand_bits: int, prng: str = "counter",
                  lfsr_bits: int = 24, seed_refresh: bool = True,
-                 rows=_batch_rows):
+                 rows=_batch_rows, strip: int = _STRIP):
     """Shared (yt, xt) TA-tile update body.
 
     ``ci``/``li`` are the tile's GLOBAL grid coordinates — the dense kernel
@@ -124,117 +195,160 @@ def _tile_update(ci, li, ta_ref, lit_ref, code_ref, lmask_ref, params_ref,
 
     ``prng``/``lfsr_bits``/``seed_refresh`` select the stream family
     (module docstring); all stream state lives in registers/VMEM — only
-    the uint32 master seed crosses from SMEM.  ``rows(lit_ref, code_ref,
-    b)`` reads batch element ``b``'s literals and feedback codes:
-    :func:`_batch_rows` (one literal row shared by every clause) or, for
-    the conv update, :func:`_conv_rows`."""
+    the uint32 master seed crosses from SMEM.  The master–slave re-seed
+    is compiled in only when the static batch reaches the 2^L−1 period
+    (it cannot fire otherwise: every launch starts the cycle count at 0).
+
+    ``ev_ref`` (VMEM scratch [min(batch, _RUN), yt, min(xt, 128)]) holds
+    every element's :func:`_event_tile`.  They depend on the clause rows
+    alone, so a batch of one run expands them on the first literal tile
+    of each clause tile (grid axis 1 runs in order) and every later
+    literal tile reuses them.  The batch loops then run per strip of
+    ``strip`` rows (:data:`_STRIP`; the conv update :data:`_CONV_STRIP`).
+    ``rows(lit_ref, b, r, S)`` reads batch element
+    ``b``'s literals for rows [r, r + S): :func:`_batch_rows` (one
+    literal row shared by every clause) or, for the conv update,
+    :func:`_conv_rows`."""
     # dynamic model scalars ride in SMEM — a DTMProgram swap or a fresh
     # per-step seed never retraces (cache-size == 1 semantics, §IV-D-a).
     seed = params_ref[0, 0]
     p_ta = params_ref[0, 1]
-    boost = params_ref[0, 2] > 0
+    keep = (params_ref[0, 2] == 0).astype(jnp.int32)     # ¬boost
     n_states = params_ref[0, 3].astype(jnp.int32)
     row0 = params_ref[0, 4]
-    ta = ta_ref[...]                                      # [yt, xt] int32
-    include = ta >= (n_states >> 1)
+    refresh = seed_refresh and batch >= (1 << lfsr_bits) - 1
+    low_of = _low_mask(p_ta, prng, lfsr_bits, rand_bits)
+    lanes = ev_ref.shape[2]
+    strips = [(r, min(strip, yt - r)) for r in range(0, yt, strip)]
+    runs = [(lo, min(lo + _RUN, batch)) for lo in range(0, batch, _RUN)]
 
     # per-element stream keyed on GLOBAL element index — the result is
     # tile-layout independent (ref.py reproduces it exactly).
-    gy = (ci.astype(jnp.uint32) * yt + row0
-          + jax.lax.broadcasted_iota(jnp.uint32, (yt, xt), 0))
-    gx = (li.astype(jnp.uint32) * xt
-          + jax.lax.broadcasted_iota(jnp.uint32, (yt, xt), 1))
-    key = gy * jnp.uint32(n_l_tiles * xt) + gx
-    st0 = stream_start(seed, key, prng, lfsr_bits)
+    keys, st, delta = [], [], []
+    for r, S in strips:
+        gy = (ci.astype(jnp.uint32) * yt + row0 + r
+              + jax.lax.broadcasted_iota(jnp.uint32, (S, xt), 0))
+        gx = (li.astype(jnp.uint32) * xt
+              + jax.lax.broadcasted_iota(jnp.uint32, (S, xt), 1))
+        keys.append(gy * jnp.uint32(n_l_tiles * xt) + gx)
+        st.append(stream_start(seed, keys[-1], prng, lfsr_bits, refresh))
+        delta.append(jnp.zeros((S, xt), jnp.int32))
 
-    def body(b, carry):
-        st, delta = carry
-        st, rand = stream_advance(st, key, prng, lfsr_bits, seed_refresh,
-                                  rand_bits)
-        lit_row, code_col = rows(lit_ref, code_ref, b)
-        delta = _tile_delta(rand, lit_row, code_col, include, p_ta, boost,
-                            delta)
-        return st, delta
+    for lo, hi in runs:
+        def expand(lo=lo, hi=hi):
+            def put(b, carry):
+                ev_ref[b - lo] = _event_tile(code_ref[pl.ds(b, 1), :], keep,
+                                             lanes)
+                return carry
+            jax.lax.fori_loop(lo, hi, put, 0)
 
-    delta = jnp.zeros((yt, xt), jnp.int32)
-    _, delta = jax.lax.fori_loop(0, batch, body, (st0, delta))
-    delta = delta * lmask_ref[...]                        # Fig 6a inverse mask
-    out_ref[...] = jnp.clip(ta + delta, 0, n_states - 1)
+        if len(runs) == 1:
+            pl.when(pl.program_id(1) == 0)(expand)
+        else:
+            expand()
+        for i, (r, S) in enumerate(strips):
+            def body(b, carry, lo=lo, r=r, S=S, key=keys[i]):
+                st, acc = carry
+                st = stream_step(st, key, prng, lfsr_bits)
+                ev = ev_ref[b - lo, r:r + S, :]
+                if lanes < xt:
+                    ev = jnp.concatenate([ev] * (xt // lanes), 1)
+                acc = _tile_delta(low_of(st), rows(lit_ref, b, r, S) > 0, ev,
+                                  acc)
+                return st, acc
+
+            st[i], acc = jax.lax.fori_loop(
+                lo, hi, body, (st[i], jnp.zeros((S, xt), jnp.int32)))
+            include = ta_ref[r:r + S, :] >= (n_states >> 1)
+            delta[i] = delta[i] + _decode(acc, include)
+
+    lmask = lmask_ref[...]                                # Fig 6a inverse mask
+    for (r, S), d in zip(strips, delta):
+        out_ref[r:r + S, :] = jnp.clip(ta_ref[r:r + S, :] + d * lmask, 0,
+                                       n_states - 1)
 
 
-def _kernel(ta_ref, lit_ref, code_ref, lmask_ref, params_ref, out_ref, *,
-            batch: int, n_l_tiles: int, yt: int, xt: int, rand_bits: int,
-            prng: str, lfsr_bits: int, seed_refresh: bool):
+def _kernel(ta_ref, lit_ref, code_ref, lmask_ref, params_ref, out_ref,
+            ev_ref, *, batch: int, n_l_tiles: int, yt: int, xt: int,
+            rand_bits: int, prng: str, lfsr_bits: int, seed_refresh: bool):
     _tile_update(pl.program_id(0), pl.program_id(1), ta_ref, lit_ref,
-                 code_ref, lmask_ref, params_ref, out_ref,
+                 code_ref, lmask_ref, params_ref, out_ref, ev_ref,
                  batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
                  rand_bits=rand_bits, prng=prng, lfsr_bits=lfsr_bits,
                  seed_refresh=seed_refresh)
 
 
 def _sparse_kernel(idx_ref, params_ref, ta_ref, lit_ref, code_ref,
-                   lmask_ref, out_ref, *, batch: int, n_l_tiles: int,
-                   yt: int, xt: int, rand_bits: int, prng: str,
-                   lfsr_bits: int, seed_refresh: bool):
+                   lmask_ref, out_ref, ev_ref, *, batch: int,
+                   n_l_tiles: int, yt: int, xt: int, rand_bits: int,
+                   prng: str, lfsr_bits: int, seed_refresh: bool):
     """Compacted grid step: slot ``program_id(0)`` owns the ACTIVE clause
     tile whose original row-tile index is ``idx_ref[program_id(0)]`` (the
     scalar-prefetch index vector also drives the BlockSpec gathers).  The
     PRNG stream is keyed on the original tile coordinates, so the update
     is bit-identical to the dense kernel's for that tile."""
     _tile_update(idx_ref[pl.program_id(0)], pl.program_id(1), ta_ref,
-                 lit_ref, code_ref, lmask_ref, params_ref, out_ref,
+                 lit_ref, code_ref, lmask_ref, params_ref, out_ref, ev_ref,
                  batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
                  rand_bits=rand_bits, prng=prng, lfsr_bits=lfsr_bits,
                  seed_refresh=seed_refresh)
 
 
 def _conv_kernel(ta_ref, words_ref, code_ref, lmask_ref, params_ref,
-                 out_ref, *, batch: int, n_lit: int, n_l_tiles: int, yt: int,
-                 xt: int, rand_bits: int, prng: str, lfsr_bits: int,
-                 seed_refresh: bool):
+                 out_ref, ev_ref, *, batch: int, n_lit: int, n_l_tiles: int,
+                 yt: int, xt: int, rand_bits: int, prng: str,
+                 lfsr_bits: int, seed_refresh: bool):
     """Dense conv grid step: the flat tile body, with each clause row's
     literals read from its own chosen patch (:func:`_conv_rows`)."""
     _tile_update(pl.program_id(0), pl.program_id(1), ta_ref, words_ref,
-                 code_ref, lmask_ref, params_ref, out_ref,
+                 code_ref, lmask_ref, params_ref, out_ref, ev_ref,
                  batch=batch, n_l_tiles=n_l_tiles, yt=yt, xt=xt,
                  rand_bits=rand_bits, prng=prng, lfsr_bits=lfsr_bits,
                  seed_refresh=seed_refresh,
-                 rows=functools.partial(_conv_rows, n_lit=n_lit, xt=xt))
+                 rows=functools.partial(_conv_rows, n_lit=n_lit, xt=xt),
+                 strip=_CONV_STRIP)
 
 
 def _streamed_kernel(ta_ref, lit_ref, code_ref, lmask_ref, rand_ref,
                      params_ref, out_ref, *, batch: int, yt: int, xt: int):
-    """Streamed-rand baseline: the same tile body, but the randoms arrive
-    as a pre-materialised [B, yt, xt] uint32 block from HBM
+    """Streamed-rand baseline: the same event counting, but the randoms
+    arrive as a pre-materialised [B, yt, xt] uint32 block from HBM
     (ref.ta_rand_stream) — exactly the traffic the in-kernel generator
     eliminates.  Kept as a dispatchable path so the win is measurable on
     one machine (benchmarks/fig15_lfsr.py) and streamed-vs-in-kernel
     bit-identity is a test, not a claim."""
     p_ta = params_ref[0, 1]
-    boost = params_ref[0, 2] > 0
+    keep = (params_ref[0, 2] == 0).astype(jnp.int32)     # ¬boost
     n_states = params_ref[0, 3].astype(jnp.int32)
     ta = ta_ref[...]                                      # [yt, xt] int32
-    include = ta >= (n_states >> 1)
+    delta = jnp.zeros((yt, xt), jnp.int32)
+    for lo in range(0, batch, _RUN):
+        def body(b, acc):
+            ev = _event_tile(code_ref[pl.ds(b, 1), :], keep, xt)
+            return _tile_delta(rand_ref[b] < p_ta, lit_ref[pl.ds(b, 1), :] > 0,
+                               ev, acc)
 
-    def body(b, delta):
-        lit_row, code_col = _batch_rows(lit_ref, code_ref, b)
-        return _tile_delta(rand_ref[b], lit_row, code_col, include, p_ta,
-                           boost, delta)
-
-    delta = jax.lax.fori_loop(0, batch, body,
-                              jnp.zeros((yt, xt), jnp.int32))
+        acc = jax.lax.fori_loop(lo, min(lo + _RUN, batch), body,
+                                jnp.zeros((yt, xt), jnp.int32))
+        delta = delta + _decode(acc, ta >= (n_states >> 1))
     delta = delta * lmask_ref[...]
     out_ref[...] = jnp.clip(ta + delta, 0, n_states - 1)
 
 
+def _events_shape(B: int, yt: int, xt: int) -> tuple:
+    """Shape of :func:`_tile_update`'s int32 event-tile scratch."""
+    return (min(B, _RUN), yt, min(xt, 128))
+
+
 def _vmem_need(B: int, yt: int, xt: int, rands: bool = False) -> int:
     """Per-step VMEM of the TA-update kernels: TA tile in + out, literal
-    rows, feedback codes, l_mask (+ the streamed rand block)."""
+    rows, feedback codes, l_mask, plus the event-tile scratch (+ the
+    streamed rand block, which needs no scratch)."""
     blocks = [((yt, xt), 4), ((B, xt), 4), ((B, yt), 4), ((1, xt), 4),
               ((yt, xt), 4)]
     if rands:
-        blocks.append(((B, yt, xt), 4))
-    return block_bytes(*blocks)
+        return block_bytes(*blocks, ((B, yt, xt), 4))
+    return block_bytes(*blocks) + math.prod(_events_shape(B, yt, xt)) * 4
 
 
 def _params(seed, p_ta, boost, n_states, row0):
@@ -301,6 +415,7 @@ def ta_update_sparse(ta: jax.Array, literals: jax.Array,
             pl.BlockSpec((1, xt), lambda c, l, idx, prm: (0, l)),
         ],
         out_specs=pl.BlockSpec((yt, xt), lambda c, l, idx, prm: (c, l)),
+        scratch_shapes=[pltpu.VMEM(_events_shape(B, yt, xt), jnp.int32)],
     )
     return pl.pallas_call(
         functools.partial(_sparse_kernel, batch=B, n_l_tiles=grid[1], yt=yt,
@@ -308,7 +423,7 @@ def ta_update_sparse(ta: jax.Array, literals: jax.Array,
                           lfsr_bits=lfsr_bits, seed_refresh=seed_refresh),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((k * yt, L), jnp.int32),
-        compiler_params=compiler_params(("parallel", "parallel"),
+        compiler_params=compiler_params(("parallel", "arbitrary"),
                                         _vmem_need(B, yt, xt)),
         interpret=interpret,
     )(tile_idx.astype(jnp.int32), params,
@@ -364,7 +479,8 @@ def ta_update(ta: jax.Array, literals: jax.Array, clause_out: jax.Array,
         ],
         out_specs=pl.BlockSpec((yt, xt), lambda c, l: (c, l)),
         out_shape=jax.ShapeDtypeStruct((C, L), jnp.int32),
-        compiler_params=compiler_params(("parallel", "parallel"),
+        scratch_shapes=[pltpu.VMEM(_events_shape(B, yt, xt), jnp.int32)],
+        compiler_params=compiler_params(("parallel", "arbitrary"),
                                         _vmem_need(B, yt, xt)),
         interpret=interpret,
     )(ta.astype(jnp.int32), literals.astype(jnp.int32),
@@ -455,8 +571,9 @@ def ta_update_conv(ta: jax.Array, lit_words: jax.Array,
         0, 2, 1)                                            # [Bl, L/32, C]
     grid = (C // yt, L // xt)
     params = _params(seed, p_ta, boost, n_states, row0)
-    need = block_bytes(((yt, xt), 4), ((Bl, xt // 32, yt), 4), ((E, yt), 4),
-                       ((1, xt), 4), ((yt, xt), 4))
+    need = (block_bytes(((yt, xt), 4), ((Bl, xt // 32, yt), 4),
+                        ((E, yt), 4), ((1, xt), 4), ((yt, xt), 4))
+            + math.prod(_events_shape(E, yt, xt)) * 4)
     return pl.pallas_call(
         functools.partial(_conv_kernel, batch=E, n_lit=Bl,
                           n_l_tiles=grid[1], yt=yt, xt=xt,
@@ -474,7 +591,8 @@ def ta_update_conv(ta: jax.Array, lit_words: jax.Array,
         ],
         out_specs=pl.BlockSpec((yt, xt), lambda c, l: (c, l)),
         out_shape=jax.ShapeDtypeStruct((C, L), jnp.int32),
-        compiler_params=compiler_params(("parallel", "parallel"), need),
+        scratch_shapes=[pltpu.VMEM(_events_shape(E, yt, xt), jnp.int32)],
+        compiler_params=compiler_params(("parallel", "arbitrary"), need),
         interpret=interpret,
     )(ta.astype(jnp.int32), words, feedback_code(clause_out, type1, type2),
       l_mask.reshape(1, L).astype(jnp.int32), params)

@@ -30,7 +30,7 @@ from repro import api
 from repro.core import dtm
 from repro.core.booleanize import pack_literals
 from repro.core.prng import PRNG
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 BENCH = pathlib.Path(__file__).resolve().parents[1] / "bench"
 if str(BENCH) not in sys.path:
@@ -217,6 +217,38 @@ def test_conv_kernel_equals_its_oracle(C, L, Bl, row0, prng):
                                   backend="ref", **kw)
     for f, c in zip(flat, conv_):
         assert np.array_equal(np.asarray(f), np.asarray(c))
+
+
+@pytest.mark.parametrize("prng,bits,E,Bl,boost", [
+    ("lfsr", 4, 14, 7, True), ("lfsr", 4, 15, 5, True),
+    ("lfsr", 12, 6, 3, True), ("lfsr", 24, 6, 3, False),
+    ("lfsr", 24, 130, 65, True)])
+def test_conv_kernel_body_branches_equal_the_oracle(prng, bits, E, Bl, boost):
+    """The tile body's static branches in the conv kernel: the LFSR
+    re-seed either side of B = 15 (2^4 - 1), a 12-bit LFSR under the
+    16-bit comparison, boost off, and a batch past one 128-element
+    counting run; ``E`` elements cycle over ``Bl`` literal blocks."""
+    C, L = 136, 300
+    rng = np.random.default_rng(E + bits)
+    W = -(-L // 32)
+    ta = jnp.asarray(rng.integers(0, 256, (C, L)), jnp.uint8)
+    bitsarr = rng.integers(0, 2, (Bl, C, 32 * W)).astype(np.int8)
+    bitsarr[..., L:] = 0
+    words = pack_literals(jnp.asarray(bitsarr))
+    cl, t1, t2 = (jnp.asarray(rng.integers(0, 2, (E, C)), jnp.int32)
+                  for _ in range(3))
+    l_mask = jnp.asarray(np.arange(L) % 5 != 2, jnp.int32)
+    kw = dict(seed=jnp.uint32(4242), p_ta=jnp.uint32(6554), rand_bits=16,
+              boost=boost, n_states=256, prng=prng, lfsr_bits=bits,
+              seed_refresh=True)
+    got = ops.ta_update_conv_op(ta, words, cl, t1, t2, l_mask,
+                                backend="pallas", **kw)
+    rows = jnp.arange(C, dtype=jnp.int32)
+    want = ref.ta_update_conv_ref(ta, words, cl, t1, t2, l_mask,
+                                  row_idx=rows, **kw)
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want))
+    assert np.array_equal(np.asarray(got[1]),
+                          np.asarray(ref.pack_include(want, 256)))
 
 
 @pytest.mark.parametrize("n,chunk", [(37, 8), (37, 37), (37, 64), (40, 8)])

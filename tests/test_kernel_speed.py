@@ -52,9 +52,20 @@ SPECS = {
 }
 
 # (prng, lfsr_bits, seed_refresh) — lfsr_bits=4 with B past the 15-cycle
-# period exercises the in-kernel master-slave re-seed branch
+# period exercises the in-kernel master-slave re-seed branch; lfsr_bits=12
+# is narrower than the 16-bit comparison, so its words are zero-extended
+# (no raw-lane threshold)
 STREAMS = [("counter", 24, True), ("lfsr", 24, True), ("lfsr", 4, True),
-           ("lfsr", 8, False)]
+           ("lfsr", 8, False), ("lfsr", 12, True)]
+
+# (prng, lfsr_bits, B, boost): the static choices of the TA-update tile
+# body — the re-seed compiled in only from B = 2^4 - 1 = 15 (B = 14 is one
+# element short of it), the zero-extended word of a 12-bit LFSR, boost
+# off (a low draw cancels a Type I +1), and B = 130 past one 128-element
+# counting run (events expanded per run, not once per clause tile)
+BODY_CASES = [("lfsr", 4, 14, True), ("lfsr", 4, 15, True),
+              ("lfsr", 12, 6, True), ("lfsr", 24, 6, False),
+              ("counter", 24, 6, False), ("lfsr", 24, 130, True)]
 
 
 def _ta_inputs(C, L, B, seed=0):
@@ -143,6 +154,33 @@ def test_compact_matches_dense_under_lfsr(backend, prng, bits, refresh):
                                             7, 13000, **kw)
     np.testing.assert_array_equal(np.asarray(d_ta), np.asarray(c_ta))
     np.testing.assert_array_equal(np.asarray(d_inc), np.asarray(c_inc))
+
+
+@pytest.mark.parametrize("prng,bits,B,boost", BODY_CASES)
+@pytest.mark.parametrize("kernel", ["dense", "compact"])
+def test_tile_body_branches_match_the_oracle(kernel, prng, bits, B, boost):
+    """Each static branch of the tile body equals ``ref.ta_update_ref`` on
+    two clause tiles and two literal tiles (the event tiles are expanded
+    on a clause tile's first literal tile and reused on the next); the
+    compact kernel gets one active clause tile of two."""
+    C, L = 136, 300
+    ta, lit, cl, t1, t2, lm = _ta_inputs(C, L, B, seed=B + bits)
+    if kernel == "compact":
+        t1, t2 = t1.at[:, 128:].set(0), t2.at[:, 128:].set(0)
+    kw = dict(rand_bits=16, boost=boost, prng=prng, lfsr_bits=bits,
+              seed_refresh=True)
+    want = ref.ta_update_ref(ta, lit, cl, t1, t2, lm, 77, 7000, **kw)
+    if kernel == "dense":
+        got = ta_update_op(ta, lit, cl, t1, t2, lm, 77, 7000,
+                           backend="pallas", **kw)
+    else:
+        got, inc = kops.ta_update_compact_op(ta, lit, cl, t1, t2, lm,
+                                             ref.pack_include(ta, 256), 77,
+                                             7000, backend="pallas", **kw)
+        np.testing.assert_array_equal(np.asarray(inc),
+                                      np.asarray(ref.pack_include(want, 256)))
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    assert not np.array_equal(np.asarray(want), np.asarray(ta))
 
 
 def test_resolve_ta_prng_env(monkeypatch):
